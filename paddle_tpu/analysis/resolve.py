@@ -1,7 +1,7 @@
 """Lightweight import/alias resolution for project-aware passes.
 
 The SPMD surface is imported under many spellings — ``from jax.sharding
-import PartitionSpec as P``, ``from ._compat import shard_map``, ``import
+import PartitionSpec as P``, ``from jax import shard_map``, ``import
 jax`` + ``jax.lax.psum`` — and passes that key on those symbols must see
 through every one of them.  :class:`Imports` builds a per-file table mapping
 local names to canonical dotted paths (resolving relative imports against
@@ -12,7 +12,7 @@ On top of that sit the symbol classifiers the ``sharding-spec-coverage``
 pass uses: :func:`is_shard_map`, :func:`is_partition_spec`,
 :func:`collective_axis_arg`, and :func:`mesh_axis_names`.  They match by
 canonical-path suffix so both the jax spellings and this repo's wrappers
-(``parallel/_compat.shard_map``, ``distributed/collective.mesh_*``) resolve
+(``distributed/collective.mesh_*``) resolve
 to the same semantic symbol.
 """
 from __future__ import annotations
@@ -74,9 +74,9 @@ def _match(canon: str | None, suffixes) -> bool:
     return any(canon == s or canon.endswith("." + s) for s in suffixes)
 
 
-# every spelling that means jax's shard_map, including this repo's shim
+# every spelling that means jax's shard_map
 _SHARD_MAP = ("jax.shard_map", "jax.experimental.shard_map.shard_map",
-              "parallel._compat.shard_map", "_compat.shard_map", "shard_map")
+              "shard_map")
 _PARTITION_SPEC = ("jax.sharding.PartitionSpec",
                    "jax.experimental.pjit.PartitionSpec", "PartitionSpec")
 _NAMED_SHARDING = ("jax.sharding.NamedSharding", "NamedSharding")

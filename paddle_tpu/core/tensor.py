@@ -264,9 +264,8 @@ class Tensor:
     def cpu(self) -> "Tensor":
         return self.to(device="cpu")
 
-    def cuda(self, *a, **k) -> "Tensor":  # paddle compat name; routes to accelerator
-        from .device import _accel_platform
-        return self.to(device=_accel_platform())
+    def cuda(self, *a, **k) -> "Tensor":  # paddle compat name; routes to the TPU
+        return self.to(device="tpu")
 
     def pin_memory(self) -> "Tensor":
         return self

@@ -232,7 +232,7 @@ def local_map(fn, out_placements=None, in_placements=None, process_mesh=None,
               reshard_inputs=False):
     """Run fn on local shards via shard_map (reference: auto_parallel local_map)."""
     def wrapper(*tensors):
-        from ...parallel._compat import shard_map
+        from jax import shard_map
         mesh = (process_mesh or _global_mesh).jax_mesh()
         in_specs = tuple(placements_to_spec(p, t.ndim, list(mesh.axis_names))
                          for p, t in zip(in_placements, tensors))

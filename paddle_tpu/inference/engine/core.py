@@ -109,11 +109,11 @@ class LLMEngine(_LegacyDelegation, _SpecOrchestration):
         decode_block: max decode steps fused into one dispatch (power-of-two
         blocks are chosen per step, shrinking near max_new; eos-bearing
         requests force 1). Raise it when dispatch latency, not throughput,
-        dominates (e.g. a remote/tunneled runtime) — or pass "auto": the
-        engine then samples wall time at two block sizes, solves the
-        dispatch model t(k) = RTT + k*c for the session's actual round-trip
-        latency and per-token device time, and picks the power-of-two block
-        where RTT costs <= ~25% of device time (re-estimated as timing
+        dominates — or pass "auto": the engine then samples wall time at
+        two block sizes, solves the dispatch model t(k) = RTT + k*c for
+        the per-dispatch latency (RTT) and per-token device time (c), and
+        picks the power-of-two block where the dispatch latency costs
+        <= ~25% of device time (re-estimated as timing
         samples accumulate, capped at decode_block_max).
 
         kv_cache_dtype: "auto" stores pages in the weight dtype; "int8"

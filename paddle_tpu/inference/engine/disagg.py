@@ -27,7 +27,7 @@ hides under decode compute instead of serializing with it (seating
 latency matches the blocking hop, minus the stall), double-buffered
 exactly like ``runner.restore_pages``.  ``async_handoff=False`` keeps the original
 blocking hop (gather → device_put → scatter inline before the decode
-step), which the bench uses as the 1:1-sync comparator.  Source pages are
+step), the 1:1-sync comparator (ROADMAP D5).  Source pages are
 released as soon as the gather is dispatched (the dispatched program owns
 the data); content-registered prompt pages park in the prefill LRU, so
 prefix-cache hits survive disaggregation.  A full queue back-pressures
@@ -602,7 +602,7 @@ class DisaggEngine:
         """Blocking hop (``async_handoff=False``): move every placeable
         handoff into a decode slot inline — gather, device_put, scatter,
         admit, all before the next decode step dispatches.  The original
-        1:1 engine's behavior, kept as the bench's sync comparator."""
+        1:1 engine's behavior, kept as the sync comparator (ROADMAP D5)."""
         while True:
             nxt = self._next_placeable()
             if nxt is None:

@@ -37,6 +37,7 @@ class SpecConfig:
     draft_model: optional small LlamaForCausalLM replacing the n-gram
         proposer — greedy continuation of the request's token history.
     adaptive: learn the verify dispatch's cost curve t(rows) = RTT+rows*c
+        (RTT: the fixed per-dispatch latency)
         (separately from the decode-block auto-fit: a verify step consumes
         a VARIABLE number of tokens) and pick the draft length maximizing
         expected accepted tokens per second under the observed acceptance

@@ -17,8 +17,7 @@ replicas into one service:
   (``Idempotency-Key``), SSE streams client-resumable (``Last-Event-ID``),
   and gateway ``kill -9`` recoverable (journal replay re-drives unfinished
   requests through the engines' ``resume_tokens`` machinery).
-- :mod:`.loadgen` — deterministic trace-driven load generation for tests and
-  the bench frontend extra.
+- :mod:`.loadgen` — deterministic trace-driven load generation.
 - :mod:`.rpc` / :mod:`.worker` / :mod:`.supervisor` / :mod:`.fleet` — the
   self-healing multi-process fleet: each replica runs its engine in its own
   OS process behind a socket RPC, holds a TTL lease on the membership plane

@@ -1,14 +1,14 @@
 """Deterministic trace-driven load generation for the serving front door.
 
-A trace is a plain list of request dicts built from one seed, so the test
-suite and ``bench.py`` replay byte-identical workloads: ``make_trace`` draws
+A trace is a plain list of request dicts built from one seed, so every
+caller replays byte-identical workloads: ``make_trace`` draws
 ``groups`` shared prefixes (whole KV pages, to make prefix-cache affinity
 visible) and gives every request its own suffix.  ``run_closed_loop`` drives
 a :class:`~.replica.ReplicaSet` with N concurrency workers, each submitting
 its next request only after the previous one is terminal (closed loop — the
 offered load adapts to the service rate instead of piling an unbounded
-queue), and ``summarize`` reduces the per-request records to the numbers the
-bench reports: aggregate tokens/s and p50/p95 TTFT.
+queue), and ``summarize`` reduces the per-request records to aggregate
+tokens/s and p50/p95 TTFT.
 """
 from __future__ import annotations
 
@@ -107,7 +107,7 @@ def percentile(values, q):
 
 
 def summarize(records, wall_seconds):
-    """Reduce closed-loop records to the bench-facing aggregate numbers."""
+    """Reduce closed-loop records to the aggregate numbers."""
     done = [r for r in records if r is not None]
     ttfts = [r["ttft"] for r in done if r["ttft"] is not None]
     total_tokens = sum(r["tokens"] for r in done)

@@ -179,7 +179,7 @@ class PrefixAffinityRouter:
 
 class RoundRobinRouter:
     """Affinity-blind baseline: cycle through the replica list in order.
-    Used by the bench comparison and as the control in the affinity tests."""
+    The control in the affinity tests."""
 
     def __init__(self):
         self._lock = threading.Lock()

@@ -12,9 +12,8 @@ lease so watchers see ``leave`` immediately.
 
 :class:`WorkerServer` is host-agnostic on purpose — production runs it
 under ``python -m paddle_tpu.inference.frontend.worker`` as a supervised
-child process, the deterministic tier-1 tests run several in threads of
-one process with an injected clock, and the bench does the same to measure
-degradation without TPU-sized process images.
+child process, and the deterministic tier-1 tests run several in threads
+of one process with an injected clock.
 
 RPC ops: ``submit poll cancel status result request_error ttft tpot load
 health metrics metrics_snapshot trace_events prefix_keys pull_pages
@@ -42,6 +41,7 @@ import threading
 import time
 
 from ... import observability as _obs
+from ...core.compile_cache import enable_compile_cache
 from ...distributed.membership import MembershipService
 from ...observability import flight as _flight
 from .admission import ShedError
@@ -229,7 +229,12 @@ def load_engine_factory(spec):
 
 def main(argv=None):
     """``python -m paddle_tpu.inference.frontend.worker`` — the supervised
-    child-process entry.  Blocks until SIGTERM (graceful drain) or death."""
+    child-process entry.  Blocks until SIGTERM (graceful drain) or death.
+
+    A chip belongs to one process: whoever spawns several workers on one
+    multi-chip host gives worker ``i`` the environment
+    ``core.hermetic.one_chip_env(i)`` (as the launcher does), and must not
+    itself have initialized the JAX backend."""
     import argparse
 
     from ...distributed.store import TCPStore
@@ -251,6 +256,7 @@ def main(argv=None):
                         "disaggregation pool instead of decoding")
     args = p.parse_args(argv)
 
+    enable_compile_cache()      # a respawned worker must not recompile
     engine = load_engine_factory(args.engine_spec)()
     store = TCPStore(host=args.store_host, port=args.store_port)
     server = WorkerServer(args.name, engine, store, group=args.group,

@@ -45,7 +45,7 @@ from .engine import (  # noqa: F401
     prefix_page_keys,
     split_mesh,
 )
-from .engine.spec import _NgramProposer  # noqa: F401  (test/bench import)
+from .engine.spec import _NgramProposer  # noqa: F401  (test import)
 
 __all__ = ["LLMEngine", "DisaggEngine", "split_mesh", "Request",
            "RequestStatus", "SpecConfig", "prefix_page_keys",

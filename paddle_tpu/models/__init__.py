@@ -1,2 +1,2 @@
-"""Model zoo: flagship configs from BASELINE.md (GPT-2, Llama-3, MoE,
+"""Model zoo: flagship configs from BASELINE.json (GPT-2, Llama-3, MoE,
 ERNIE encoder family)."""

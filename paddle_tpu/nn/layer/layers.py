@@ -28,7 +28,7 @@ class HookRemoveHelper:
 
 
 class Layer:
-    def __init__(self, name_scope=None, dtype="float32"):
+    def __init__(self, name_scope=None, dtype=None):
         object.__setattr__(self, "_parameters", OrderedDict())
         object.__setattr__(self, "_buffers", OrderedDict())
         object.__setattr__(self, "_sub_layers", OrderedDict())
@@ -37,7 +37,9 @@ class Layer:
         self._forward_post_hooks = OrderedDict()
         self._hook_id = 0
         self.training = True
-        self._dtype = dtypes.convert_dtype(dtype)
+        # parameters are born in the default dtype (paddle.set_default_dtype):
+        # a bf16 model never holds a float32 copy of itself on the device
+        self._dtype = dtypes.convert_dtype(dtype) or dtypes.get_default_dtype()
         self._name_scope = name_scope or self.__class__.__name__.lower()
 
     # ---- attribute magic -----------------------------------------------------

@@ -22,8 +22,8 @@ Usage::
 Cost model: disabled (the default), every instrumented call site pays one
 global-bool check; the op-dispatch hot path pays nothing at all because
 ``enable()``/``disable()`` install/remove the recorder in core.dispatch's
-single instrumentation slot (``bench.py``'s serving extra measures the
-enabled-vs-disabled decode throughput to keep this claim honest).
+single instrumentation slot (what it costs on the chip when enabled: not
+measured).
 
 Standard metric families are declared here, in one place, so instrumented
 modules share names and label schemas instead of inventing their own.

@@ -196,7 +196,7 @@ _dump_dir = None              # configure() override; else env var
 
 def enable() -> None:
     """Switch the flight recorder on (independent of the metrics switch, so
-    the bench can pin trace overhead on its own)."""
+    trace overhead can be measured on its own)."""
     global _ENABLED
     _ENABLED = True
 

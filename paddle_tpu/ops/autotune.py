@@ -12,6 +12,7 @@ timing side effects into a compiled program."""
 from __future__ import annotations
 
 import json
+import logging
 import os
 import threading
 import time
@@ -24,6 +25,8 @@ if "use_autotune" not in flags._registry:   # normally defined in core/flags
     flags.define_flag("use_autotune", False,
                       "time Pallas launch-config candidates and cache the "
                       "best")
+
+logger = logging.getLogger(__name__)
 
 _lock = threading.Lock()
 _cache: dict[str, dict] = {}
@@ -58,6 +61,11 @@ def cache_key(op: str, *parts) -> str:
 
 
 def lookup(key: str):
+    """The tuned config for ``key``, or None.  With autotuning off nothing
+    is consulted: a file left on disk by another run — another jax, another
+    chip — must not pick a kernel's blocks."""
+    if not enabled():
+        return None
     _load_disk()
     with _lock:
         hit = _cache.get(key)
@@ -75,15 +83,19 @@ def _concrete(args) -> bool:
 def tune(key: str, candidates, build, args, iters=3):
     """Pick the fastest candidate config for `key`.
 
-    build(cfg) -> callable(*args). Returns the cached config when present;
-    times candidates only when autotune is enabled AND args are concrete
-    (never inside a jit trace); otherwise returns candidates[0]."""
+    build(cfg) -> callable(*args). With autotune off: candidates[0]. With it
+    on: the cached config when present, else candidates[0] inside a jit
+    trace, else the fastest candidate timed on the concrete args.  A
+    candidate that fails to compile or run is skipped, but never quietly:
+    the failures are counted and logged, and if EVERY candidate failed the
+    last error is raised — on the chip a refused kernel is a finding."""
     hit = lookup(key)
     if hit is not None:
         return hit
     if not enabled() or not _concrete(args):
         return candidates[0]
     best, best_t = None, float("inf")
+    failed = []
     for cfg in candidates:
         try:
             fn = build(cfg)
@@ -93,12 +105,21 @@ def tune(key: str, candidates, build, args, iters=3):
                 out = fn(*args)
             jax.block_until_ready(out)
             dt = (time.perf_counter() - t0) / iters
-        except Exception:
-            continue                                # invalid config: skip
+        except Exception as e:  # noqa: BLE001 — any refusal is recorded
+            failed.append((cfg, e))
+            continue
         if dt < best_t:
             best, best_t = cfg, dt
+    if failed:
+        cfg, e = failed[0]
+        logger.warning(
+            "autotune %s: %d of %d candidates failed to compile/run (first: "
+            "%s -> %s: %s)", key, len(failed), len(candidates), cfg,
+            type(e).__name__, str(e)[:200])
     if best is None:
-        best = candidates[0]
+        raise RuntimeError(
+            f"autotune {key}: all {len(candidates)} candidates failed"
+        ) from failed[-1][1]
     with _lock:
         _cache[key] = list(best) if isinstance(best, tuple) else best
         _save_disk()

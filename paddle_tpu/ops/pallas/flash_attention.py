@@ -430,13 +430,10 @@ def warm_autotune(q, k, v, causal=True):
         return
     B, Sq, H, D = q.shape
     Hk = k.shape[2]
-    try:
-        q3 = jnp.moveaxis(q, 2, 1).reshape(B * H, Sq, D)
-        k3 = jnp.moveaxis(k, 2, 1).reshape(B * Hk, k.shape[1], D)
-        v3 = jnp.moveaxis(v, 2, 1).reshape(B * Hk, v.shape[1], D)
-        _pick_blocks(q3, k3, v3, causal)
-    except Exception:   # tuning is best-effort, never fails the op
-        pass
+    q3 = jnp.moveaxis(q, 2, 1).reshape(B * H, Sq, D)
+    k3 = jnp.moveaxis(k, 2, 1).reshape(B * Hk, k.shape[1], D)
+    v3 = jnp.moveaxis(v, 2, 1).reshape(B * Hk, v.shape[1], D)
+    _pick_blocks(q3, k3, v3, causal)
 
 
 # ---------------------------------------------------------------------------

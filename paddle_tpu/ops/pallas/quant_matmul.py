@@ -38,7 +38,10 @@ def _kernel(x_ref, qw_ref, s_ref, o_ref, acc_s, *, nk, int4, out_dtype):
 
     q = qw_ref[...]
     if int4:
-        lo = (q << 4).astype(jnp.int8) >> 4      # sign-extend low nibble
+        # Mosaic legalizes no i8 vector shifts (arith.shli on vector<..xi8>):
+        # widen to i32 first, then sign-extend each nibble arithmetically
+        q = q.astype(jnp.int32)
+        lo = (q << 28) >> 28                     # sign-extend low nibble
         hi = q >> 4                              # arithmetic shift high
         # packed rows [bk//2, bn] -> interleaved [bk, bn] (row 2i from lo,
         # row 2i+1 from hi) matching the packer in quantization/weight_only

@@ -16,9 +16,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ._compat import shard_map
 from ..core.tensor import Tensor
 from ..core.dispatch import apply_op, unwrap
 from ..distributed.collective import mesh_ppermute

@@ -12,9 +12,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ._compat import shard_map
 from ..core.tensor import Tensor
 from ..core.dispatch import apply_op
 from ..distributed.collective import mesh_ppermute

@@ -17,9 +17,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ._compat import shard_map
 from ..core.dispatch import apply_op
 from ..distributed.collective import mesh_all_to_all
 from ..distributed.fleet.topology import get_hybrid_communicate_group

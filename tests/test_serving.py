@@ -522,7 +522,7 @@ class TestAutoDecodeBlock:
 
     def test_block_model_math_high_rtt(self):
         """Feed synthetic timings: RTT 100ms, c 3ms/token -> target 32 (the
-        cap), the tunneled-runtime regime."""
+        cap), the regime where dispatch latency dominates."""
         _, eng = self._engine()
         eng._record_block_sample(1, 0.103)
         assert eng._block_target == 2         # second sample size forced
