@@ -41,6 +41,9 @@ per-object protocol) with two resolution aids shared by the rules: a
 method whose every intra-class call site holds lock L is analyzed as if
 it held L itself (private helpers documented "caller holds the lock"),
 and call sites in ``__init__`` neither grant nor revoke that inheritance.
+A ``@contextmanager`` method that acquires ``self.L`` before its ``yield``
+and releases it after (a helper that counts the wait for the lock, say)
+holds L for the body of every ``with self.helper(...)``.
 Module-level locks (``_lock = threading.Lock()`` guarding a global
 registry) participate in CC102/CC103/CC104.  Nested ``def``/``lambda``
 bodies run later, possibly on another thread, so they never inherit the
@@ -149,12 +152,41 @@ class _Method:
         return self.ctx.get(node, (frozenset(), False))[1]
 
 
-def _collect(method, class_locks, module_locks):
+def _lock_holders(defs, class_locks):
+    """{method name: lock attr} for the ``@contextmanager`` methods that
+    take ``self.<lock>.acquire()`` and give it back with ``.release()``:
+    ``with self.<method>(...)`` holds that lock for its body."""
+    holders = {}
+    for d in defs:
+        if not any(getattr(x, "attr", getattr(x, "id", None))
+                   == "contextmanager" for x in d.decorator_list):
+            continue
+        args = d.args.posonlyargs + d.args.args
+        selfname = args[0].arg if args else None
+        taken = {"acquire": set(), "release": set()}
+        for node in ast.walk(d):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr in taken):
+                attr = _self_attr(node.func.value, selfname)
+                if attr in class_locks:
+                    taken[node.func.attr].add(attr)
+        both = taken["acquire"] & taken["release"]
+        if len(both) == 1:
+            holders[d.name] = both.pop()
+    return holders
+
+
+def _collect(method, class_locks, module_locks, holders=None):
     """Populate ``method.ctx``/``method.acquisitions`` by walking the body
     with the lexically-held lock set threaded through ``with`` blocks."""
     selfname = method.selfname
 
     def lock_key(expr):
+        if holders and isinstance(expr, ast.Call):
+            helper = _self_attr(expr.func, selfname)
+            if helper in holders:
+                return class_locks[holders[helper]].key
         attr = _self_attr(expr, selfname)
         if attr is not None and attr in class_locks:
             return class_locks[attr].key
@@ -366,7 +398,7 @@ def _find_cycles(edges):
 @register_pass
 class ConcurrencyPass(AnalysisPass):
     name = "concurrency"
-    version = 1
+    version = 2
     codes = ("CC101", "CC102", "CC103", "CC104", "CC105")
     description = ("lock discipline: guarded-attribute races (CC101), "
                    "blocking calls under a held lock (CC102), condition "
@@ -477,8 +509,9 @@ class ConcurrencyPass(AnalysisPass):
             return
         locks_by_key.update({l.key: l for l in class_locks.values()})
         class_keys = {l.key for l in class_locks.values()}
+        holders = _lock_holders(defs, class_locks)
         for m in methods.values():
-            _collect(m, class_locks, module_locks)
+            _collect(m, class_locks, module_locks, holders)
         _infer_inherited(methods, class_keys)
         sleep_attrs = _sleep_attrs(methods, imports)
 

@@ -20,6 +20,14 @@ and ``trace_span`` (timeline).  Ad-hoc instrumentation rots past them:
     ``ctx=`` — ``wire_context()`` for request-scoped traffic, an explicit
     ``ctx=None`` for control-plane ops that genuinely have no trace.
     (AT103)
+  * In ``inference/``, a ``t0 = time.perf_counter()`` whose every use is a
+    ``time.perf_counter() - t0`` handed to ``flight.record(dur=...)`` or to
+    an ``observe()`` is a second clock beside the one span call:
+    ``with obs.trace_span(name, ...) as sp`` times the scope into the
+    registry, the profiler's trace and the flight recorder at once, and
+    ``sp.dur`` is there for whoever else needs it.  A duration that feeds
+    the program's own control or an always-on counter as well is no such
+    pair and is left alone.  (AT104)
 
 Pure CLI front-ends (whose job *is* printing) opt out with
 ``# graftlint: disable-file=no-adhoc-telemetry``.
@@ -39,6 +47,8 @@ _HINTS = {
     "AT103": "pass ctx=wire_context() to thread the ambient trace through "
              "the frame, or an explicit ctx=None for untraced "
              "control-plane ops",
+    "AT104": "time the scope with `with obs.trace_span(name, ...) as sp` and "
+             "read sp.dur; pragma a pair that must stay, with the reason",
 }
 
 # receivers treated as RPC clients: `client.call(...)`, `self.client.call`,
@@ -55,16 +65,88 @@ def _is_client_receiver(expr):
     return name == "rpc" or name == "client" or name.endswith("_client")
 
 
+def _is_perf_counter(node):
+    return (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+            and node.func.attr == "perf_counter"
+            and isinstance(node.func.value, ast.Name)
+            and node.func.value.id == "time")
+
+
+def _only_feeds_telemetry(name, fn, parents, seen=()):
+    """Whether every read of local ``name`` in ``fn`` ends in a ``dur=`` of
+    a ``.record(...)`` call or in an argument of an ``.observe(...)`` call,
+    directly or through further locals it is assigned to."""
+    loads = [n for n in ast.walk(fn) if isinstance(n, ast.Name)
+             and n.id == name and isinstance(n.ctx, ast.Load)]
+    if not loads:
+        return False
+    for load in loads:
+        node = load
+        while True:
+            parent = parents[node]
+            if isinstance(parent, ast.keyword):
+                call = parents[parent]
+                if not (parent.arg == "dur" and isinstance(
+                        call.func, ast.Attribute) and call.func.attr == "record"):
+                    return False
+                break
+            if isinstance(parent, ast.Call):
+                if not (node in parent.args and isinstance(
+                        parent.func, ast.Attribute)
+                        and parent.func.attr == "observe"):
+                    return False
+                break
+            if isinstance(parent, ast.Assign):
+                target = parent.targets[0]
+                if not (len(parent.targets) == 1 and isinstance(target, ast.Name)
+                        and target.id != name and target.id not in seen
+                        and _only_feeds_telemetry(target.id, fn, parents,
+                                                  seen + (name,))):
+                    return False
+                break
+            if not isinstance(parent, (ast.BinOp, ast.UnaryOp)):
+                return False
+            node = parent
+    return True
+
+
+def _adhoc_span_pairs(tree):
+    """Line numbers of ``t0 = time.perf_counter()`` assignments that AT104
+    describes."""
+    parents = {child: node for node in ast.walk(tree)
+               for child in ast.iter_child_nodes(node)}
+    lines = []
+    for fn in ast.walk(tree):
+        if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            continue
+        for node in ast.walk(fn):
+            if (isinstance(node, ast.Assign) and len(node.targets) == 1
+                    and isinstance(node.targets[0], ast.Name)
+                    and _is_perf_counter(node.value)
+                    and _only_feeds_telemetry(node.targets[0].id, fn, parents)):
+                lines.append(node.lineno)
+    return sorted(set(lines))
+
+
 @register_pass
 class NoAdhocTelemetryPass(AnalysisPass):
     name = "no-adhoc-telemetry"
-    version = 2
-    codes = ("AT101", "AT102", "AT103")
-    description = ("bare print(), wall-clock time.time() timing, and RPC "
-                   "client.call() sites that drop the trace-context field")
+    version = 3
+    codes = ("AT101", "AT102", "AT103", "AT104")
+    description = ("bare print(), wall-clock time.time() timing, RPC "
+                   "client.call() sites that drop the trace-context field, "
+                   "and perf_counter pairs in inference/ that only feed "
+                   "telemetry beside the span call")
 
     def check_file(self, src) -> list[Finding]:
         findings: list[Finding] = []
+        if "/inference/" in str(src.path).replace("\\", "/"):
+            for lineno in _adhoc_span_pairs(src.tree):
+                findings.append(Finding(
+                    self.name, "AT104", src.path, lineno,
+                    "a perf_counter() pair that only feeds flight.record(dur=) "
+                    "or observe() — a second clock beside trace_span",
+                    _HINTS["AT104"]))
         # `from time import time [as t]` makes bare-name calls wall-clock too
         time_aliases = set()
         for node in ast.walk(src.tree):

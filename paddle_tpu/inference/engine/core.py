@@ -345,41 +345,42 @@ class LLMEngine(_LegacyDelegation, _SpecOrchestration):
     def _prefill_chunk(self, slot):
         sched = self.sched
         r = sched.slots[slot]
-        self._step_phase = ("prefill", (slot,))
-        _faults.maybe_fire("serving.step", rids=[r.rid], phase="prefill")
-        start = r.pos
-        n = min(self.chunk, len(r.prompt) - start)
-        if self.prefix_cache:
-            # about to write [start, start+n): un-share any page another
-            # slot still maps (a fully-cached prompt re-prefilling its
-            # final token into the last shared page lands here)
-            sched.cow_unshare(slot, start, n)
-        toks = np.zeros((self.chunk,), np.int32)
-        toks[:n] = r.prompt[start:start + n]
-        finishes = (start + n) == len(r.prompt)
-        r.prefill_dispatches += 1
-        self.prefill_dispatches += 1
-        self._m.prefill.inc()
-        t0 = time.perf_counter()
-        with _obs.trace_span("serving.prefill"):
+        with _obs.trace_span("engine.prepare"):
+            self._step_phase = ("prefill", (slot,))
+            _faults.maybe_fire("serving.step", rids=[r.rid], phase="prefill")
+            start = r.pos
+            n = min(self.chunk, len(r.prompt) - start)
+            if self.prefix_cache:
+                # about to write [start, start+n): un-share any page another
+                # slot still maps (a fully-cached prompt re-prefilling its
+                # final token into the last shared page lands here)
+                sched.cow_unshare(slot, start, n)
+            toks = np.zeros((self.chunk,), np.int32)
+            toks[:n] = r.prompt[start:start + n]
+            finishes = (start + n) == len(r.prompt)
+            r.prefill_dispatches += 1
+            self.prefill_dispatches += 1
+            self._m.prefill.inc()
+        with _obs.trace_span("prefill", rid=r.rid, trace_id=r.trace_id,
+                             tokens=n, start=start):
             nxt = self.runner.run_prefill(
                 toks, start, sched.slot_tables[slot], n,
                 0 if r.do_sample else 1, r.temperature, r.top_p, r.top_k,
                 self._next_seed(r))
-        if r.trace_id is not None:
-            _flight.record("prefill", rid=r.rid, trace_id=r.trace_id,
-                           dur=time.perf_counter() - t0, tokens=n,
-                           start=start)
-        r.pos += n
-        sched.lens[slot] = start + n
-        if self.prefix_cache:
-            sched.register_pages(slot, r)
-        if finishes:
-            token = int(np.asarray(nxt))
-            if self.prefill_sink is not None:
-                self.prefill_sink(slot, token)
-            else:
-                sched.emit(slot, token)
+            if finishes:
+                # only the chunk that ends a prompt reads its sample
+                with _obs.trace_span("runner.wait"):
+                    token = int(np.asarray(nxt))
+        with _obs.trace_span("engine.emit"):
+            r.pos += n
+            sched.lens[slot] = start + n
+            if self.prefix_cache:
+                sched.register_pages(slot, r)
+            if finishes:
+                if self.prefill_sink is not None:
+                    self.prefill_sink(slot, token)
+                else:
+                    sched.emit(slot, token)
 
     def step(self):
         """One engine dispatch: a prefill chunk if any slot is mid-prompt,
@@ -394,13 +395,17 @@ class LLMEngine(_LegacyDelegation, _SpecOrchestration):
         an already-dispatched XLA program is best-effort (the donated cache
         buffer may be unrecoverable) — the engine still degrades per-request
         instead of crashing the loop."""
-        if self._any_deadline:
-            self.sched.expire_deadlines()
-        self._step_phase = ("admit", ())
-        try:
-            served = self._step_impl()
-        except Exception as e:  # noqa: BLE001 — the isolation boundary
-            served = self._survive_step_failure(e)
+        with _obs.trace_span("engine.step") as sp:
+            if self._any_deadline:
+                with _obs.trace_span("engine.admit"):
+                    self.sched.expire_deadlines()
+            self._step_phase = ("admit", ())
+            try:
+                served = self._step_impl()
+            except Exception as e:  # noqa: BLE001 — the isolation boundary
+                served = self._survive_step_failure(e)
+            kind = self._step_phase[0]
+            sp.set(kind="none" if kind == "admit" else kind)
         if self.debug_refcount_audit:
             problems = self.audit_refcounts()
             if problems:
@@ -410,9 +415,10 @@ class LLMEngine(_LegacyDelegation, _SpecOrchestration):
 
     def _step_impl(self):
         sched = self.sched
-        sched.admit()
-        if _obs.enabled():
-            self._refresh_gauges()
+        with _obs.trace_span("engine.admit"):
+            sched.admit()
+            if _obs.enabled():
+                self._refresh_gauges()
         if _faults.active:
             point = _faults.fire("serving.slow_step")
             if point is not None and point.delay:
@@ -425,37 +431,74 @@ class LLMEngine(_LegacyDelegation, _SpecOrchestration):
         if not live:
             return 0
         if self._spec is not None:
-            props = self._propose_drafts(live)
+            with _obs.trace_span("engine.prepare"):
+                props = self._propose_drafts(live)
             if any(props.values()):
                 return self._spec_step(live, props)
             # no slot has a draft this step: the plain decode block below
             # amortizes dispatch cost better than a 1-row verify would
-        # block size: largest power of two <= every slot's remaining budget,
-        # capped by decode_block (or the RTT-adapted target in auto mode);
-        # any eos request needs per-token host inspection -> 1
-        cap = self._block_target if self._auto_block else self.decode_block
-        k = min(cap, min(r.max_new - len(r.out) for _, r in live))
-        if any(r.eos is not None for _, r in live):
-            k = 1
-        k = 1 << max(0, k.bit_length() - 1)              # floor to pow2
-        active = np.zeros((self.max_batch,), np.int32)
-        tokens = np.zeros((self.max_batch,), np.int32)
-        greedy = np.ones((self.max_batch,), np.int32)
-        temp = np.ones((self.max_batch,), np.float32)
-        topp = np.ones((self.max_batch,), np.float32)
-        topk = np.zeros((self.max_batch,), np.int32)
-        seeds = np.zeros((self.max_batch,), np.int32)
-        fold = np.zeros((self.max_batch,), np.int32)
-        for slot, r in live:
-            if sched.slots[slot] is not r:
-                continue        # preempted by an earlier slot's growth
-            sched.ensure_page(slot, ahead=k)
-        # growth may have preempted members of `live` — drop them before
-        # building the batch (a stale entry would re-allocate pages to an
-        # empty slot and decode a request that is back in the queue)
-        live = [(s, r) for s, r in live if sched.slots[s] is r]
-        if not live:
-            return 0
+        with _obs.trace_span("engine.prepare"):
+            # block size: largest power of two <= every slot's remaining
+            # budget, capped by decode_block (or the RTT-adapted target in
+            # auto mode); any eos request needs per-token host inspection -> 1
+            cap = self._block_target if self._auto_block else self.decode_block
+            k = min(cap, min(r.max_new - len(r.out) for _, r in live))
+            if any(r.eos is not None for _, r in live):
+                k = 1
+            k = 1 << max(0, k.bit_length() - 1)              # floor to pow2
+            for slot, r in live:
+                if sched.slots[slot] is not r:
+                    continue        # preempted by an earlier slot's growth
+                sched.ensure_page(slot, ahead=k)
+            # growth may have preempted members of `live` — drop them before
+            # building the batch (a stale entry would re-allocate pages to an
+            # empty slot and decode a request that is back in the queue)
+            live = [(s, r) for s, r in live if sched.slots[s] is r]
+            if not live:
+                return 0
+            args = self._decode_args(live)
+            self._step_phase = ("decode", tuple(s for s, _ in live))
+            _faults.maybe_fire("serving.step", rids=[r.rid for _, r in live],
+                               phase="decode")
+            compile_call = not self.runner.has_decode_program(k)
+            self._m.decode.inc()
+        # timed: the auto-fit below needs the wall time whatever is switched on
+        with _obs.trace_span("decode", rid=[r.rid for _, r in live],
+                             trace_id=[r.trace_id for _, r in live],
+                             timed=self._auto_block, block=k) as sp:
+            toks = self.runner.run_decode(
+                k, args[0], sched.lens, sched.slot_tables, *args[1:])  # [k, B]
+        with _obs.trace_span("engine.emit"):
+            if self._auto_block and not compile_call:
+                # the host sync in run_decode makes the span's wall time a
+                # true dispatch sample
+                self._record_block_sample(k, sp.dur)
+            if not compile_call and _obs.enabled():
+                # dispatch served k tokens for each live slot; exclude the
+                # compile call so the histogram reflects steady-state latency
+                for _ in live:
+                    self._m.token_latency.observe(sp.dur / k)
+            for j in range(k):
+                for slot, r in live:
+                    if sched.slots[slot] is not r:           # released mid-block
+                        continue
+                    sched.lens[slot] += 1
+                    sched.emit(slot, int(toks[j, slot]))
+        return len(live)
+
+    def _decode_args(self, live):
+        """Host arrays of one decode dispatch over the ``live`` slots, in
+        ``run_decode``'s order without ``lens`` and ``tables``: tokens,
+        active, then the per-slot sampling parameters."""
+        B = self.max_batch
+        tokens = np.zeros((B,), np.int32)
+        active = np.zeros((B,), np.int32)
+        greedy = np.ones((B,), np.int32)
+        temp = np.ones((B,), np.float32)
+        topp = np.ones((B,), np.float32)
+        topk = np.zeros((B,), np.int32)
+        seeds = np.zeros((B,), np.int32)
+        fold = np.zeros((B,), np.int32)
         for slot, r in live:
             active[slot] = 1
             tokens[slot] = r.out[-1]
@@ -465,37 +508,7 @@ class LLMEngine(_LegacyDelegation, _SpecOrchestration):
             topk[slot] = r.top_k
             seeds[slot] = self._next_seed(r)
             fold[slot] = 1 if r.seed is None else 0
-        self._step_phase = ("decode", tuple(s for s, _ in live))
-        _faults.maybe_fire("serving.step", rids=[r.rid for _, r in live],
-                           phase="decode")
-        compile_call = not self.runner.has_decode_program(k)
-        self._m.decode.inc()
-        t0 = time.perf_counter()
-        with _obs.trace_span("serving.decode"):
-            toks = self.runner.run_decode(
-                k, tokens, sched.lens, sched.slot_tables, active,
-                greedy, temp, topp, topk, seeds, fold)       # [k, B]
-        dt = time.perf_counter() - t0
-        if _flight.enabled():
-            for slot, r in live:
-                if r.trace_id is not None:
-                    _flight.record("decode", rid=r.rid, trace_id=r.trace_id,
-                                   dur=dt, block=k)
-        if self._auto_block and not compile_call:
-            # host sync above makes the wall time a true dispatch sample
-            self._record_block_sample(k, dt)
-        if not compile_call and _obs.enabled():
-            # dispatch served k tokens for each live slot; exclude the
-            # compile call so the histogram reflects steady-state latency
-            for _ in live:
-                self._m.token_latency.observe(dt / k)
-        for j in range(k):
-            for slot, r in live:
-                if sched.slots[slot] is not r:               # released mid-block
-                    continue
-                sched.lens[slot] += 1
-                sched.emit(slot, int(toks[j, slot]))
-        return len(live)
+        return tokens, active, greedy, temp, topp, topk, seeds, fold
 
     # ----------------------------------------------------- failure isolation
     def _survive_step_failure(self, e):
@@ -594,27 +607,12 @@ class LLMEngine(_LegacyDelegation, _SpecOrchestration):
         sched.ensure_page(slot, ahead=1)
         if sched.slots[slot] is not r:
             return                # growth preempted the probe target
-        active = np.zeros((self.max_batch,), np.int32)
-        tokens = np.zeros((self.max_batch,), np.int32)
-        greedy = np.ones((self.max_batch,), np.int32)
-        temp = np.ones((self.max_batch,), np.float32)
-        topp = np.ones((self.max_batch,), np.float32)
-        topk = np.zeros((self.max_batch,), np.int32)
-        seeds = np.zeros((self.max_batch,), np.int32)
-        fold = np.zeros((self.max_batch,), np.int32)
-        active[slot] = 1
-        tokens[slot] = r.out[-1]
-        greedy[slot] = 0 if r.do_sample else 1
-        temp[slot] = r.temperature
-        topp[slot] = r.top_p
-        topk[slot] = r.top_k
-        seeds[slot] = self._next_seed(r)
-        fold[slot] = 1 if r.seed is None else 0
+        args = self._decode_args([(slot, r)])
         self._m.decode.inc()
-        with _obs.trace_span("serving.decode_probe"):
+        with _obs.trace_span("decode", rid=r.rid, trace_id=r.trace_id,
+                             block=1, probe=1):
             toks = self.runner.run_decode(
-                1, tokens, sched.lens, sched.slot_tables, active,
-                greedy, temp, topp, topk, seeds, fold)
+                1, args[0], sched.lens, sched.slot_tables, *args[1:])
         sched.lens[slot] += 1
         sched.emit(slot, int(toks[0, slot]))
 

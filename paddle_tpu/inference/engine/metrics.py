@@ -25,6 +25,7 @@ class _EngineMetrics:
         self.label = label
         self.ttft = _obs.SERVING_TTFT.labels(**e)
         self.token_latency = _obs.SERVING_TOKEN_LATENCY.labels(**e)
+        self.queue_wait = _obs.SERVING_QUEUE_WAIT.labels(**e)
         self.queue_depth = _obs.SERVING_QUEUE_DEPTH.labels(**e)
         self.active_slots = _obs.SERVING_ACTIVE_SLOTS.labels(**e)
         self.occupancy = _obs.SERVING_OCCUPANCY.labels(**e)

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ... import observability as _obs
 from ...core.device import Place
 
 __all__ = ["ModelRunner"]
@@ -128,46 +129,47 @@ class ModelRunner:
         def wb(lin):        # Linear stores weight [in, out]
             return np.asarray(lin.weight._data)
 
-        lay = model.llama.layers
-        W = {
-            "embed": np.asarray(model.llama.embed_tokens.weight._data),
-            "norm": np.asarray(model.llama.norm.weight._data),
-            "wq": np.stack([wb(l.self_attn.q_proj) for l in lay]),
-            "wk": np.stack([wb(l.self_attn.k_proj) for l in lay]),
-            "wv": np.stack([wb(l.self_attn.v_proj) for l in lay]),
-            "wo": np.stack([wb(l.self_attn.o_proj) for l in lay]),
-            "ln1": np.stack([np.asarray(l.input_layernorm.weight._data)
-                             for l in lay]),
-            "ln2": np.stack([np.asarray(
-                l.post_attention_layernorm.weight._data) for l in lay]),
-            "wg": np.stack([wb(l.mlp.gate_proj) for l in lay]),
-            "wu": np.stack([wb(l.mlp.up_proj) for l in lay]),
-            "wd": np.stack([wb(l.mlp.down_proj) for l in lay]),
-        }
-        W["head"] = (np.asarray(model.lm_head.weight._data)
-                     if model.lm_head is not None else W["embed"].T)
-        dtype = W["wq"].dtype
-        if mesh is not None:
-            pp = pp_axis if pp_axis in mesh.axis_names else None
-            mp = mp_axis if mp_axis in mesh.axis_names else None
-
-            def put(name, arr, spec):
-                # host -> mesh directly: jnp.asarray would stage every
-                # replica's weights on the default device first
-                return jax.device_put(arr, NamedSharding(mesh, spec))
-            specs = {
-                "embed": P(), "norm": P(), "head": P(None, mp),
-                "wq": P(pp, None, mp), "wk": P(pp, None, mp),
-                "wv": P(pp, None, mp), "wo": P(pp, mp, None),
-                "ln1": P(pp, None), "ln2": P(pp, None),
-                "wg": P(pp, None, mp), "wu": P(pp, None, mp),
-                "wd": P(pp, mp, None),
+        with _obs.trace_span("engine.build.weights"):
+            lay = model.llama.layers
+            W = {
+                "embed": np.asarray(model.llama.embed_tokens.weight._data),
+                "norm": np.asarray(model.llama.norm.weight._data),
+                "wq": np.stack([wb(l.self_attn.q_proj) for l in lay]),
+                "wk": np.stack([wb(l.self_attn.k_proj) for l in lay]),
+                "wv": np.stack([wb(l.self_attn.v_proj) for l in lay]),
+                "wo": np.stack([wb(l.self_attn.o_proj) for l in lay]),
+                "ln1": np.stack([np.asarray(l.input_layernorm.weight._data)
+                                 for l in lay]),
+                "ln2": np.stack([np.asarray(
+                    l.post_attention_layernorm.weight._data) for l in lay]),
+                "wg": np.stack([wb(l.mlp.gate_proj) for l in lay]),
+                "wu": np.stack([wb(l.mlp.up_proj) for l in lay]),
+                "wd": np.stack([wb(l.mlp.down_proj) for l in lay]),
             }
-            self.W = {k: put(k, v, specs[k]) for k, v in W.items()}
-            cache_spec = NamedSharding(mesh, P(pp))
-        else:
-            self.W = {k: jnp.asarray(v) for k, v in W.items()}
-            cache_spec = None
+            W["head"] = (np.asarray(model.lm_head.weight._data)
+                         if model.lm_head is not None else W["embed"].T)
+            dtype = W["wq"].dtype
+            if mesh is not None:
+                pp = pp_axis if pp_axis in mesh.axis_names else None
+                mp = mp_axis if mp_axis in mesh.axis_names else None
+
+                def put(name, arr, spec):
+                    # host -> mesh directly: jnp.asarray would stage every
+                    # replica's weights on the default device first
+                    return jax.device_put(arr, NamedSharding(mesh, spec))
+                specs = {
+                    "embed": P(), "norm": P(), "head": P(None, mp),
+                    "wq": P(pp, None, mp), "wk": P(pp, None, mp),
+                    "wv": P(pp, None, mp), "wo": P(pp, mp, None),
+                    "ln1": P(pp, None), "ln2": P(pp, None),
+                    "wg": P(pp, None, mp), "wu": P(pp, None, mp),
+                    "wd": P(pp, mp, None),
+                }
+                self.W = {k: put(k, v, specs[k]) for k, v in W.items()}
+                cache_spec = NamedSharding(mesh, P(pp))
+            else:
+                self.W = {k: jnp.asarray(v) for k, v in W.items()}
+                cache_spec = None
         self.cache_sharding = cache_spec
         self.kv_quant = (kv_cache_dtype == "int8")
         page_dtype = jnp.int8 if self.kv_quant else dtype
@@ -175,12 +177,14 @@ class ModelRunner:
         def pool(dt, *tail):    # born on its own devices (None: the default)
             return jnp.zeros((L, self.n_pages, page_size, kvh) + tail, dt,
                              device=cache_spec)
-        self.cache = (pool(page_dtype, D), pool(page_dtype, D))
-        if self.kv_quant:
-            self.cache += (pool(jnp.float32), pool(jnp.float32))
+        with _obs.trace_span("engine.build.pool"):
+            self.cache = (pool(page_dtype, D), pool(page_dtype, D))
+            if self.kv_quant:
+                self.cache += (pool(jnp.float32), pool(jnp.float32))
         self._prefill = self._build_prefill()
         self._decode_programs: dict = {}
         self._verify_programs: dict = {}
+        self._launched = set()      # programs that have run (and so compiled)
         self._copy_page_fn = None
         self._gather_fn = {}
         self._scatter_fn = {}
@@ -403,19 +407,38 @@ class ModelRunner:
     def has_verify_program(self, kv):
         return kv in self._verify_programs
 
+    def _launch(self, key, prog, attrs, *args):
+        """Hand one dispatch's host values to the device
+        (``runner.dispatch``) and launch ``prog`` on them
+        (``runner.launch``: the jitted call until it returns, which on the
+        chip is not at once — a prefill chunk's call sits out the chunk
+        before it); returns the program's first output as a device value.
+        A program's first launch traces, lowers and compiles it: that one
+        goes whole under ``engine.build.programs``, so the two leaf spans
+        hold the steady state alone."""
+        if key not in self._launched:
+            with _obs.trace_span("engine.build.programs", **attrs):
+                out, self.cache = prog(self.W, self.cache,
+                                       *[jnp.asarray(a) for a in args])
+            self._launched.add(key)
+            return out
+        with _obs.trace_span("runner.dispatch", **attrs):
+            args = [jnp.asarray(a) for a in args]
+        with _obs.trace_span("runner.launch", kind=key[0]):
+            out, self.cache = prog(self.W, self.cache, *args)
+        return out
+
     def run_prefill(self, tokens, start, table, n_valid,
                     greedy, temp, topp, topk, seed):
         """Dispatch one prefill chunk; returns the sampled next token as a
         DEVICE value (only the caller decides whether to sync on it — a
         mid-prompt chunk's sample is never read)."""
-        nxt, self.cache = self._prefill(
-            self.W, self.cache, jnp.asarray(tokens),
-            jnp.asarray(np.int32(start)), jnp.asarray(table),
-            jnp.asarray(np.int32(n_valid)),
-            jnp.asarray(np.int32(greedy)), jnp.asarray(np.float32(temp)),
-            jnp.asarray(np.float32(topp)), jnp.asarray(np.int32(topk)),
-            jnp.asarray(np.int32(seed)))
-        return nxt
+        attrs = ({"kind": "prefill", "rows": int(n_valid), "start": int(start)}
+                 if _obs.enabled() else {})
+        return self._launch(
+            ("prefill",), self._prefill, attrs, tokens, np.int32(start), table,
+            np.int32(n_valid), np.int32(greedy), np.float32(temp),
+            np.float32(topp), np.int32(topk), np.int32(seed))
 
     def run_decode(self, k, tokens, lens, tables, active,
                    greedy, temp, topp, topk, seeds, fold):
@@ -425,12 +448,17 @@ class ModelRunner:
         prog = self._decode_programs.get(k)
         if prog is None:
             prog = self._decode_programs[k] = self._build_decode(k)
-        toks, self.cache = prog(
-            self.W, self.cache, jnp.asarray(tokens), jnp.asarray(lens),
-            jnp.asarray(tables), jnp.asarray(active), jnp.asarray(greedy),
-            jnp.asarray(temp), jnp.asarray(topp), jnp.asarray(topk),
-            jnp.asarray(seeds), jnp.asarray(fold))
-        return np.asarray(toks)
+        attrs = {}
+        if _obs.enabled():
+            # what a roofline needs of this dispatch: the rows that decode
+            # and the valid context each of them reads
+            ctx = (np.asarray(lens) + 1)[np.asarray(active) > 0]
+            attrs = {"kind": "decode", "rows": int(ctx.size),
+                     "ctx_sum": int(ctx.sum()), "k": int(k)}
+        toks = self._launch(("decode", k), prog, attrs, tokens, lens, tables,
+                            active, greedy, temp, topp, topk, seeds, fold)
+        with _obs.trace_span("runner.wait"):
+            return np.asarray(toks)
 
     def run_verify(self, kv, tokens, lens, tables, n_rows,
                    greedy, temp, topp, topk, seeds, fold):
@@ -439,12 +467,17 @@ class ModelRunner:
         prog = self._verify_programs.get(kv)
         if prog is None:
             prog = self._verify_programs[kv] = self._build_verify(kv)
-        toks, self.cache = prog(
-            self.W, self.cache, jnp.asarray(tokens), jnp.asarray(lens),
-            jnp.asarray(tables), jnp.asarray(n_rows), jnp.asarray(greedy),
-            jnp.asarray(temp), jnp.asarray(topp), jnp.asarray(topk),
-            jnp.asarray(seeds), jnp.asarray(fold))
-        return np.asarray(toks)
+        attrs = {}
+        if _obs.enabled():
+            # row j of a slot reads lens + 1 + j tokens of context
+            n = np.asarray(n_rows, np.int64)
+            ctx_sum = int((n * (np.asarray(lens) + 1) + n * (n - 1) // 2).sum())
+            attrs = {"kind": "verify", "rows": int(n.sum()),
+                     "ctx_sum": ctx_sum, "k": int(kv)}
+        toks = self._launch(("verify", kv), prog, attrs, tokens, lens, tables,
+                            n_rows, greedy, temp, topp, topk, seeds, fold)
+        with _obs.trace_span("runner.wait"):
+            return np.asarray(toks)
 
     # ---------------------------------------------------------- page movement
     def copy_page(self, src, dst):

@@ -25,6 +25,7 @@ from collections import deque
 
 import numpy as np
 
+from ... import observability as _obs
 from ...observability import flight as _flight
 from .request import RequestStatus, prefix_page_keys
 
@@ -252,13 +253,11 @@ class Scheduler:
                 while i + len(run) < len(plan) \
                         and plan[i + len(run)][1] is None:
                     run.append(plan[i + len(run)][0])
-                t0 = time.perf_counter()
-                got = self._restore_chain(run)
-                if r.trace_id is not None:
-                    _flight.record("spill_restore", rid=r.rid,
-                                   trace_id=r.trace_id,
-                                   dur=time.perf_counter() - t0,
-                                   asked=len(run), restored=len(got))
+                with _obs.trace_span("spill_restore", rid=r.rid,
+                                     trace_id=r.trace_id,
+                                     asked=len(run)) as sp:
+                    got = self._restore_chain(run)
+                    sp.set(restored=len(got))
                 pages.extend(got)
                 n_restored += len(got)
                 if len(got) < len(run):
@@ -304,6 +303,10 @@ class Scheduler:
             self.lens[slot] = skip
             r.slot = slot
             r.status = RequestStatus.RUNNING
+            if r.admit_seq < 0 and self._m is not None:
+                # the first slot only: a preempted request's later waits
+                # start where it was served, not at its submission
+                self._m.queue_wait.observe(time.perf_counter() - r.t_submit)
             r.admit_seq = self._admit_seq
             self._admit_seq += 1
             self.slots[slot] = r

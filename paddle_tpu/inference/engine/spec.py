@@ -10,8 +10,6 @@ paged-KV rollback, plus the adaptive draft-length cost fit).
 """
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from ... import observability as _obs
@@ -128,82 +126,85 @@ class _SpecOrchestration:
         roll rejected pages back. Slots without a proposal ride along with
         one row (their pending token advances normally)."""
         sched = self.sched
-        for slot, r in live:
-            if sched.slots[slot] is not r:
-                continue        # preempted by an earlier slot's growth
-            sched.ensure_page(slot, ahead=len(props.get(slot, ())) + 1)
-        live = [(s, r) for s, r in live if sched.slots[s] is r]
-        if not live:
-            return 0
-        Kv = ceil_pow2(max(len(props.get(s, ())) + 1 for s, _ in live))
-        tokens = np.zeros((self.max_batch, Kv), np.int32)
-        n_rows = np.zeros((self.max_batch,), np.int32)
-        greedy = np.ones((self.max_batch,), np.int32)
-        temp = np.ones((self.max_batch,), np.float32)
-        topp = np.ones((self.max_batch,), np.float32)
-        topk = np.zeros((self.max_batch,), np.int32)
-        seeds = np.zeros((self.max_batch,), np.int32)
-        fold = np.zeros((self.max_batch,), np.int32)
-        for slot, r in live:
-            drafts = props.get(slot, [])
-            n_rows[slot] = 1 + len(drafts)
-            tokens[slot, 0] = r.out[-1]
-            tokens[slot, 1:1 + len(drafts)] = drafts
-            greedy[slot] = 0 if r.do_sample else 1
-            temp[slot] = r.temperature
-            topp[slot] = r.top_p
-            topk[slot] = r.top_k
-            seeds[slot] = self._next_seed(r)
-            fold[slot] = 1 if r.seed is None else 0
-        self._step_phase = ("verify", tuple(s for s, _ in live))
-        _faults.maybe_fire("serving.step", rids=[r.rid for _, r in live],
-                           phase="verify")
-        compile_call = not self.runner.has_verify_program(Kv)
-        self.spec_dispatches += 1
-        self._m.verify.inc()
-        t0 = time.perf_counter()
-        with _obs.trace_span("serving.verify"):
+        with _obs.trace_span("engine.prepare"):
+            for slot, r in live:
+                if sched.slots[slot] is not r:
+                    continue        # preempted by an earlier slot's growth
+                sched.ensure_page(slot, ahead=len(props.get(slot, ())) + 1)
+            live = [(s, r) for s, r in live if sched.slots[s] is r]
+            if not live:
+                return 0
+            Kv = ceil_pow2(max(len(props.get(s, ())) + 1 for s, _ in live))
+            tokens = np.zeros((self.max_batch, Kv), np.int32)
+            n_rows = np.zeros((self.max_batch,), np.int32)
+            greedy = np.ones((self.max_batch,), np.int32)
+            temp = np.ones((self.max_batch,), np.float32)
+            topp = np.ones((self.max_batch,), np.float32)
+            topk = np.zeros((self.max_batch,), np.int32)
+            seeds = np.zeros((self.max_batch,), np.int32)
+            fold = np.zeros((self.max_batch,), np.int32)
+            for slot, r in live:
+                drafts = props.get(slot, [])
+                n_rows[slot] = 1 + len(drafts)
+                tokens[slot, 0] = r.out[-1]
+                tokens[slot, 1:1 + len(drafts)] = drafts
+                greedy[slot] = 0 if r.do_sample else 1
+                temp[slot] = r.temperature
+                topp[slot] = r.top_p
+                topk[slot] = r.top_k
+                seeds[slot] = self._next_seed(r)
+                fold[slot] = 1 if r.seed is None else 0
+            self._step_phase = ("verify", tuple(s for s, _ in live))
+            _faults.maybe_fire("serving.step", rids=[r.rid for _, r in live],
+                               phase="verify")
+            compile_call = not self.runner.has_verify_program(Kv)
+            self.spec_dispatches += 1
+            self._m.verify.inc()
+        # timed: the adaptive draft length fits this wall time, switches or no
+        with _obs.trace_span("verify", rid=[r.rid for _, r in live],
+                             trace_id=[r.trace_id for _, r in live],
+                             timed=self._spec.adaptive, rows=Kv) as sp:
             toks = self.runner.run_verify(
                 Kv, tokens, sched.lens, sched.slot_tables, n_rows,
                 greedy, temp, topp, topk, seeds, fold)       # [B, Kv]
-        dt = time.perf_counter() - t0
-        if self._spec.adaptive and not compile_call:
-            self._record_verify_sample(Kv, dt)
-        proposed = accepted = 0
-        for slot, r in live:
-            drafts = props.get(slot, [])
-            n = len(drafts)
-            t = toks[slot]
-            # accept the longest draft prefix the target would have sampled
-            # itself: draft j+1 (fed at row j+1) survives iff it equals the
-            # token sampled from row j's logits
-            a = 0
-            while a < n and drafts[a] == int(t[a]):
-                a += 1
-            proposed += n
-            accepted += a
-            m = a + 1                                    # tokens to emit
-            for j in range(m):
-                if sched.slots[slot] is not r:
-                    break        # eos / max_new released the slot mid-run
-                sched.lens[slot] += 1
-                sched.emit(slot, int(t[j]))
-                self.spec_emitted += 1
-            if sched.slots[slot] is r:
-                # roll back KV pages provisioned for rejected drafts
-                sched.truncate_pages(slot)
-            if not compile_call and _obs.enabled():
-                self._m.token_latency.observe(dt / m)
-        self.spec_proposed += proposed
-        self.spec_accepted += accepted
-        self._m.spec_proposed.inc(proposed)
-        self._m.spec_accepted.inc(accepted)
-        if proposed:
-            ratio = accepted / proposed
-            self._m.spec_acceptance.observe(ratio)
-            self._spec_accept_ema = (
-                ratio if self._spec_accept_ema is None
-                else 0.9 * self._spec_accept_ema + 0.1 * ratio)
+        with _obs.trace_span("engine.emit"):
+            if self._spec.adaptive and not compile_call:
+                self._record_verify_sample(Kv, sp.dur)
+            proposed = accepted = 0
+            for slot, r in live:
+                drafts = props.get(slot, [])
+                n = len(drafts)
+                t = toks[slot]
+                # accept the longest draft prefix the target would have
+                # sampled itself: draft j+1 (fed at row j+1) survives iff it
+                # equals the token sampled from row j's logits
+                a = 0
+                while a < n and drafts[a] == int(t[a]):
+                    a += 1
+                proposed += n
+                accepted += a
+                m = a + 1                                    # tokens to emit
+                for j in range(m):
+                    if sched.slots[slot] is not r:
+                        break    # eos / max_new released the slot mid-run
+                    sched.lens[slot] += 1
+                    sched.emit(slot, int(t[j]))
+                    self.spec_emitted += 1
+                if sched.slots[slot] is r:
+                    # roll back KV pages provisioned for rejected drafts
+                    sched.truncate_pages(slot)
+                if not compile_call and _obs.enabled():
+                    self._m.token_latency.observe(sp.dur / m)
+            self.spec_proposed += proposed
+            self.spec_accepted += accepted
+            self._m.spec_proposed.inc(proposed)
+            self._m.spec_accepted.inc(accepted)
+            if proposed:
+                ratio = accepted / proposed
+                self._m.spec_acceptance.observe(ratio)
+                self._spec_accept_ema = (
+                    ratio if self._spec_accept_ema is None
+                    else 0.9 * self._spec_accept_ema + 0.1 * ratio)
         return len(live)
 
     def _record_verify_sample(self, rows, wall_dt):

@@ -70,6 +70,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
@@ -218,6 +219,7 @@ class _Handler(BaseHTTPRequestHandler):
         # id; the ambient context threads it through routing, the durable
         # plane, RPC frames, and the engines without touching signatures
         _flight.set_proc_label("gateway")
+        self._t_accept = time.perf_counter()
         ctx = _flight.mint(self.headers.get("X-Request-ID") or None)
         self.request_id = ctx.trace_id
         with _flight.use_context(ctx):
@@ -295,6 +297,13 @@ class _Handler(BaseHTTPRequestHandler):
                     self.wfile.flush()
                     continue
                 self._sse({"token": int(tok), "index": i})
+                if i == 0:
+                    # the client has its first token: routing, the engine
+                    # lock, the queue and prefill all lie behind it (the
+                    # durable plane's stream replays a journal, where a
+                    # first event is no first token: not counted there)
+                    _obs.FRONTEND_TTFT.observe(
+                        time.perf_counter() - self._t_accept)
                 i += 1
             status = rs.status(handle)
             _flight.record("gateway_done", trace_id=self.request_id,

@@ -32,6 +32,7 @@ a partially-streamed request may end FAILED).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 import time
@@ -148,12 +149,13 @@ class EngineReplica:
         # record from this thread — label them with the replica's name so a
         # merged trace shows which replica served each phase
         _flight.set_proc_label(f"replica:{self.name}")
-        while True:
-            with self._cv:
-                if self._stop:
-                    return
+        # the thread's time is partitioned by leaf spans: replica.idle,
+        # replica.lock, the engine's own under engine.step, replica.publish
+        with self._cv:
+            while not self._stop:
                 if not self._has_work():
-                    self._cv.wait(self._poll)
+                    with _obs.trace_span("replica.idle"):
+                        self._cv.wait(self._poll)
                     continue
                 try:
                     _faults.FAULTS.maybe_fire("frontend.step",
@@ -171,8 +173,14 @@ class EngineReplica:
                     # so finalize engine-side state now that we are back
                     self._die(self.error)
                     return
-                self._publish()
-                self._cv.notify_all()
+                with _obs.trace_span("replica.publish"):
+                    self._publish()
+                    self._cv.notify_all()
+                # between two steps the condition is dropped and taken again
+                # at once: whoever waits in _engine_lock gets in here, or not
+                with _obs.trace_span("replica.lock"):
+                    self._cv.release()
+                    self._cv.acquire()
 
     def _watch_steps(self):
         """Wall-clock watchdog for the step loop: a step running longer
@@ -258,10 +266,26 @@ class EngineReplica:
             self._cv.notify_all()
 
     # ---- request facade ------------------------------------------------------
+    @contextlib.contextmanager
+    def _engine_lock(self, op):
+        """Hold the engine condition for ``op`` on a thread other than the
+        step loop, counting how long it took to get
+        (``frontend_engine_lock_wait_seconds``): the loop takes the condition
+        back as soon as it drops it, so this wait can last many steps."""
+        with _obs.trace_span("replica.lock_wait", op=op) as sp:
+            self._cv.acquire()
+        try:
+            if sp.dur is not None:
+                _obs.FRONTEND_LOCK_WAIT.observe(sp.dur, replica=self.name,
+                                                op=op)
+            yield
+        finally:
+            self._cv.release()
+
     def load(self):
         """Scheduling pressure: waiting + active requests (the router's
         tie-breaker and the least-loaded fallback metric)."""
-        with self._cv:
+        with self._engine_lock("load"):
             eng = self.engine
             return len(eng._waiting) + sum(
                 1 for s in eng._slots if s is not None)
@@ -269,7 +293,7 @@ class EngineReplica:
     def submit(self, prompt_ids, **kw):
         """Thread-safe ``add_request``; wakes the step loop.  The returned
         rid may already be terminal SHED (engine-level admission)."""
-        with self._cv:
+        with self._engine_lock("submit"):
             if not self.alive:
                 raise ReplicaDeadError(
                     f"replica {self.name!r} is dead: {self.error!r}")
@@ -313,7 +337,7 @@ class EngineReplica:
         # fallback for rids the engine was handed directly: read under the
         # engine condition (may block for a step; such callers own the
         # engine's pace anyway)
-        with self._cv:
+        with self._engine_lock("poll"):
             while True:
                 toks = self.engine.new_tokens(rid)
                 status = self.engine.status(rid)
@@ -328,7 +352,7 @@ class EngineReplica:
                     self._cv.wait(self._poll)
 
     def cancel(self, rid):
-        with self._cv:
+        with self._engine_lock("cancel"):
             ok = self.engine.cancel(rid)
             self._publish()
             self._cv.notify_all()

@@ -386,10 +386,11 @@ class _CacheEntry:
                  "grad_in_list", "out_treedef", "out_mask",
                  "treedef", "guard_kinds", "guard_ints",
                  "scalar_plan", "break_kinds", "op_tape",
-                 "scan_grad_slots", "scan_static")
+                 "scan_grad_slots", "scan_static", "ran")
 
     def __init__(self):
         self.compiled = None
+        self.ran = False         # its first run is the one that compiles
         self.guard_kinds = ()
         self.guard_ints = ()     # specialized guard values, int-normalized
         self.scalar_plan = ()    # ordered kinds of ALL scalar events
@@ -537,7 +538,8 @@ class StaticFunction:
         _state.trace_ctx = ctx
         try:
             args, kwargs = jax.tree_util.tree_unflatten(treedef, leaves)
-            result = self._fn(*args, **kwargs)
+            with _obs.trace_span("jit.capture.eager_pass", fn=self._obs_fn):
+                result = self._fn(*args, **kwargs)
         finally:
             _state.trace_ctx = prev
         entry = _CacheEntry()
@@ -561,7 +563,10 @@ class StaticFunction:
         group.variants.append(entry)
         group.last = entry
         try:
-            self._compile(entry, leaves, ctx.events)
+            # the python replayed into one pure function (scan_steps traces
+            # it here too); XLA's own work waits for the first run
+            with _obs.trace_span("jit.capture.lower", fn=self._obs_fn):
+                self._compile(entry, leaves, ctx.events)
         except _BREAKS as e:
             logger.info("to_static: graph break (%s); signature stays eager",
                         type(e).__name__)
@@ -746,8 +751,8 @@ class StaticFunction:
         after the echo pass confirms the python still follows the traced op
         sequence — a diverged run leaves all framework state untouched so the
         caller can re-run another variant or fall back to eager."""
-        out_vals, write_out, grad_out, guard_out, break_out = entry.compiled(
-            *self._program_args(entry, leaves))
+        out_vals, write_out, grad_out, guard_out, break_out = \
+            self._call_compiled(entry, leaves)
         actual = None
         if entry.guard_kinds:
             actual = tuple(int(v) for v in jax.device_get(guard_out))
@@ -762,6 +767,16 @@ class StaticFunction:
         out_leaves = [Tensor(v) if m else v
                       for v, m in zip(out_vals, entry.out_mask)]
         return jax.tree_util.tree_unflatten(entry.out_treedef, out_leaves), actual
+
+    def _call_compiled(self, entry, leaves):
+        args = self._program_args(entry, leaves)
+        if entry.ran:
+            return entry.compiled(*args)
+        # jit traces, lowers and compiles (or reads its cache) on this call
+        with _obs.trace_span("jit.capture.compile", fn=self._obs_fn):
+            out = entry.compiled(*args)
+        entry.ran = True
+        return out
 
     def _echo(self, entry, leaves, break_vals):
         """Re-run the python with op dispatches short-circuited so side
@@ -912,10 +927,12 @@ class ScanStaticFunction(StaticFunction):
         prev = flags.flag("eager_recompute_grad")
         flags.set_flags({"FLAGS_eager_recompute_grad": True})
         try:
-            for i in range(1, k):
-                args, kwargs = jax.tree_util.tree_unflatten(
-                    treedef, self._slice(leaves, i))
-                results.append(self._fn(*args, **kwargs))
+            with _obs.trace_span("jit.capture.eager_pass", fn=self._obs_fn,
+                                 slices=k - 1):
+                for i in range(1, k):
+                    args, kwargs = jax.tree_util.tree_unflatten(
+                        treedef, self._slice(leaves, i))
+                    results.append(self._fn(*args, **kwargs))
         finally:
             flags.set_flags({"FLAGS_eager_recompute_grad": prev})
         return self._stack_results(results)
@@ -1032,8 +1049,7 @@ class ScanStaticFunction(StaticFunction):
                 [t._buf for t in entry.ro_list])
 
     def _run(self, entry, leaves):
-        ys, fin_state, fin_grads = entry.compiled(
-            *self._program_args(entry, leaves))
+        ys, fin_state, fin_grads = self._call_compiled(entry, leaves)
         for t, arr in zip(entry.write_list, fin_state):
             t._buf = arr
         gmap = dict(zip(entry.scan_grad_slots, fin_grads))

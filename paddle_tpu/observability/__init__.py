@@ -15,8 +15,9 @@ Usage::
     ... run work ...
     snap = obs.snapshot()             # JSON-able dict
     text = obs.render_prometheus()    # Prometheus text exposition
-    with obs.trace_span("my.phase"):  # TraceAnnotation + chrome-trace event
-        ...
+    with obs.trace_span("my.phase", rows=4) as sp:   # TraceAnnotation (with
+        ...                           # its attributes) + span_seconds; and the
+    sp.dur                            # flight recorder for a traced request
     obs.disable()
 
 Cost model: disabled (the default), every instrumented call site pays one
@@ -77,6 +78,10 @@ SERVING_TTFT = REGISTRY.histogram(
 SERVING_TOKEN_LATENCY = REGISTRY.histogram(
     "serving_token_latency_seconds",
     "per-token decode latency (dispatch wall / block size)", ("engine",))
+SERVING_QUEUE_WAIT = REGISTRY.histogram(
+    "serving_queue_wait_seconds",
+    "add_request to the slot: the wait in the engine's admission queue",
+    ("engine",))
 SERVING_QUEUE_DEPTH = REGISTRY.gauge(
     "serving_queue_depth", "requests waiting for admission", ("engine",))
 SERVING_ACTIVE_SLOTS = REGISTRY.gauge(
@@ -186,6 +191,17 @@ FRONTEND_INFLIGHT = REGISTRY.gauge(
 FRONTEND_STREAM_SECONDS = REGISTRY.histogram(
     "frontend_stream_seconds",
     "submit-to-terminal wall time per gateway request")
+FRONTEND_TTFT = REGISTRY.histogram(
+    "frontend_ttft_seconds",
+    "gateway accept to the first token written to the client's socket: "
+    "routing, the engine lock, the queue and prefill included")
+FRONTEND_LOCK_WAIT = REGISTRY.histogram(
+    "frontend_engine_lock_wait_seconds",
+    "time a thread other than the step loop waited for a replica's engine "
+    "condition, by what it wanted it for (submit/cancel/load/poll)",
+    ("replica", "op"),
+    buckets=(0.0001, 0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
+             10.0))
 
 # membership plane (distributed/membership.py); group labels the fleet
 MEMBERSHIP_LEASE_EXPIRIES = REGISTRY.counter(

@@ -154,6 +154,7 @@ def _flash_fwd(q3, k3, v3, scale, causal, nh, nhk, bq=BQ, bk=BK):
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(q3, k3, v3)
     return o, lse
 
@@ -306,6 +307,7 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, scale, causal, nh, nhk, bq=BQ,
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q3.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q3, k3, v3, do3, o3, lse)
 
     # dk/dv: grid batch is the KV row; the combined t axis walks the GQA
@@ -342,6 +344,7 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, scale, causal, nh, nhk, bq=BQ,
             pltpu.VMEM((bk, D), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q3, k3, v3, do3, o3, lse)
     return dq, dk, dv
 
@@ -554,6 +557,7 @@ def _bshd_fwd(q, k, v, scale, causal, bq, bk, hb):
             pltpu.VMEM((hb, bq, D), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(q.reshape(B, Sq, H * D), k.reshape(B, Sk, H * D),
       v.reshape(B, Sk, H * D))
     return o.reshape(B, Sq, H, D), lse
@@ -705,6 +709,7 @@ def _bshd_bwd(q, k, v, o, lse, do, scale, causal, bq, bk, hb):
         out_shape=jax.ShapeDtypeStruct((B, Sq, H * D), q.dtype),
         scratch_shapes=[pltpu.VMEM((hb, bq, D), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q2, k2, v2, do2, o2, lse)
     qspec_t = pl.BlockSpec((1, bq, H * D), lambda b, j, i: (b, i, 0))
     kspec_t = pl.BlockSpec((1, bk, H * D), lambda b, j, i: (b, j, 0))
@@ -724,6 +729,7 @@ def _bshd_bwd(q, k, v, o, lse, do, scale, causal, bq, bk, hb):
             pltpu.VMEM((hb, bk, D), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q2, k2, v2, do2, o2, lse)
     return (dq.reshape(B, Sq, H, D), dk.reshape(B, Sk, H, D),
             dv.reshape(B, Sk, H, D))

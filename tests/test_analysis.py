@@ -362,6 +362,71 @@ def test_no_adhoc_telemetry_line_pragma(tmp_path):
     assert res.findings == [] and res.suppressed == 2
 
 
+AT104_BAD = """
+    import time
+
+
+    def prefill(runner, flight, r):
+        t0 = time.perf_counter()
+        out = runner.run_prefill(r)
+        if r.trace_id is not None:
+            flight.record("prefill", rid=r.rid, dur=time.perf_counter() - t0)
+        return out
+
+
+    def append(hist, fh, payload):
+        t0 = time.perf_counter()
+        fh.write(payload)
+        dt = time.perf_counter() - t0
+        hist.observe(dt / 2)
+"""
+
+AT104_CLEAN = """
+    import time
+
+
+    def decode(self, obs, k):
+        with obs.trace_span("decode", block=k) as sp:
+            toks = self.runner.run_decode(k)
+        self.hist.observe(sp.dur / k)
+        return toks
+
+
+    def stage(self, flight, h):
+        t0 = time.perf_counter()
+        block = self.dispatch(h)
+        dispatch_s = time.perf_counter() - t0
+        flight.record("handoff_dispatch", dur=dispatch_s)
+        self.transfer_s += dispatch_s     # an always-on counter reads it too
+        return block, t0
+
+
+    def stamp(self, hist, r):
+        hist.observe(time.perf_counter() - r.t_submit)   # no pair: a stamp
+"""
+
+
+def _lint_inference(tmp_path, source):
+    d = tmp_path / "inference"
+    d.mkdir()
+    return _lint(d, source, select=["no-adhoc-telemetry"])
+
+
+def test_no_adhoc_telemetry_at104_pair_beside_the_span_call(tmp_path):
+    res = _lint_inference(tmp_path, AT104_BAD)
+    assert [f.code for f in res.findings] == ["AT104", "AT104"]
+    assert all("trace_span" in f.message for f in res.findings)
+
+
+def test_no_adhoc_telemetry_at104_clean_idioms(tmp_path):
+    assert _lint_inference(tmp_path, AT104_CLEAN).findings == []
+
+
+def test_no_adhoc_telemetry_at104_only_in_inference(tmp_path):
+    res = _lint(tmp_path, AT104_BAD, select=["no-adhoc-telemetry"])
+    assert res.findings == []
+
+
 # ----------------------------------------------- sharding-spec-coverage
 
 def _sharding(paths):
@@ -636,6 +701,53 @@ def test_concurrency_cc103_clean_fixture():
     # predicate (which runs WITH the lock held — no CC101 either)
     res = _cc([FIXTURES / "concurrency_cc103_clean.py"])
     assert res.findings == []
+
+
+CC_HOLDER = """
+    import contextlib
+    import threading
+
+
+    class Replica:
+        def __init__(self):
+            self._cv = threading.Condition(threading.RLock())
+            self.queue = []
+
+        @contextlib.contextmanager
+        def _engine_lock(self, op):
+            self._cv.acquire()
+            try:
+                yield
+            finally:
+                self._cv.release()
+
+        def submit(self, item):
+            with self._engine_lock("submit"):
+                self.queue.append(item)
+                self._cv.notify_all()
+
+        def take(self):
+            with self._cv:
+                while not self.queue:
+                    self._cv.wait()
+                return self.queue.pop()
+%s
+"""
+
+CC_HOLDER_BARE = """
+        def peek(self):
+            self._cv.notify_all()          # CC103: nothing held here
+"""
+
+
+def test_concurrency_lock_held_through_a_contextmanager_helper(tmp_path):
+    """``with self._engine_lock(...)`` holds the condition its helper
+    acquires: the notify inside is owned, the queue is guarded."""
+    res = _lint(tmp_path, CC_HOLDER % "", select=["concurrency"])
+    assert res.findings == []
+    res = _lint(tmp_path, CC_HOLDER % CC_HOLDER_BARE, select=["concurrency"],
+                name="bare.py")
+    assert _codes(res) == {"CC103"}
 
 
 def test_concurrency_cc104_bad_fixture():

@@ -358,6 +358,60 @@ def _engine(model, **kw):
     return LLMEngine(model, **kw)
 
 
+class TestSpanInTheRecorder:
+    """``obs.trace_span``'s flight leg: the span a request's trace holds is
+    the one the registry and the profiler hold, with ``dur``, its
+    attributes and the enclosing span as ``parent``."""
+
+    def test_traced_span_carries_dur_attributes_and_parent(self, recorder):
+        with flight.use_context(flight.mint("spantrace")):
+            with obs.trace_span("outer", rid=5, kind="decode"):
+                with obs.trace_span("inner", rid=5, rows=3, ctx_sum=41) as sp:
+                    pass
+        inner, outer = flight.events_for("spantrace")
+        assert (inner["phase"], outer["phase"]) == ("inner", "outer")
+        assert inner["rid"] == 5 and inner["dur"] == pytest.approx(sp.dur)
+        assert inner["args"] == {"parent": "outer", "rows": 3, "ctx_sum": 41}
+        assert outer["args"] == {"parent": None, "kind": "decode"}
+        assert outer["dur"] >= inner["dur"]
+
+    def test_untraced_span_is_the_noop_while_metrics_are_off(self, recorder):
+        from paddle_tpu.observability import tracing
+        assert obs.trace_span("nobody.asked", rows=1) is tracing._NOOP
+        assert obs.trace_span("nobody.asked", rid=[1, 2],
+                              trace_id=[None, None]) is tracing._NOOP
+        assert flight.snapshot_events() == []
+
+    def test_batched_span_records_once_for_each_traced_request(self, recorder):
+        with obs.trace_span("decode", rid=[1, 2, 3],
+                            trace_id=["ta", None, "tc"], block=2) as sp:
+            sp.set(late=1)
+        events = flight.snapshot_events()
+        assert [(e["trace_id"], e["rid"]) for e in events] == [
+            ("ta", 1), ("tc", 3)]
+        assert all(e["dur"] == pytest.approx(sp.dur) and e["args"] == {
+            "parent": None, "block": 2, "late": 1} for e in events)
+
+    def test_engine_phases_come_from_the_span_call(self, recorder, model):
+        eng = _engine(model, prefill_chunk=8)
+        with flight.use_context(flight.mint("enginetrace")):
+            rid = eng.add_request(list(range(1, 12)), max_new_tokens=3)
+            # stepped under the context, the step's own span is live too
+            # and is the parent (a replica's loop has it from metrics)
+            eng.run_until_done()
+        events = {}
+        for e in flight.events_for("enginetrace"):
+            events.setdefault(e["phase"], []).append(e)
+        # two chunks of 8 for 11 prompt tokens, then two decode steps
+        assert [(e["args"]["start"], e["args"]["tokens"])
+                for e in events["prefill"]] == [(0, 8), (8, 3)]
+        assert len(events["decode"]) == 2
+        for e in events["prefill"] + events["decode"]:
+            assert e["rid"] == rid and e["dur"] > 0
+            assert e["args"]["parent"] == "engine.step"
+        assert all(e["args"]["block"] == 1 for e in events["decode"])
+
+
 def _post(url, body, headers=None):
     req = urllib.request.Request(
         f"{url}/v1/completions", data=json.dumps(body).encode(),

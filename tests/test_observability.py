@@ -10,6 +10,7 @@ series zeroed (reset keeps bound children valid by design).
 import os
 import re
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -367,15 +368,19 @@ class TestDispatch:
 # ----------------------------------------------------------------- trace_span
 
 class TestTraceSpan:
-    def test_span_records_host_event_and_histogram(self, metrics):
+    def test_span_counts_once_and_leaves_the_profiler_list_alone(self, metrics):
+        # the span's registry leg; the old ``profiler._host_events`` leg (a
+        # list nothing bounded) is gone: RecordEvent alone writes there
         from paddle_tpu.profiler import _host_events
         _host_events.pop("test.span", None)
-        with obs.trace_span("test.span"):
+        with obs.trace_span("test.span") as sp:
             pass
-        assert len(_host_events["test.span"]) == 1
+        assert "test.span" not in _host_events
         snap = obs.snapshot(prefix="span_seconds",
                             labels={"span": "test.span"})
-        assert snap["span_seconds"]["series"][0]["count"] == 1
+        series = snap["span_seconds"]["series"][0]
+        assert series["count"] == 1
+        assert series["sum"] == pytest.approx(sp.dur)
 
     def test_span_disabled_is_passthrough(self):
         from paddle_tpu.profiler import _host_events
@@ -384,6 +389,48 @@ class TestTraceSpan:
         with obs.trace_span("test.span.off"):
             pass
         assert "test.span.off" not in _host_events
+        snap = obs.snapshot(prefix="span_seconds",
+                            labels={"span": "test.span.off"})
+        assert not snap.get("span_seconds", {}).get("series")
+
+    def test_both_switches_off_is_one_shared_noop_and_reads_no_clock(
+            self, monkeypatch):
+        from paddle_tpu.observability import flight, tracing
+        obs.disable()
+        flight.disable()
+
+        def no_clock():
+            raise AssertionError("a span with nothing listening read the clock")
+        monkeypatch.setattr(tracing.time, "perf_counter", no_clock)
+        a = obs.trace_span("test.off.a", rows=3)
+        b = obs.trace_span("test.off.b", rid=1, trace_id="t")
+        assert a is b is tracing._NOOP
+        with a as sp:
+            sp.set(kind="decode")
+        assert sp.dur is None
+
+    def test_timed_span_keeps_dur_with_both_switches_off(self):
+        from paddle_tpu.observability import flight
+        obs.disable()
+        flight.disable()
+        with obs.trace_span("test.timed", timed=True) as sp:
+            pass
+        assert sp.dur is not None and sp.dur >= 0.0
+        snap = obs.snapshot(prefix="span_seconds",
+                            labels={"span": "test.timed"})
+        assert not snap.get("span_seconds", {}).get("series")
+
+    def test_exception_leaves_the_scope_and_is_counted(self, metrics):
+        with pytest.raises(KeyError):
+            with obs.trace_span("test.span.raises"):
+                raise KeyError("x")
+        snap = obs.snapshot(prefix="span_seconds",
+                            labels={"span": "test.span.raises"})
+        assert snap["span_seconds"]["series"][0]["count"] == 1
+        # the thread's stack of open spans is back where it was
+        with obs.trace_span("test.span.after") as sp:
+            pass
+        assert sp.dur is not None
 
 
 # ------------------------------------------------- profiler scheduler/export
@@ -591,3 +638,129 @@ class TestEngineMetrics:
         typed, samples = _assert_valid_exposition(obs.render_prometheus())
         assert typed["serving_ttft_seconds"] == "histogram"
         assert any(l.startswith("serving_dispatches_total{") for l in samples)
+
+
+# --------------------------------------------- the step loop, span by span
+
+LEAF_SPANS = ("replica.idle", "replica.lock", "engine.admit", "engine.prepare",
+              "runner.dispatch", "runner.launch", "runner.wait", "engine.emit",
+              "replica.publish")
+
+
+def _drain(rep, rid, timeout=60.0):
+    toks = []
+    while True:
+        got, status = rep.poll(rid, timeout=timeout)
+        toks += got
+        if status.terminal:
+            return toks
+
+
+def _loop_thread_events(trace_dir):
+    """The ``TraceAnnotation`` events of the thread that ran ``engine.step``,
+    from the profiler's xplane file: ``(name, start_ns, end_ns, stats)``."""
+    import glob
+    from jax.profiler import ProfileData
+    path = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    host = [p for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:CPU")][0]
+    for line in host.lines:
+        events = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                   dict(e.stats)) for e in line.events]
+        if any(name == "engine.step" for name, *_ in events):
+            return sorted(events, key=lambda e: e[1])
+    raise AssertionError("no thread of the trace ran engine.step")
+
+
+class TestStepLoopSpans:
+    @pytest.fixture
+    def replica(self, metrics, model):
+        from paddle_tpu.inference.frontend.replica import EngineReplica
+        eng = _engine(model, max_batch=4)
+        rep = EngineReplica("r0", eng, poll_interval=0.01).start()
+        # compile both programs before anything is measured
+        _drain(rep, rep.submit(_prompts(seed=9, n=1)[0], max_new_tokens=3))
+        yield rep, eng
+        rep.close()
+
+    def test_leaf_spans_partition_the_loop_and_dispatch_carries_the_spy(
+            self, replica, tmp_path):
+        import jax
+        from bench.traffic.open_loop_http import _spy_on_runner
+        rep, eng = replica
+        spied = []
+        _spy_on_runner([eng], spied)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            time.sleep(0.05)            # a few whole idle waits on each side
+            rids = [rep.submit(p, max_new_tokens=8)
+                    for p in _prompts(seed=11, n=3, shared=10, tail=7)]
+            for rid in rids:
+                assert len(_drain(rep, rid)) == 8
+            time.sleep(0.05)
+        finally:
+            jax.profiler.stop_trace()
+        events = _loop_thread_events(tmp_path)
+        leaves = [e for e in events if e[0] in LEAF_SPANS]
+        assert {name for name, *_ in leaves} == set(LEAF_SPANS)
+        # no two leaves overlap, and together they cover the thread's time
+        # between the first leaf's start and the last one's end
+        for (_, _, end, _), (name, start, _, _) in zip(leaves, leaves[1:]):
+            assert start >= end, (name, start, end)
+        wall = leaves[-1][2] - leaves[0][1]
+        covered = sum(end - start for _, start, end, _ in leaves)
+        assert covered >= 0.95 * wall, (covered, wall)
+        # every step says what it ran
+        kinds = {st.get("kind") for name, _, _, st in events
+                 if name == "engine.step"}
+        assert kinds <= {"prefill", "decode", "none"} and "decode" in kinds
+        # runner.dispatch holds what the benchmark's spy records for the
+        # same dispatches: the xplane file alone feeds the rooflines
+        keys = ("kind", "rows", "ctx_sum", "start", "k")
+        mine = [{k: st[k] for k in keys if k in st}
+                for name, _, _, st in events if name == "runner.dispatch"]
+        spy = [{k: sp[k] for k in keys if k in sp} for sp in spied]
+        assert mine == spy and len(mine) > 8
+
+    def test_span_sums_agree_with_the_dispatch_counter(self, replica):
+        rep, eng = replica
+        obs.reset()
+        _drain(rep, rep.submit(_prompts(seed=12, n=1)[0], max_new_tokens=5))
+        snap = obs.snapshot()
+        count = {s["labels"]["span"]: s["count"]
+                 for s in snap["span_seconds"]["series"]}
+        n = sum(s["value"] for s in snap["serving_dispatches_total"]["series"])
+        assert n >= 5 and count["runner.dispatch"] == n
+        assert count["runner.launch"] == n
+        assert count["engine.step"] >= n
+
+    def test_a_submitter_held_out_by_a_slow_step_is_counted(self, replica):
+        from paddle_tpu.testing.faults import FAULTS, Always
+        rep, eng = replica
+        obs.reset()
+        FAULTS.install("serving.slow_step", Always(), delay=0.1)
+        try:
+            first = rep.submit(_prompts(seed=13, n=1)[0], max_new_tokens=4)
+            deadline = 50
+            while not any(s is not None for s in eng._slots) and deadline:
+                deadline -= 1
+                time.sleep(0.01)
+            # the loop is now inside a step of 0.1 s at the least
+            second = rep.submit(_prompts(seed=14, n=1)[0], max_new_tokens=2)
+        finally:
+            FAULTS.reset()
+        _drain(rep, first)
+        _drain(rep, second)
+        snap = obs.snapshot(prefix="frontend_engine_lock_wait_seconds",
+                            labels={"op": "submit", "replica": "r0"})
+        series = snap["frontend_engine_lock_wait_seconds"]["series"][0]
+        assert series["count"] == 2
+        # the second submit sat out what was left of a 0.1 s step at least
+        assert series["sum"] > 0.02
+        waits = obs.snapshot(prefix="serving_queue_wait_seconds",
+                             labels={"engine": eng._m.label})
+        assert waits["serving_queue_wait_seconds"]["series"][0]["count"] == 2
