@@ -203,20 +203,32 @@ class ModelRunner:
         and where those rows' pages are). With ``mq=(B, Q)`` the flat rows
         are B sequences x Q consecutive query positions and attention goes
         through the multi-query kernel (tables [B, S]; ctx [B] is row 0's
-        context length, row j sees ctx+j); KV writes stay per-flat-row."""
+        context length, row j sees ctx+j); KV writes stay per-flat-row.
+
+        The body's carry is ``(x, cache)``: the WHOLE stacked pools ride
+        beside the activations and layer ``l`` (scanned in as ``wl["l"]``)
+        scatters its rows into them at ``(l, page_idx, within)``. Attention
+        then reads the pages as one flat stack of ``L * n_pages`` (a
+        reshape of the two leading axes: a bitcast) through the tables
+        shifted by ``l * n_pages``; the scale pools of int8 pages, a
+        sixteenth of their size, are written the same way and read by the
+        layer. A pool that is scanned over instead is sliced out per
+        layer, written back per layer and, being donated while still read,
+        copied whole once a dispatch."""
         nh, kvh, D = self.nh, self.kvh, self.D
         eps = self.cfg.rms_norm_eps
         theta = self.cfg.rope_theta
         use_kernel = self.use_kernel
-
         quant = self.kv_quant
+        n_pages = self.n_pages
 
         def layer(carry, wl):
             from ...ops.pallas.paged_attention import (
                 paged_attention, paged_attention_multiquery,
                 paged_attention_multiquery_ref, paged_attention_ref,
                 quantize_kv)
-            x, = carry
+            x, cache = carry
+            l = wl["l"]
             h = _rms(x, wl["ln1"], eps)
             q = (h @ wl["wq"]).reshape(-1, nh, D)
             k = (h @ wl["wk"]).reshape(-1, kvh, D)
@@ -234,40 +246,42 @@ class ModelRunner:
                     out = base(qx.reshape(Bq, Q, nh, D), kp, vp, tb, cl,
                                **kw)
                     return out.reshape(Bq * Q, nh, D)
-            if quant:
+            rows = (k, v)
+            if quant:                       # int8 pages + their scale pools
                 kq, ksc = quantize_kv(k)
                 vq, vsc = quantize_kv(v)
-                kpl = wl["kp"].at[page_idx, within].set(kq)
-                vpl = wl["vp"].at[page_idx, within].set(vq)
-                ksl = wl["kps"].at[page_idx, within].set(ksc)
-                vsl = wl["vps"].at[page_idx, within].set(vsc)
-                att = attn(q, kpl, vpl, tables, ctx,
-                           k_scales=ksl, v_scales=vsl)
-                new_cache = (kpl, vpl, ksl, vsl)
-            else:
-                kpl = wl["kp"].at[page_idx, within].set(k)
-                vpl = wl["vp"].at[page_idx, within].set(v)
-                att = attn(q, kpl, vpl, tables, ctx)
-                new_cache = (kpl, vpl)
+                rows = (kq, vq, ksc, vsc)
+            # write first, read after: the pool has one live value
+            cache = tuple(a.at[l, page_idx, within].set(r)
+                          for a, r in zip(cache, rows))
+            kp, vp = (a.reshape((-1,) + a.shape[2:]) for a in cache[:2])
+            # the scales go in by the layer, under the tables as they came:
+            # the TPU keeps a pool whose last axis is KVH in a layout of
+            # its own, and the kernel's would cost a copy of all of it
+            kw = ({"k_scales": cache[2][l], "v_scales": cache[3][l],
+                   "scale_tables": tables} if quant else {})
+            att = attn(q, kp, vp, tables + l * n_pages, ctx, **kw)
             x = x + att.reshape(-1, nh * D) @ wl["wo"]
             h = _rms(x, wl["ln2"], eps)
             gate = h @ wl["wg"]
             up = h @ wl["wu"]
             x = x + (jax.nn.silu(gate.astype(jnp.float32)).astype(
                 up.dtype) * up) @ wl["wd"]
-            return (x,), new_cache
+            return (x, cache), None
 
         return layer
 
     def _scan_layers(self, W, cache, x, layer):
+        """Run ``layer`` over the stacked weights. Only the weights and the
+        layer's index are scanned; the pools are carried and come back
+        updated in place (see :meth:`_layer_fn` for the copies that
+        avoids)."""
         per_layer = {k: W[k] for k in
                      ("wq", "wk", "wv", "wo", "ln1", "ln2",
                       "wg", "wu", "wd")}
-        per_layer["kp"], per_layer["vp"] = cache[0], cache[1]
-        if len(cache) == 4:
-            per_layer["kps"], per_layer["vps"] = cache[2], cache[3]
-        (x,), new_cache = jax.lax.scan(layer, (x,), per_layer)
-        return x, new_cache
+        per_layer["l"] = jnp.arange(cache[0].shape[0], dtype=jnp.int32)
+        (x, cache), _ = jax.lax.scan(layer, (x, cache), per_layer)
+        return x, cache
 
     # ------------------------------------------------------------- programs
     def _build_decode(self, K):

@@ -75,8 +75,9 @@ def _kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
         o_ref[0] = (acc_s[:] / denom).astype(o_ref.dtype)
 
 
-def _kernel_q(bt_ref, cl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-              m_s, l_s, acc_s, *, scale, page_size, n_slots, kv_heads, group):
+def _kernel_q(bt_ref, cl_ref, st_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+              o_ref, m_s, l_s, acc_s, *, scale, page_size, n_slots, kv_heads,
+              group):
     """int8-page variant (reference capability: block_multihead_attention's
     cache_k_quant_scales/cache_v_quant_scales, dynamic mode): pages carry
     int8 values + a per-(token, kv-head) f32 scale; the kernel dequantizes
@@ -195,9 +196,9 @@ def _kernel_mq(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s,
              kv_heads=kv_heads, group=group, q_len=q_len)
 
 
-def _kernel_mq_q(bt_ref, cl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                 m_s, l_s, acc_s, *, scale, page_size, n_slots, kv_heads,
-                 group, q_len):
+def _kernel_mq_q(bt_ref, cl_ref, st_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+                 o_ref, m_s, l_s, acc_s, *, scale, page_size, n_slots,
+                 kv_heads, group, q_len):
     """int8-page multi-query variant: dequantizes page tiles in VMEM right
     before the MXU dots, exactly like _kernel_q."""
     b = pl.program_id(0)
@@ -227,14 +228,19 @@ def quantize_kv(x):
 
 @functools.partial(jax.jit, static_argnames=("scale",))
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
-                    *, k_scales=None, v_scales=None, scale=None):
+                    *, k_scales=None, v_scales=None, scale_tables=None,
+                    scale=None):
     """Decode-step attention against a paged KV cache.
 
     q:             [B, H, D]       current-step queries
     k_pages/v_pages: [P, page_size, KVH, D]  (int8 when *_scales given)
-    k_scales/v_scales: [P, page_size, KVH] f32 per-token-per-head scales
+    k_scales/v_scales: [P', page_size, KVH] f32 per-token-per-head scales
                    (int8 KV-cache mode; reference: incubate block_multihead_
                    attention.py:47-48 cache_*_quant_scales)
+    scale_tables:  [B, S] int32    page id per (sequence, slot) into the
+                   SCALE arrays, where they are not indexed like the pages
+                   (the engine hands the pages of every layer as one stack
+                   and the scales of one layer); default block_tables
     block_tables:  [B, S] int32    physical page id per (sequence, slot)
     context_lens:  [B]   int32     tokens already in cache (incl. current)
     returns        [B, H, D]
@@ -248,14 +254,18 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
         scale = 1.0 / math.sqrt(D)
     quant = k_scales is not None
 
+    # an index map takes the grid's (b, s) and then every prefetched scalar
     page_spec = pl.BlockSpec((1, page_size, KVH, D),
-                             lambda b, s, bt, cl: (bt[b, s], 0, 0, 0))
+                             lambda b, s, bt, *_: (bt[b, s], 0, 0, 0))
     scale_spec = pl.BlockSpec((1, page_size, KVH),
-                              lambda b, s, bt, cl: (bt[b, s], 0, 0))
-    in_specs = [pl.BlockSpec((1, H, D), lambda b, s, bt, cl: (b, 0, 0)),
+                              lambda b, s, bt, cl, st: (st[b, s], 0, 0))
+    in_specs = [pl.BlockSpec((1, H, D), lambda b, s, *_: (b, 0, 0)),
                 page_spec, page_spec]
-    operands = [block_tables, context_lens, q, k_pages, v_pages]
+    prefetch = [block_tables, context_lens]
+    operands = [q, k_pages, v_pages]
     if quant:
+        prefetch.append(block_tables if scale_tables is None
+                        else scale_tables)
         in_specs += [scale_spec, scale_spec]
         operands += [k_scales, v_scales]
         kern = functools.partial(_kernel_q, scale=scale,
@@ -266,10 +276,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                                  n_slots=S, kv_heads=KVH, group=group)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, S),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, D), lambda b, s, bt, cl: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, D), lambda b, s, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
@@ -280,11 +290,12 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=_interpret(),
-    )(*operands)
+    )(*prefetch, *operands)
 
 
 def paged_attention_ref(q, k_pages, v_pages, block_tables, context_lens,
-                        *, k_scales=None, v_scales=None, scale=None):
+                        *, k_scales=None, v_scales=None, scale_tables=None,
+                        scale=None):
     """jnp reference (gathers pages densely) — golden for the kernel test."""
     B, H, D = q.shape
     P, page_size, KVH, _ = k_pages.shape
@@ -298,10 +309,11 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, context_lens,
         k = k_pages[pages].reshape(S * page_size, KVH, D)
         v = v_pages[pages].reshape(S * page_size, KVH, D)
         if k_scales is not None:                        # int8 pages: dequant
+            sp = pages if scale_tables is None else scale_tables[b_i]
             k = (k.astype(jnp.float32) *
-                 k_scales[pages].reshape(S * page_size, KVH)[..., None])
+                 k_scales[sp].reshape(S * page_size, KVH)[..., None])
             v = (v.astype(jnp.float32) *
-                 v_scales[pages].reshape(S * page_size, KVH)[..., None])
+                 v_scales[sp].reshape(S * page_size, KVH)[..., None])
         cl = context_lens[b_i]
         mask = jnp.arange(S * page_size) < cl
         qh = q[b_i].reshape(KVH, group, D).astype(jnp.float32)
@@ -317,7 +329,7 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, context_lens,
 @functools.partial(jax.jit, static_argnames=("scale",))
 def paged_attention_multiquery(q, k_pages, v_pages, block_tables,
                                context_lens, *, k_scales=None, v_scales=None,
-                               scale=None):
+                               scale_tables=None, scale=None):
     """Verification attention: Q consecutive query positions per sequence
     against the paged KV cache (speculative decoding scores the pending
     token plus all drafts in ONE forward).
@@ -327,7 +339,8 @@ def paged_attention_multiquery(q, k_pages, v_pages, block_tables,
     context_lens:  [B] int32       cache tokens visible to row 0 (incl. its
                                    own just-written entry); row j's causal
                                    horizon is context_lens[b] + j
-    k_pages/v_pages/block_tables/k_scales/v_scales: as paged_attention
+    k_pages/v_pages/block_tables/k_scales/v_scales/scale_tables: as
+                   paged_attention
     returns        [B, Q, H, D]
 
     The kernel streams each page once per sequence for all Q rows (the
@@ -345,13 +358,16 @@ def paged_attention_multiquery(q, k_pages, v_pages, block_tables,
     qf = jnp.transpose(q, (0, 2, 1, 3)).reshape(B, H * Q, D)
 
     page_spec = pl.BlockSpec((1, page_size, KVH, D),
-                             lambda b, s, bt, cl: (bt[b, s], 0, 0, 0))
+                             lambda b, s, bt, *_: (bt[b, s], 0, 0, 0))
     scale_spec = pl.BlockSpec((1, page_size, KVH),
-                              lambda b, s, bt, cl: (bt[b, s], 0, 0))
-    in_specs = [pl.BlockSpec((1, H * Q, D), lambda b, s, bt, cl: (b, 0, 0)),
+                              lambda b, s, bt, cl, st: (st[b, s], 0, 0))
+    in_specs = [pl.BlockSpec((1, H * Q, D), lambda b, s, *_: (b, 0, 0)),
                 page_spec, page_spec]
-    operands = [block_tables, context_lens, qf, k_pages, v_pages]
+    prefetch = [block_tables, context_lens]
+    operands = [qf, k_pages, v_pages]
     if quant:
+        prefetch.append(block_tables if scale_tables is None
+                        else scale_tables)
         in_specs += [scale_spec, scale_spec]
         operands += [k_scales, v_scales]
         kern = functools.partial(_kernel_mq_q, scale=scale,
@@ -363,11 +379,10 @@ def paged_attention_multiquery(q, k_pages, v_pages, block_tables,
                                  kv_heads=KVH, group=group, q_len=Q)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, S),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H * Q, D),
-                               lambda b, s, bt, cl: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H * Q, D), lambda b, s, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((H * Q, 1), jnp.float32),
             pltpu.VMEM((H * Q, 1), jnp.float32),
@@ -378,13 +393,14 @@ def paged_attention_multiquery(q, k_pages, v_pages, block_tables,
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H * Q, D), q.dtype),
         interpret=_interpret(),
-    )(*operands)
+    )(*prefetch, *operands)
     return jnp.transpose(out.reshape(B, H, Q, D), (0, 2, 1, 3))
 
 
 def paged_attention_multiquery_ref(q, k_pages, v_pages, block_tables,
                                    context_lens, *, k_scales=None,
-                                   v_scales=None, scale=None):
+                                   v_scales=None, scale_tables=None,
+                                   scale=None):
     """jnp reference for the multi-query kernel (dense gather, per-row
     causal horizon ctx + j) — golden for the kernel test and the engine's
     CPU path."""
@@ -400,10 +416,11 @@ def paged_attention_multiquery_ref(q, k_pages, v_pages, block_tables,
         k = k_pages[pages].reshape(S * page_size, KVH, D)
         v = v_pages[pages].reshape(S * page_size, KVH, D)
         if k_scales is not None:
+            sp = pages if scale_tables is None else scale_tables[b_i]
             k = (k.astype(jnp.float32) *
-                 k_scales[pages].reshape(S * page_size, KVH)[..., None])
+                 k_scales[sp].reshape(S * page_size, KVH)[..., None])
             v = (v.astype(jnp.float32) *
-                 v_scales[pages].reshape(S * page_size, KVH)[..., None])
+                 v_scales[sp].reshape(S * page_size, KVH)[..., None])
         cl = context_lens[b_i]
         # row j attends tokens [0, cl + j)
         mask = (jnp.arange(S * page_size)[None, :]

@@ -150,3 +150,87 @@ class TestQuantMatmul:
             ((HIDDEN // 2 if int4 else HIDDEN, FFN), jnp.int8),
             ((FFN,), jnp.float32))
         assert "tpu_custom_call" in text
+
+
+class TestServingProgramsCarryThePool:
+    """The engine's whole serving programs on the v5e's compiler, at the
+    attention widths above and a pool far larger than anything else they
+    hold: the pools ride the layer loop and are updated in place.  A pool
+    that is scanned over instead is copied whole once a dispatch and sliced
+    out and written back once a layer (PERF.md section 6, PR 26);
+    tests/test_serving.py holds the CPU's compiler to the same."""
+    L, N_PAGES, FFN, VOCAB, KV = 4, 513, 1024, 1024, 4
+
+    def runner(self, int8):
+        """A ``ModelRunner`` that is shapes alone: there is no device here
+        to hold a weight, so it is not built from a model."""
+        import types
+        from paddle_tpu.inference.engine.runner import ModelRunner
+        r = ModelRunner.__new__(ModelRunner)
+        r.cfg = types.SimpleNamespace(rms_norm_eps=1e-5, rope_theta=1e6)
+        r.mesh = None
+        r.max_batch, r.page, r.chunk = SLOTS, PAGE, CHUNK
+        r.n_pages, r.trash_page = self.N_PAGES, self.N_PAGES - 1
+        r.nh, r.kvh, r.D = NH, KVH, D
+        r.use_kernel, r.kv_quant = True, int8
+        return r
+
+    def arguments(self, kind, int8, sharding):
+        L, F, V, B = self.L, self.FFN, self.VOCAB, SLOTS
+        i32, f32 = jnp.int32, jnp.float32
+
+        def sds(shape, dt=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+        W = {"embed": sds((V, HIDDEN)), "norm": sds((HIDDEN,)),
+             "head": sds((HIDDEN, V)),
+             "wq": sds((L, HIDDEN, NH * D)), "wk": sds((L, HIDDEN, KVH * D)),
+             "wv": sds((L, HIDDEN, KVH * D)), "wo": sds((L, NH * D, HIDDEN)),
+             "ln1": sds((L, HIDDEN)), "ln2": sds((L, HIDDEN)),
+             "wg": sds((L, HIDDEN, F)), "wu": sds((L, HIDDEN, F)),
+             "wd": sds((L, F, HIDDEN))}
+        pages = sds((L, self.N_PAGES, PAGE, KVH, D),
+                    jnp.int8 if int8 else jnp.bfloat16)
+        cache = (pages, pages)
+        if int8:
+            cache += (sds((L, self.N_PAGES, PAGE, KVH), f32),) * 2
+        table = MAX_LEN // PAGE
+        if kind == "prefill":
+            sampling = [sds((), dt) for dt in (i32, f32, f32, i32, i32)]
+            return W, cache, [sds((CHUNK,), i32), sds((), i32),
+                              sds((table,), i32), sds((), i32)] + sampling
+        sampling = [sds((B,), dt) for dt in (i32, f32, f32, i32, i32, i32)]
+        tokens = sds((B, self.KV) if kind == "verify" else (B,), i32)
+        return W, cache, [tokens, sds((B,), i32), sds((B, table), i32),
+                          sds((B,), i32)] + sampling
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("kind", ["decode", "prefill", "verify"])
+    def test_no_pool_among_the_temporaries(self, v5e, kind, int8):
+        import re
+        r = self.runner(int8)
+        prog = {"decode": lambda: r._build_decode(1),
+                "prefill": r._build_prefill,
+                "verify": lambda: r._build_verify(self.KV)}[kind]()
+        W, cache, rest = self.arguments(kind, int8,
+                                        SingleDeviceSharding(v5e[0]))
+        compiled = prog.lower(W, cache, *rest).compile()
+        text = compiled.as_text()
+        pool_bytes = int(np.prod(cache[0].shape)) * cache[0].dtype.itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+        # the page pools; the scale pools of int8 pages are a sixteenth of
+        # them and reach the kernel by the layer (runner._layer_fn says why)
+        for a in cache[:2]:
+            L, n = a.shape[:2]
+            tail = ",".join(map(str, a.shape[2:]))
+            whole = rf"\w+\[(?:{L},{n}|{L * n}),{tail}\]"
+            one_layer = rf"\w+\[(?:1,)?{n},{tail}\]"
+            assert not re.findall(rf"= {whole}(?:\{{[^}}]*\}})? copy\(", text)
+            assert not re.findall(
+                rf"= {one_layer}\S* (?:dynamic-slice|dynamic-update-slice)\(",
+                text)
+        # the kernel is on the path, under the name the benchmark's
+        # paged_attention_roofline looks for (bench/metrics/)
+        name = ("paged_attention_multiquery" if kind == "verify"
+                else "paged_attention")
+        assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text)
+        assert "tpu_custom_call" in text

@@ -59,6 +59,39 @@ class TestQuantizedPagedAttention:
                                    atol=2e-3, rtol=2e-3)
 
 
+    @pytest.mark.parametrize("multiquery", [False, True],
+                             ids=["single", "multiquery"])
+    @pytest.mark.parametrize("kernel", [False, True], ids=["ref", "kernel"])
+    def test_scales_under_their_own_tables(self, kernel, multiquery):
+        """The engine hands the pages of all layers as one stack and the
+        scales of one layer: pages found by ``tables + l * P``, scales by
+        ``tables`` (``scale_tables``).  Same answer as that layer alone."""
+        from paddle_tpu.ops.pallas import paged_attention as pa
+        P, L, layer = 6, 3, 1
+        q, k, v, tables, ctx = _paged_setup(P=P)
+        kq, ks = quantize_kv(k)
+        vq, vs = quantize_kv(v)
+        rng = np.random.RandomState(1)
+
+        def stack(a):       # layer 1 of 3 holds the pages; noise around it
+            noise = rng.randint(-127, 128, (L,) + a.shape).astype(a.dtype)
+            return jnp.asarray(noise).at[layer].set(a).reshape(
+                (L * P,) + a.shape[1:])
+        if multiquery:
+            q = jnp.stack([q, q * 0.5, q + 1.0], axis=1)     # [B, Q, H, D]
+            fn = (pa.paged_attention_multiquery if kernel
+                  else pa.paged_attention_multiquery_ref)
+            ref = pa.paged_attention_multiquery_ref
+        else:
+            fn = pa.paged_attention if kernel else pa.paged_attention_ref
+            ref = pa.paged_attention_ref
+        want = ref(q, kq, vq, tables, ctx, k_scales=ks, v_scales=vs)
+        got = fn(q, stack(kq), stack(vq), tables + layer * P, ctx,
+                 k_scales=ks, v_scales=vs, scale_tables=tables)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-3, rtol=2e-3)
+
+
 class TestEngineInt8Pages:
     def _engines(self, **kw):
         from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM

@@ -573,3 +573,175 @@ class TestAutoDecodeBlock:
         assert eng._block_target == 2         # forced re-sample at small k
         eng._record_block_sample(2, 0.106)
         assert eng._block_target == 32        # model re-solved, back up
+
+
+# ---------------------------------------------------------------- KV pool
+# The serving programs carry the stacked pools through the layer loop and
+# update them in place; a pool that is scanned over is copied whole once a
+# dispatch and sliced out / written back once a layer (PERF.md section 6,
+# PR 26).  These hold the programs to that, and the writes to their rows.
+_POOL_L, _POOL_PAGE, _POOL_B, _POOL_KV = 4, 8, 3, 4
+
+
+@pytest.fixture(scope="module", params=["auto", "int8"], ids=["float", "int8"])
+def pool_runner(request):
+    """``make(n_pages)``: a runner over a tiny model of 4 layers, with pages
+    of the model's dtype or int8 pages.  The model is float32: the CPU
+    backend widens a bfloat16 scatter (and every bfloat16 weight) to
+    float32 and back, which would put a pool among the temporaries here
+    that the chip never sees; tests/test_aot_tpu_compile.py holds the
+    bfloat16 pool to the same on the v5e's compiler."""
+    from paddle_tpu.inference.engine.runner import ModelRunner
+    paddle.seed(0)
+    model = LlamaForCausalLM(LlamaConfig.tiny(num_hidden_layers=_POOL_L))
+    model.eval()
+    made = {}
+
+    def make(n_pages):
+        if n_pages not in made:
+            made[n_pages] = ModelRunner(
+                model, max_batch=_POOL_B, page_size=_POOL_PAGE,
+                prefill_chunk=8, n_pages=n_pages, use_kernel=False,
+                kv_cache_dtype=request.param)
+        return made[n_pages]
+    return make
+
+
+def _program_and_args(r, kind, tables):
+    """The jitted program of ``kind`` and host arguments (after W and the
+    cache) that address ``tables`` [B, S]; the rows each program writes are
+    spelled out in ``_written_rows``."""
+    i32, f32 = np.int32, np.float32
+    B = r.max_batch
+
+    def sampling(shape):
+        return (np.ones(shape, i32), np.ones(shape, f32), np.ones(shape, f32),
+                np.zeros(shape, i32), np.zeros(shape, i32))
+    if kind.startswith("decode"):
+        k = int(kind[6:])
+        return r._build_decode(k), (
+            np.array([5, 9, 7], i32), np.array([3, 10, 13], i32), tables,
+            np.array([1, 1, 0], i32), *sampling(B), np.zeros(B, i32))
+    if kind == "prefill":
+        return r._prefill, (
+            np.arange(1, 9, dtype=i32), i32(4), tables[0], i32(6),
+            *sampling(()))
+    return r._build_verify(_POOL_KV), (
+        np.arange(1, 1 + B * _POOL_KV, dtype=i32).reshape(B, _POOL_KV),
+        np.array([6, 13, 2], i32), tables, np.array([3, 0, 2], i32),
+        *sampling(B), np.zeros(B, i32))
+
+
+def _written_rows(kind, tables, trash):
+    """(page, within) of every row ``_program_and_args``'s dispatch writes,
+    the same in every layer: position p of a slot lands in its table's page
+    p // 8 at row p % 8, an invalid or inactive row in the trash page."""
+    page = _POOL_PAGE
+    if kind == "decode1":       # slots 0, 1 at lens 3, 10; slot 2 inactive
+        return [(tables[0][0], 3), (tables[1][1], 2), (trash, 13 % page)]
+    if kind == "prefill":       # positions 4..9 valid, 10 and 11 padding
+        return ([(tables[0][p // page], p % page) for p in range(4, 10)]
+                + [(trash, 2), (trash, 3)])
+    # verify: slot 0 rows at 6, 7, 8 (+ one padding row at 9), slot 1
+    # inactive (positions 13..16), slot 2 rows at 2, 3 (+ padding at 4, 5)
+    return ([(tables[0][p // page], p % page) for p in (6, 7, 8)]
+            + [(tables[2][0], 2), (tables[2][0], 3)]
+            + [(trash, p % page) for p in (9, 13, 14, 15, 16, 4, 5)])
+
+
+class TestPoolIsCarried:
+    N_PAGES = 512
+
+    @pytest.mark.parametrize("kind",
+                             ["decode1", "decode2", "prefill", "verify"])
+    def test_no_program_copies_a_pool(self, pool_runner, kind):
+        """Temporaries stay under one pool's bytes and no ``copy`` has a
+        pool's shape, stacked, flat or one layer's."""
+        import re
+        import jax.numpy as jnp
+        r = pool_runner(self.N_PAGES)
+        tables = np.arange(_POOL_B * 4, dtype=np.int32).reshape(_POOL_B, 4)
+        prog, args = _program_and_args(r, kind, tables)
+        compiled = prog.lower(r.W, r.cache,
+                              *[jnp.asarray(a) for a in args]).compile()
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < min(a.nbytes for a in r.cache[:2]))
+        text = compiled.as_text()
+        for a in r.cache:               # the scale pools of int8 pages too
+            L, n = a.shape[:2]
+            lead = "|".join((f"{L},{n}", str(L * n), str(n), f"1,{n}"))
+            tail = ",".join(map(str, a.shape[2:]))
+            assert not re.findall(
+                rf"= \w+\[(?:{lead}),{tail}\](?:\{{[^}}]*\}})? copy\(", text)
+
+    @pytest.mark.parametrize("kind", ["decode1", "prefill", "verify"])
+    def test_a_dispatch_writes_its_rows_and_nothing_else(self, pool_runner,
+                                                         kind):
+        """After one dispatch on a pool of known values every row it did not
+        address is bit-identical, in every layer, and its own rows changed
+        in every layer.  What it reads lies in its tables alone: the same
+        dispatch over NaN in every other page gives the same tokens and
+        rows.  And layer ``l`` reads layer ``l``'s pages, not a neighbour's:
+        other values in layer ``j``'s pages leave the rows written in
+        layers up to ``j`` as they were and change those of every later
+        layer (a layer's rows are made from what the layers before it
+        read)."""
+        import jax.numpy as jnp
+        n_pages = 32
+        r = pool_runner(n_pages)
+        trash = r.trash_page
+        # first and last page of a layer among the written ones; the trash
+        # page (the layer's very last) takes the invalid rows
+        tables = np.array([[0, n_pages - 2, 9], [4, n_pages - 2, 11],
+                           [1, 6, 12]], np.int32)
+        if kind == "decode1":
+            tables[0, 1] = 5    # two slots never own one page
+        rows = _written_rows(kind, tables, trash)
+        live = [(p, w) for p, w in rows if p != trash]
+        rng = np.random.RandomState(3)
+
+        def pattern(a):
+            if a.dtype == jnp.int8:
+                return rng.randint(-127, 128, a.shape).astype(np.int8)
+            return rng.uniform(0.5, 1.5, a.shape).astype(a.dtype)
+        before = [pattern(a) for a in r.cache]
+
+        prog, args = _program_and_args(r, kind, tables)   # compiled once
+        args = [jnp.asarray(a) for a in args]
+
+        def dispatch(pools):
+            toks, after = prog(r.W, tuple(jnp.asarray(p) for p in pools),
+                               *args)
+            return np.asarray(toks), [np.asarray(a) for a in after]
+
+        toks, after = dispatch(before)
+        for b, a in zip(before, after):
+            untouched = np.ones(a.shape[:3], bool)
+            for p, w in rows:
+                untouched[:, p, w] = False
+                for l in range(_POOL_L):
+                    assert not np.array_equal(a[l, p, w], b[l, p, w]), (
+                        l, p, w)
+            np.testing.assert_array_equal(a[untouched], b[untouched])
+
+        poisoned = [b.copy() for b in before]
+        off_table = np.setdiff1d(np.arange(n_pages), tables.ravel())
+        for b in poisoned:
+            if b.dtype != np.int8:          # int8 pages: the scales carry it
+                b[:, off_table] = np.nan
+        toks_p, after_p = dispatch(poisoned)
+        np.testing.assert_array_equal(toks, toks_p)
+        for a, ap in zip(after, after_p):
+            for p, w in live:
+                np.testing.assert_array_equal(a[:, p, w], ap[:, p, w])
+
+        for j in range(_POOL_L):
+            shaken = [b.copy() for b in before]
+            for b in shaken:
+                b[j] = pattern(b[j])
+            _, after_j = dispatch(shaken)
+            for a, aj in zip(after, after_j):
+                for p, w in live:
+                    for l in range(_POOL_L):
+                        assert np.array_equal(a[l, p, w], aj[l, p, w]) == (
+                            l <= j), (j, l, p, w)
