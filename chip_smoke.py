@@ -400,7 +400,10 @@ def kernel_phase(sz, llama_cfg, gpt2_heads=(12, 64)):
         close_to(f"flash_attention_bshd {name} {q.shape}", got, want)
 
     # paged attention at the serving shape: every slot's pages scattered
-    # over the pool, context lengths from one token to the whole table
+    # over the pool; context lengths from one token to the whole table, and
+    # ragged - short rows in a long table, each table padded with its last
+    # page as the scheduler pads it, idle slots at one token, one full row:
+    # the kernel's trip count follows each row's own context (PR 29)
     nh, kvh = llama_cfg.num_attention_heads, llama_cfg.num_key_value_heads
     D = llama_cfg.hidden_size // nh
     page, n_slots, Q = 16, sz.max_len // 16, 4
@@ -408,7 +411,13 @@ def kernel_phase(sz, llama_cfg, gpt2_heads=(12, 64)):
     kp, vp = (normal((Bq * n_slots + 1, page, kvh, D)) for _ in range(2))
     tables = jax.random.permutation(next(keys), Bq * n_slots).reshape(
         Bq, n_slots).astype(jnp.int32)
-    ctx = jnp.linspace(1, sz.max_len - Q, Bq).astype(jnp.int32)
+    spread = jnp.linspace(1, sz.max_len - Q, Bq).astype(jnp.int32)
+    ragged = jnp.minimum(jnp.asarray(
+        ([1, 127, 128, 129, 17, 1, 300] * Bq)[:Bq - 1] + [sz.max_len],
+        jnp.int32), sz.max_len - Q)
+    last = jnp.take_along_axis(tables, ((ragged + Q - 2) // page)[:, None], 1)
+    padded = jnp.where(jnp.arange(n_slots)[None, :] * page < ragged[:, None]
+                       + Q - 1, tables, last)
     kq, ks = pa.quantize_kv(kp)
     vq, vs = pa.quantize_kv(vp)
     q1, q4 = normal((Bq, nh, D)), normal((Bq, Q, nh, D))
@@ -419,9 +428,11 @@ def kernel_phase(sz, llama_cfg, gpt2_heads=(12, 64)):
                 (pa.paged_attention, pa.paged_attention_ref, q1),
                 (pa.paged_attention_multiquery,
                  pa.paged_attention_multiquery_ref, q4)):
-            close_to(f"{fn.__name__} {label} q{qx.shape}",
-                     fn(qx, *pages, tables, ctx, **scales),
-                     jax.jit(ref)(qx, *pages, tables, ctx, **scales))
+            for how, tb, ctx in (("spread", tables, spread),
+                                 ("ragged", padded, ragged)):
+                close_to(f"{fn.__name__} {label} q{qx.shape} {how} contexts",
+                         fn(qx, *pages, tb, ctx, **scales),
+                         jax.jit(ref)(qx, *pages, tb, ctx, **scales))
 
     # weight-only matmul at the FFN width (off the main path; the int4
     # branch was changed in this round to lower at all)

@@ -102,15 +102,18 @@ class TestFlashAttention:
 
 
 class TestPagedAttention:
-    N_PAGES = SLOTS * MAX_LEN // PAGE + 1
-    TABLE = MAX_LEN // PAGE
+    """The four entry points of the one walk (PERF.md section 6, PR 29), at
+    the decode and the prefill row counts, on the serving table and on one
+    four times as long: the table is prefetched whole into scalar memory,
+    and the walk's trip count, not the grid, follows the context."""
 
-    def shapes(self, rows, q_shape, int8):
-        pages = ((self.N_PAGES, PAGE, KVH, D),
-                 jnp.int8 if int8 else jnp.bfloat16)
+    @staticmethod
+    def shapes(rows, q_shape, int8, max_len):
+        n_pages = SLOTS * max_len // PAGE + 1
+        pages = ((n_pages, PAGE, KVH, D), jnp.int8 if int8 else jnp.bfloat16)
         return [bf16(*q_shape), pages, pages,
-                ((rows, self.TABLE), jnp.int32), ((rows,), jnp.int32)] + (
-            [((self.N_PAGES, PAGE, KVH), jnp.float32)] * 2 if int8 else [])
+                ((rows, max_len // PAGE), jnp.int32), ((rows,), jnp.int32)] + (
+            [((n_pages, PAGE, KVH), jnp.float32)] * 2 if int8 else [])
 
     @staticmethod
     def call(kernel):
@@ -119,19 +122,24 @@ class TestPagedAttention:
             return kernel(q, kp, vp, tables, ctx, **kw)
         return f
 
+    @pytest.mark.parametrize("max_len", [MAX_LEN, 4 * MAX_LEN],
+                             ids=["table2048", "table8192"])
     @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
     @pytest.mark.parametrize("rows", [SLOTS, CHUNK], ids=["decode", "prefill"])
-    def test_single_query(self, v5e, rows, int8):
+    def test_single_query(self, v5e, rows, int8, max_len):
         text = compile_for(SingleDeviceSharding(v5e[0]),
                            self.call(pa.paged_attention),
-                           *self.shapes(rows, (rows, NH, D), int8))
+                           *self.shapes(rows, (rows, NH, D), int8, max_len))
         assert "tpu_custom_call" in text
 
+    @pytest.mark.parametrize("max_len", [MAX_LEN, 4 * MAX_LEN],
+                             ids=["table2048", "table8192"])
     @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-    def test_multi_query(self, v5e, int8):
+    def test_multi_query(self, v5e, int8, max_len):
         text = compile_for(SingleDeviceSharding(v5e[0]),
                            self.call(pa.paged_attention_multiquery),
-                           *self.shapes(SLOTS, (SLOTS, 4, NH, D), int8))
+                           *self.shapes(SLOTS, (SLOTS, 4, NH, D), int8,
+                                        max_len))
         assert "tpu_custom_call" in text
 
 
