@@ -6,7 +6,6 @@ guard in ``tests/test_analysis.py`` enforces this):
 
     request    Request / RequestStatus / prefix_page_keys — lifecycle types
     metrics    _EngineMetrics — per-engine registry children (labelled)
-    compat     _LegacyDelegation — the pre-split private-attribute surface
     pages      PagePool — paged-KV accounting: refcounts, prefix-cache
                chain-hash index, LRU reclaim, audit
     runner     ModelRunner — the jitted prefill/decode/verify programs and

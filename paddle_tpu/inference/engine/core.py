@@ -4,11 +4,10 @@ The engine composes the three core components behind explicit interfaces —
 :class:`~.scheduler.Scheduler` (admission, deadlines, continuous batching,
 preemption), :class:`~.pages.PagePool` (paged KV accounting, refcounts,
 prefix cache, CoW, rollback), :class:`~.runner.ModelRunner` (prefill /
-decode / verify forwards over a mesh slice) — and keeps the pre-split
-public API byte-for-byte: the frontend, fault-tolerance, and spec-decode
-layers drive it unchanged, and the legacy private attributes
-(``_slots``, ``_free_pages``, ``_waiting``, ...) remain reachable through
-the :class:`~.compat._LegacyDelegation` mixin.
+decode / verify forwards over a mesh slice) — behind the pre-split public
+API: the frontend, fault-tolerance, and spec-decode layers drive it
+unchanged.  State is read where it lives: ``engine.sched``, ``engine.pool``,
+``engine.runner``.
 
 What stays IN the facade is exactly the cross-component orchestration: the
 step loop and its phase policy, step-failure isolation (transient retry →
@@ -32,7 +31,6 @@ from ... import observability as _obs
 from ...observability import flight as _flight
 from ...core.retry import RetryError, RetryPolicy, retry_call
 from ...testing.faults import FAULTS as _faults
-from .compat import _LegacyDelegation
 from .metrics import _EngineMetrics
 from .pages import HostPageStore, PagePool
 from .request import Request, RequestStatus
@@ -65,12 +63,10 @@ class _TransientTier(Exception):
         self.err = err
 
 
-class LLMEngine(_LegacyDelegation, _SpecOrchestration):
-    """Continuous-batching paged-KV engine over a LlamaForCausalLM.
-
-    The pre-split private-attribute surface comes from
-    :class:`~.compat._LegacyDelegation`; the speculative-decode
-    orchestration from :class:`~.spec._SpecOrchestration`."""
+class LLMEngine(_SpecOrchestration):
+    """Continuous-batching paged-KV engine over a LlamaForCausalLM; the
+    speculative-decode orchestration comes from
+    :class:`~.spec._SpecOrchestration`."""
 
     _engine_seq = 0   # observability label: one series set per engine
 
@@ -696,6 +692,16 @@ class LLMEngine(_LegacyDelegation, _SpecOrchestration):
             self._refresh_gauges()
         return _obs.snapshot(prefix="serving_",
                              labels={"engine": self._m.label})
+
+    @property
+    def cache_event_listener(self):
+        """``fn(event, key)`` called on every prefix-cache register / evict /
+        spill (the router's affinity feed, set by ``frontend.replica``)."""
+        return self.pool.cache_event_listener
+
+    @cache_event_listener.setter
+    def cache_event_listener(self, fn):
+        self.pool.cache_event_listener = fn
 
     def prefix_cache_stats(self):
         """Counters for the automatic prefix cache (all zero when the

@@ -23,9 +23,12 @@ TPU-native design (carried over from the monolithic serving engine):
 - KV lives in PAGES [L, n_pages, page, KVH, D]; page tables arrive from the
   scheduler per dispatch.  Pages are just indices here — allocation policy
   (refcounts, prefix cache, preemption) is the PagePool's business.
-- Weights are extracted from the model once, stacked [L, ...] and placed
-  with NamedShardings: layers sharded over the pp axis, head/ffn dims over
-  the mp axis. GSPMD inserts the collectives.
+- The model says what it is: ``model.stacked_weights()`` hands over the
+  weights stacked [L, ...], ``models.llama.stacked_weight_specs`` how they
+  split (layers over the pp axis, head/ffn dims over the mp axis; GSPMD
+  inserts the collectives), and ``block_qkv`` / ``block_out`` are the block
+  on either side of the attention. What is here is the engine's: the pool,
+  the write of a step's rows into its pages, which attention reads them.
 """
 from __future__ import annotations
 
@@ -36,6 +39,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ... import observability as _obs
 from ...core.device import Place
+from ...models.llama import (BLOCK_KEYS, block_out, block_qkv, rms_norm,
+                             stacked_weight_specs)
 
 __all__ = ["ModelRunner"]
 
@@ -49,26 +54,6 @@ def _kernel_applies(device, mesh):
     several devices splits heads (mp) or layers (pp) across them, which the
     kernels do not take; it runs the ``*_ref`` path (ROADMAP S6)."""
     return Place(device).is_tpu_place() and (mesh is None or mesh.size == 1)
-
-
-def _rope(x, pos, theta):
-    """neox-style RoPE at integer positions pos [B] (x [B, Hn, D])."""
-    D = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
-    freqs = pos.astype(jnp.float32)[:, None] * inv[None, :]      # [B, D/2]
-    emb = jnp.concatenate([freqs, freqs], axis=-1)               # [B, D]
-    s, c = jnp.sin(emb)[:, None, :], jnp.cos(emb)[:, None, :]
-    xf = x.astype(jnp.float32)
-    half = D // 2
-    rot = jnp.concatenate([-xf[..., half:], xf[..., :half]], axis=-1)
-    return (xf * c + rot * s).astype(x.dtype)
-
-
-def _rms(x, w, eps):
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)).astype(
-        x.dtype)
 
 
 def _sample_row(logits, greedy, temp, topp, topk, seed):
@@ -126,46 +111,17 @@ class ModelRunner:
             use_kernel = _kernel_applies(self.devices[0], mesh)
         self.use_kernel = use_kernel
 
-        def wb(lin):        # Linear stores weight [in, out]
-            return np.asarray(lin.weight._data)
-
         with _obs.trace_span("engine.build.weights"):
-            lay = model.llama.layers
-            W = {
-                "embed": np.asarray(model.llama.embed_tokens.weight._data),
-                "norm": np.asarray(model.llama.norm.weight._data),
-                "wq": np.stack([wb(l.self_attn.q_proj) for l in lay]),
-                "wk": np.stack([wb(l.self_attn.k_proj) for l in lay]),
-                "wv": np.stack([wb(l.self_attn.v_proj) for l in lay]),
-                "wo": np.stack([wb(l.self_attn.o_proj) for l in lay]),
-                "ln1": np.stack([np.asarray(l.input_layernorm.weight._data)
-                                 for l in lay]),
-                "ln2": np.stack([np.asarray(
-                    l.post_attention_layernorm.weight._data) for l in lay]),
-                "wg": np.stack([wb(l.mlp.gate_proj) for l in lay]),
-                "wu": np.stack([wb(l.mlp.up_proj) for l in lay]),
-                "wd": np.stack([wb(l.mlp.down_proj) for l in lay]),
-            }
-            W["head"] = (np.asarray(model.lm_head.weight._data)
-                         if model.lm_head is not None else W["embed"].T)
+            W = model.stacked_weights()
             dtype = W["wq"].dtype
             if mesh is not None:
                 pp = pp_axis if pp_axis in mesh.axis_names else None
                 mp = mp_axis if mp_axis in mesh.axis_names else None
-
-                def put(name, arr, spec):
-                    # host -> mesh directly: jnp.asarray would stage every
-                    # replica's weights on the default device first
-                    return jax.device_put(arr, NamedSharding(mesh, spec))
-                specs = {
-                    "embed": P(), "norm": P(), "head": P(None, mp),
-                    "wq": P(pp, None, mp), "wk": P(pp, None, mp),
-                    "wv": P(pp, None, mp), "wo": P(pp, mp, None),
-                    "ln1": P(pp, None), "ln2": P(pp, None),
-                    "wg": P(pp, None, mp), "wu": P(pp, None, mp),
-                    "wd": P(pp, mp, None),
-                }
-                self.W = {k: put(k, v, specs[k]) for k, v in W.items()}
+                specs = stacked_weight_specs(pp, mp)
+                # host -> mesh directly: jnp.asarray would stage every
+                # replica's weights on the default device first
+                self.W = {k: jax.device_put(v, NamedSharding(mesh, specs[k]))
+                          for k, v in W.items()}
                 cache_spec = NamedSharding(mesh, P(pp))
             else:
                 self.W = {k: jnp.asarray(v) for k, v in W.items()}
@@ -229,12 +185,7 @@ class ModelRunner:
                 quantize_kv)
             x, cache = carry
             l = wl["l"]
-            h = _rms(x, wl["ln1"], eps)
-            q = (h @ wl["wq"]).reshape(-1, nh, D)
-            k = (h @ wl["wk"]).reshape(-1, kvh, D)
-            v = (h @ wl["wv"]).reshape(-1, kvh, D)
-            q = _rope(q, pos, theta)
-            k = _rope(k, pos, theta)
+            q, k, v = block_qkv(wl, x, pos, nh, kvh, eps, theta)
             if mq is None:
                 attn = paged_attention if use_kernel else paged_attention_ref
             else:
@@ -261,12 +212,7 @@ class ModelRunner:
             kw = ({"k_scales": cache[2][l], "v_scales": cache[3][l],
                    "scale_tables": tables} if quant else {})
             att = attn(q, kp, vp, tables + l * n_pages, ctx, **kw)
-            x = x + att.reshape(-1, nh * D) @ wl["wo"]
-            h = _rms(x, wl["ln2"], eps)
-            gate = h @ wl["wg"]
-            up = h @ wl["wu"]
-            x = x + (jax.nn.silu(gate.astype(jnp.float32)).astype(
-                up.dtype) * up) @ wl["wd"]
+            x = block_out(wl, x, att, eps)
             return (x, cache), None
 
         return layer
@@ -276,9 +222,7 @@ class ModelRunner:
         layer's index are scanned; the pools are carried and come back
         updated in place (see :meth:`_layer_fn` for the copies that
         avoids)."""
-        per_layer = {k: W[k] for k in
-                     ("wq", "wk", "wv", "wo", "ln1", "ln2",
-                      "wg", "wu", "wd")}
+        per_layer = {k: W[k] for k in BLOCK_KEYS}
         per_layer["l"] = jnp.arange(cache[0].shape[0], dtype=jnp.int32)
         (x, cache), _ = jax.lax.scan(layer, (x, cache), per_layer)
         return x, cache
@@ -313,7 +257,7 @@ class ModelRunner:
                 ctx = jnp.where(active > 0, pos + 1, 1).astype(jnp.int32)
                 layer = self._layer_fn(page_idx, within, tables, ctx, pos)
                 x, cache = self._scan_layers(W, cache, x, layer)
-                h = _rms(x, W["norm"], eps)
+                h = rms_norm(x, W["norm"], eps)
                 logits = h.astype(jnp.float32) @ W["head"].astype(
                     jnp.float32)
                 # one vmapped sampler, not B inlined sort/cumsum subgraphs
@@ -354,7 +298,7 @@ class ModelRunner:
             tables = jnp.broadcast_to(table[None, :], (C, table.shape[0]))
             layer = self._layer_fn(page_idx, within, tables, ctx, pos)
             x, cache2 = self._scan_layers(W, cache, x, layer)
-            h = _rms(x, W["norm"], eps)
+            h = rms_norm(x, W["norm"], eps)
             last = h[jnp.maximum(n_valid - 1, 0)]
             logits = last.astype(jnp.float32) @ W["head"].astype(jnp.float32)
             nxt = _sample_row(logits, greedy, temp, topp, topk, seed)
@@ -400,7 +344,7 @@ class ModelRunner:
             layer = self._layer_fn(page_idx, within, tables, cl, pos,
                                    mq=(B, Kv))
             x, cache2 = self._scan_layers(W, cache, x, layer)
-            h = _rms(x, W["norm"], eps)
+            h = rms_norm(x, W["norm"], eps)
             logits = h.astype(jnp.float32) @ W["head"].astype(jnp.float32)
             # seed schedule mirrors the decode block's `seeds + i*fold`:
             # emitted token #j of this step draws the key step #j of a

@@ -141,8 +141,8 @@ class EngineReplica:
             self._watchdog = None
 
     def _has_work(self):
-        eng = self.engine
-        return bool(eng._waiting) or any(s is not None for s in eng._slots)
+        sched = self.engine.sched
+        return bool(sched.waiting) or any(s is not None for s in sched.slots)
 
     def _loop(self):
         # engine-side span events (prefill/decode/first_token/terminal) all
@@ -286,9 +286,9 @@ class EngineReplica:
         """Scheduling pressure: waiting + active requests (the router's
         tie-breaker and the least-loaded fallback metric)."""
         with self._engine_lock("load"):
-            eng = self.engine
-            return len(eng._waiting) + sum(
-                1 for s in eng._slots if s is not None)
+            sched = self.engine.sched
+            return len(sched.waiting) + sum(
+                1 for s in sched.slots if s is not None)
 
     def submit(self, prompt_ids, **kw):
         """Thread-safe ``add_request``; wakes the step loop.  The returned

@@ -9,7 +9,9 @@ from __future__ import annotations
 import math
 
 import numpy as np
+import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .. import ops
 from ..core.tensor import Tensor
@@ -50,40 +52,6 @@ class LlamaConfig:
         return cls(**kw)
 
     @classmethod
-    def qwen2_moe_a14b(cls, **kw):
-        """Qwen2-57B-A14B MoE geometry (public config: 64 experts, top-8,
-        GQA 28q/4kv, 3584 hidden) — BASELINE config #5 family."""
-        kw.setdefault("vocab_size", 151936)
-        kw.setdefault("hidden_size", 3584)
-        kw.setdefault("intermediate_size", 18944)
-        kw.setdefault("num_hidden_layers", 28)
-        kw.setdefault("num_attention_heads", 28)
-        kw.setdefault("num_key_value_heads", 4)
-        kw.setdefault("max_position_embeddings", 32768)
-        kw.setdefault("rope_theta", 1000000.0)
-        kw.setdefault("num_experts", 64)
-        kw.setdefault("num_experts_per_tok", 8)
-        kw.setdefault("moe_intermediate_size", 2560)
-        return cls(**kw)
-
-    @classmethod
-    def deepseek_moe_16b(cls, **kw):
-        """DeepSeekMoE-16B geometry (public config: 64 routed experts, top-6,
-        2048 hidden, 1408 moe-ffn) — BASELINE config #5 family."""
-        kw.setdefault("vocab_size", 102400)
-        kw.setdefault("hidden_size", 2048)
-        kw.setdefault("intermediate_size", 10944)
-        kw.setdefault("num_hidden_layers", 28)
-        kw.setdefault("num_attention_heads", 16)
-        kw.setdefault("num_key_value_heads", 16)
-        kw.setdefault("max_position_embeddings", 4096)
-        kw.setdefault("rope_theta", 10000.0)
-        kw.setdefault("num_experts", 64)
-        kw.setdefault("num_experts_per_tok", 6)
-        kw.setdefault("moe_intermediate_size", 1408)
-        return cls(**kw)
-
-    @classmethod
     def tiny(cls, **kw):
         kw.setdefault("vocab_size", 256)
         kw.setdefault("hidden_size", 64)
@@ -99,6 +67,73 @@ class LlamaConfig:
     def tiny_moe(cls, **kw):
         kw.setdefault("num_experts", 4)
         return cls.tiny(**kw)
+
+
+# ---- the dense block on raw arrays --------------------------------------
+# One definition for the two callers that run on raw arrays: the serving
+# engine's programs (inference/engine/runner.py) and the SPMD pipeline stage
+# (make_decoder_stage). The block comes in two halves around the caller's
+# attention, which is what differs between them (paged against dense). The
+# eager LlamaDecoderLayer goes through the op registry and the training path
+# and is pinned to these by tests/test_serving.py.
+
+# one layer's weights, under these keys; stacked_weights() gives each a
+# leading layer axis
+BLOCK_KEYS = ("ln1", "wq", "wk", "wv", "wo", "ln2", "wg", "wu", "wd")
+
+
+def rms_norm(x, w, eps):
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)).astype(
+        x.dtype)
+
+
+def rope(x, pos, theta):
+    """neox-style RoPE at integer positions pos [B] (x [B, Hn, D])."""
+    D = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    freqs = pos.astype(jnp.float32)[:, None] * inv[None, :]      # [B, D/2]
+    emb = jnp.concatenate([freqs, freqs], axis=-1)               # [B, D]
+    s, c = jnp.sin(emb)[:, None, :], jnp.cos(emb)[:, None, :]
+    xf = x.astype(jnp.float32)
+    half = D // 2
+    rot = jnp.concatenate([-xf[..., half:], xf[..., :half]], axis=-1)
+    return (xf * c + rot * s).astype(x.dtype)
+
+
+def block_qkv(p, x, pos, nh, kvh, eps, theta):
+    """The block up to its attention: norm, the three projections split into
+    heads, RoPE on q and k. x [B, H] rows at positions pos [B]; returns
+    q [B, nh, D], k and v [B, kvh, D]."""
+    D = p["wq"].shape[-1] // nh
+    h = rms_norm(x, p["ln1"], eps)
+    q = (h @ p["wq"]).reshape(-1, nh, D)
+    k = (h @ p["wk"]).reshape(-1, kvh, D)
+    v = (h @ p["wv"]).reshape(-1, kvh, D)
+    return rope(q, pos, theta), rope(k, pos, theta), v
+
+
+def block_out(p, x, att, eps):
+    """The block after its attention (att [B, nh, D]): output projection and
+    residual, norm, SwiGLU (silu in float32), residual."""
+    x = x + att.reshape(x.shape[0], -1) @ p["wo"]
+    h = rms_norm(x, p["ln2"], eps)
+    gate = h @ p["wg"]
+    up = h @ p["wu"]
+    return x + (jax.nn.silu(gate.astype(jnp.float32)).astype(
+        up.dtype) * up) @ p["wd"]
+
+
+def stacked_weight_specs(pp, mp):
+    """PartitionSpecs of ``LlamaForCausalLM.stacked_weights()``'s leaves for
+    mesh axes ``pp`` and ``mp`` (either may be None): the layer axis of the
+    ``BLOCK_KEYS`` leaves over pp, head and ffn dims over mp."""
+    col, row = P(pp, None, mp), P(pp, mp, None)
+    return {"embed": P(), "norm": P(), "head": P(None, mp),
+            "wq": col, "wk": col, "wv": col, "wo": row,
+            "ln1": P(pp, None), "ln2": P(pp, None),
+            "wg": col, "wu": col, "wd": row}
 
 
 class KVCache:
@@ -296,6 +331,39 @@ class LlamaForCausalLM(Layer):
             self.lm_head = Linear(config.hidden_size, config.vocab_size,
                                   weight_attr=Normal(std=config.initializer_range),
                                   bias_attr=False)
+
+    def stacked_weights(self):
+        """The model's weights as the flat dict of host arrays that the raw
+        array block takes: ``embed norm head`` and the ``BLOCK_KEYS`` leaves
+        stacked ``[L, ...]`` (Linear stores weight [in, out]); ``head`` is
+        ``embed.T`` when the embeddings are tied."""
+        lay = self.llama.layers
+        for l in lay:
+            if not isinstance(l.mlp, LlamaMLP):
+                raise NotImplementedError(
+                    "stacked_weights() stacks the dense Llama block only: "
+                    f"this model's layers hold a {type(l.mlp).__name__} "
+                    f"(num_experts={self.config.num_experts})")
+
+        def w(m):
+            return np.asarray(m.weight._data)
+
+        W = {
+            "embed": w(self.llama.embed_tokens),
+            "norm": w(self.llama.norm),
+            "wq": np.stack([w(l.self_attn.q_proj) for l in lay]),
+            "wk": np.stack([w(l.self_attn.k_proj) for l in lay]),
+            "wv": np.stack([w(l.self_attn.v_proj) for l in lay]),
+            "wo": np.stack([w(l.self_attn.o_proj) for l in lay]),
+            "ln1": np.stack([w(l.input_layernorm) for l in lay]),
+            "ln2": np.stack([w(l.post_attention_layernorm) for l in lay]),
+            "wg": np.stack([w(l.mlp.gate_proj) for l in lay]),
+            "wu": np.stack([w(l.mlp.up_proj) for l in lay]),
+            "wd": np.stack([w(l.mlp.down_proj) for l in lay]),
+        }
+        W["head"] = (w(self.lm_head) if self.lm_head is not None
+                     else W["embed"].T)
+        return W
 
     def new_kv_caches(self, batch, max_len, dtype="float32"):
         cfg = self.config
@@ -534,12 +602,10 @@ def causal_lm_loss(logits, labels, vocab_size, aux_loss=None, aux_coef=0.01):
 
 
 def make_decoder_stage(config: LlamaConfig):
-    """Pure-jnp Llama decoder block as (init, apply) — the homogeneous stage
-    function for the SPMD stacked-weight pipeline (parallel/pipeline.py), which
-    runs inside shard_map on raw arrays. Real block: RMSNorm → GQA attention
-    with RoPE → RMSNorm → SwiGLU MLP."""
-    import jax
-
+    """The dense Llama block as (init, apply) on raw arrays — the
+    homogeneous stage function for the SPMD stacked-weight pipeline
+    (parallel/pipeline.py), which runs inside shard_map. The block is
+    ``block_qkv`` / ``block_out`` around a dense causal attention."""
     h = config.hidden_size
     nh, nkv = config.num_attention_heads, config.num_key_value_heads
     hd = h // nh
@@ -560,28 +626,12 @@ def make_decoder_stage(config: LlamaConfig):
             "wd": n(ks[6], (m, h)),
         }
 
-    def _rms(x, w):
-        v = jnp.mean(x.astype(jnp.float32) ** 2, -1, keepdims=True)
-        return (x * jax.lax.rsqrt(v + eps)).astype(x.dtype) * w
-
-    def _rope(x):
-        b, s, n_heads, d = x.shape
-        pos = jnp.arange(s, dtype=jnp.float32)
-        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-        ang = pos[:, None] * freqs[None, :]
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
-        x1, x2 = x[..., ::2], x[..., 1::2]
-        cos = cos[None, :, None, :]
-        sin = sin[None, :, None, :]
-        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-        return out.reshape(x.shape)
-
     def apply(p, x):
         b, s, _ = x.shape
-        y = _rms(x, p["ln1"])
-        q = _rope((y @ p["wq"]).reshape(b, s, nh, hd))
-        k = _rope((y @ p["wk"]).reshape(b, s, nkv, hd))
-        v = (y @ p["wv"]).reshape(b, s, nkv, hd)
+        rows = x.reshape(b * s, h)
+        pos = jnp.tile(jnp.arange(s, dtype=jnp.int32), b)
+        q, k, v = (a.reshape((b, s) + a.shape[1:])
+                   for a in block_qkv(p, rows, pos, nh, nkv, eps, theta))
         if nh != nkv:
             k = jnp.repeat(k, nh // nkv, axis=2)
             v = jnp.repeat(v, nh // nkv, axis=2)
@@ -589,10 +639,9 @@ def make_decoder_stage(config: LlamaConfig):
         mask = jnp.tril(jnp.ones((s, s), bool))
         scores = jnp.where(mask[None, None], scores, -1e30)
         att = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bnst,btnd->bsnd", att, v).reshape(b, s, nh * hd)
-        x = x + o @ p["wo"]
-        y = _rms(x, p["ln2"])
-        return x + (jax.nn.silu(y @ p["wg"]) * (y @ p["wu"])) @ p["wd"]
+        o = jnp.einsum("bnst,btnd->bsnd", att, v)
+        return block_out(p, rows, o.reshape(b * s, nh, hd), eps).reshape(
+            x.shape)
 
     return init, apply
 

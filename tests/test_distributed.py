@@ -773,11 +773,11 @@ class TestTopKGating:
         import paddle_tpu as paddle
         from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
         paddle.seed(0)
-        cfg = LlamaConfig.deepseek_moe_16b(
+        cfg = LlamaConfig(
             vocab_size=128, hidden_size=64, intermediate_size=128,
             num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
-            max_position_embeddings=64, num_experts=8,
-            moe_intermediate_size=32)
+            max_position_embeddings=64, rope_theta=10000.0, num_experts=8,
+            num_experts_per_tok=6, moe_intermediate_size=32)
         assert cfg.num_experts_per_tok == 6
         model = LlamaForCausalLM(cfg)
         assert model.llama.layers[0].mlp.top_k == 6

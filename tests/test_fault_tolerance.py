@@ -206,7 +206,7 @@ class TestServingChaos:
         rids = [eng.add_request(p, max_new_tokens=6) for p in prompts]
         # request #2 is expired before it can finish; #3 is poison (its
         # batched decode dispatches always fail; probes pin the blame)
-        eng._waiting[2].deadline = time.perf_counter() - 1.0
+        eng.sched.waiting[2].deadline = time.perf_counter() - 1.0
         eng._any_deadline = True
         poison = rids[3]
         FAULTS.install("serving.page_alloc", FailNth({2, 5, 9}))
@@ -224,7 +224,7 @@ class TestServingChaos:
         for i in (0, 1, 4):                      # the survivors
             assert statuses[i] == RequestStatus.FINISHED
             assert eng.result(rids[i]) == ref[i], i
-        assert eng.quarantined == 1 and eng.timeouts == 1
+        assert eng.sched.quarantined == 1 and eng.sched.timeouts == 1
         assert eng.step_failures >= 1
         assert eng.audit_refcounts() == []       # zero leaked pages
         h = eng.health()
@@ -263,7 +263,7 @@ class TestServingChaos:
         rids = [eng.add_request(p, max_new_tokens=5) for p in prompts]
         with injected("serving.step", FailNth({2, 7}), transient=True):
             eng.run_until_done()
-        assert eng.step_retries >= 1 and eng.quarantined == 0
+        assert eng.step_retries >= 1 and eng.sched.quarantined == 0
         for rr, r in zip(ref, rids):
             assert eng.status(r) == RequestStatus.FINISHED
             assert eng.result(r) == ref_eng.result(rr)
@@ -303,7 +303,8 @@ class TestServingChaos:
         rid = eng.add_request([1, 2, 3, 4], max_new_tokens=50, deadline=30.0)
         for _ in range(4):                       # prefill + a few tokens
             eng.step()
-        r = eng._slots[[s is not None for s in eng._slots].index(True)]
+        slots = eng.sched.slots
+        r = slots[[s is not None for s in slots].index(True)]
         n_before = len(r.out)
         assert n_before >= 1
         r.deadline = time.perf_counter() - 1.0   # force expiry
@@ -320,7 +321,7 @@ class TestServingChaos:
         rid = eng.add_request(rng.randint(1, 128, (30,)), max_new_tokens=4)
         other = eng.add_request(rng.randint(1, 128, (5,)), max_new_tokens=4)
         eng.step()                               # first prefill chunk only
-        r = next(s for s in eng._slots if s is not None and s.rid == rid)
+        r = next(s for s in eng.sched.slots if s is not None and s.rid == rid)
         assert r.pos < len(r.prompt)             # genuinely mid-prefill
         assert eng.cancel(rid) is True
         eng.run_until_done()
@@ -336,14 +337,14 @@ class TestServingChaos:
         eng = self._engine(model, prefix_cache=True, max_batch=2)
         prompt = list(range(1, 25))              # three full 8-token pages
         a = eng.add_request(prompt, max_new_tokens=8)
-        while eng._waiting:                      # admit + let pages register
+        while eng.sched.waiting:          # admit + let pages register
             eng.step()
         for _ in range(3):
             eng.step()
         b = eng.add_request(prompt, max_new_tokens=8)  # shares a's pages
-        while eng._waiting:
+        while eng.sched.waiting:
             eng.step()
-        assert eng.cache_hits > 0                # b really did share pages
+        assert eng.pool.cache_hits > 0    # b really did share pages
         assert eng.cancel(a) is True             # free sharer mid-flight
         eng.step()
         assert eng.audit_refcounts() == []       # shared pages survived
@@ -374,7 +375,7 @@ class TestServingChaos:
         # nothing has been admitted to a slot yet, so all five queue:
         # the bound of 2 sheds the last three
         shed = [r for r in rids if eng.status(r) == RequestStatus.SHED]
-        assert len(shed) == 3 and eng.shed_requests == 3
+        assert len(shed) == 3 and eng.sched.shed_requests == 3
         eng.run_until_done()
         for r in rids:
             if r not in shed:

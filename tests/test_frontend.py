@@ -360,7 +360,7 @@ class TestReplicaSet:
     def test_engine_level_shed_surfaces_as_shed_error(self, model):
         rs = _replica_set(model, n=1)
         try:
-            rs.replicas[0].engine.max_waiting = 0    # engine refuses all
+            rs.replicas[0].engine.sched.max_waiting = 0    # engine refuses all
             with pytest.raises(ShedError) as ei:
                 rs.submit(_prompts(1)[0], max_new_tokens=4)
             assert ei.value.reason == "engine"
@@ -653,7 +653,7 @@ class TestGatewayHTTP:
         conn.close()                        # walk away mid-stream
         deadline = time.monotonic() + 60.0
         while time.monotonic() < deadline:
-            done = [r.engine._finished for r in rs.replicas]
+            done = [r.engine.sched.finished for r in rs.replicas]
             statuses = [req.status for fin in done for req in fin.values()]
             if RequestStatus.CANCELLED in statuses:
                 break

@@ -70,7 +70,7 @@ def _serve_one_by_one(eng, prompts, **req_kw):
         rid = eng.add_request(p, **req_kw)
         eng.run_until_done()
         outs.append(eng.result(rid))
-        disp.append(eng._finished[rid].prefill_dispatches)
+        disp.append(eng.sched.finished[rid].prefill_dispatches)
     return outs, disp
 
 

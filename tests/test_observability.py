@@ -746,7 +746,7 @@ class TestStepLoopSpans:
         try:
             first = rep.submit(_prompts(seed=13, n=1)[0], max_new_tokens=4)
             deadline = 50
-            while not any(s is not None for s in eng._slots) and deadline:
+            while not any(s is not None for s in eng.sched.slots) and deadline:
                 deadline -= 1
                 time.sleep(0.01)
             # the loop is now inside a step of 0.1 s at the least

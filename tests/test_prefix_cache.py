@@ -52,7 +52,7 @@ def _serve_one_by_one(eng, prompts, **req_kw):
         rid = eng.add_request(p, **req_kw)
         eng.run_until_done()
         outs.append(eng.result(rid))
-        disp.append(eng._finished[rid].prefill_dispatches)
+        disp.append(eng.sched.finished[rid].prefill_dispatches)
     return outs, disp
 
 
@@ -96,17 +96,18 @@ class TestPrefixCache:
         def serve(eng):
             r1 = eng.add_request(p, max_new_tokens=8)
             eng.step()                       # admit + first prefill chunk
-            while eng._slots[0] is not None and eng._slots[0].pos < len(p):
+            while (eng.sched.slots[0] is not None
+                   and eng.sched.slots[0].pos < len(p)):
                 eng.step()                   # r1 prefilled, still decoding
             r2 = eng.add_request(p, max_new_tokens=8)
             eng.run_until_done()
             return eng.result(r1), eng.result(r2)
 
         ref = serve(eng_off)
-        cow0 = eng_on.cache_cow_copies
+        cow0 = eng_on.pool.cache_cow_copies
         got = serve(eng_on)
         assert got == ref
-        assert eng_on.cache_cow_copies > cow0, eng_on.prefix_cache_stats()
+        assert eng_on.pool.cache_cow_copies > cow0, eng_on.prefix_cache_stats()
 
     def test_eviction_under_pool_pressure(self, model):
         """Pool far smaller than the distinct-prompt working set: cached
@@ -121,7 +122,7 @@ class TestPrefixCache:
         eng = _engine(model, True, **kw)
         got, _ = _serve_one_by_one(eng, prompts, max_new_tokens=4)
         assert got == ref
-        assert eng.cache_evictions >= 1, eng.prefix_cache_stats()
+        assert eng.pool.cache_evictions >= 1, eng.prefix_cache_stats()
 
     def test_preemption_oversubscription_parity(self, model):
         """Concurrent slots + a pool too small for everyone's decode growth:
@@ -147,20 +148,20 @@ class TestPrefixCache:
         got = serve(eng)
         assert got == ref
         # the configuration must actually exercise the oversubscribed path
-        assert eng.preemptions + ref_eng.preemptions > 0
+        assert eng.sched.preemptions + ref_eng.sched.preemptions > 0
 
     def test_knob_off_is_legacy_engine(self, eng_off):
-        assert len(eng_off._finished) > 0      # served earlier tests
+        assert len(eng_off.sched.finished) > 0      # served earlier tests
         st = eng_off.prefix_cache_stats()
         assert st["hits"] == st["misses"] == st["evictions"] == 0
         assert st["cached_pages"] == 0 and st["reclaimable_pages"] == 0
         # every page back on the free list, exactly as before the feature
-        assert len(eng_off._free_pages) == eng_off.n_pages - 1
+        assert len(eng_off.pool.free_pages) == eng_off.n_pages - 1
 
     def test_stats_and_full_recycle_with_cache_on(self, eng_on):
         st = eng_on.prefix_cache_stats()
         assert st["hits"] > 0 and st["cached_pages"] > 0
         assert st["prefill_dispatches"] > 0
         # all pages accounted for: free + reclaimable == whole pool
-        assert (len(eng_on._free_pages) + len(eng_on._lru)) \
+        assert (len(eng_on.pool.free_pages) + len(eng_on.pool.lru)) \
             == eng_on.n_pages - 1

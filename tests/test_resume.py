@@ -395,7 +395,7 @@ class TestGatewayKeepAlive:
             while time.monotonic() < deadline:
                 statuses = [req.status
                             for r in rs.replicas
-                            for req in r.engine._finished.values()]
+                            for req in r.engine.sched.finished.values()]
                 if RequestStatus.CANCELLED in statuses:
                     break
                 time.sleep(0.1)

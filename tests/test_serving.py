@@ -237,9 +237,10 @@ class TestLLMEngine:
         rids = [eng.add_request(rng.randint(1, 128, (4 + i,)),
                                 max_new_tokens=4) for i in range(4)]
         steps = eng.run_until_done()
-        assert steps > 0 and len(eng._finished) == 4
+        assert steps > 0 and len(eng.sched.finished) == 4
         assert all(len(eng.result(r)) == 4 for r in rids)
-        assert len(eng._free_pages) == eng.n_pages - 1  # all pages recycled
+        # all pages recycled
+        assert len(eng.pool.free_pages) == eng.n_pages - 1
 
     def test_streaming_accessor_parity(self):
         """new_tokens(rid) is incremental and lossless: concatenating every
@@ -254,7 +255,8 @@ class TestLLMEngine:
         rids = [eng.add_request(rng.randint(1, 128, (4 + i,)),
                                 max_new_tokens=5) for i in range(4)]
         seen = {r: [] for r in rids}
-        while eng._waiting or any(s is not None for s in eng._slots):
+        while eng.sched.waiting or any(
+                s is not None for s in eng.sched.slots):
             eng.step()
             for r in rids:
                 inc = eng.new_tokens(r)
@@ -407,18 +409,19 @@ class TestEngineRound4:
                         prefill_chunk=8)
         rid = eng.add_request(prompt, max_new_tokens=40)
         eng.step()                       # prefill: exactly 1 page in use
-        used_after_prefill = eng.n_pages - 1 - len(eng._free_pages)
+        used_after_prefill = eng.n_pages - 1 - len(eng.pool.free_pages)
         assert used_after_prefill == 1   # NOT ceil((8+40)/8)=6
         # force an early finish via eos on the next emitted token
-        eng._slots[0].eos = None
+        eng.sched.slots[0].eos = None
         for _ in range(9):               # 9 decode tokens -> 17 total -> 3 pages
             eng.step()
-        used = eng.n_pages - 1 - len(eng._free_pages)
+        used = eng.n_pages - 1 - len(eng.pool.free_pages)
         assert used == 3, used
-        eng._slots[0].eos = eng._slots[0].out[-1]  # any token; then match it
+        # any token; then match it
+        eng.sched.slots[0].eos = eng.sched.slots[0].out[-1]
         # run until the engine emits that token again or request completes
         eng.run_until_done()
-        assert len(eng._free_pages) == eng.n_pages - 1   # all freed
+        assert len(eng.pool.free_pages) == eng.n_pages - 1   # all freed
 
     def test_preemption_recovers_and_completes(self):
         """With an OVERSUBSCRIBED page_pool (smaller than worst case) the
@@ -435,11 +438,11 @@ class TestEngineRound4:
         rids = [eng.add_request(rng.randint(1, 128, (8,)).astype(np.int32),
                                 max_new_tokens=16) for _ in range(3)]
         eng.run_until_done()
-        assert eng.preemptions > 0          # oversubscription really bit
-        assert len(eng._finished) == 3
+        assert eng.sched.preemptions > 0          # oversubscription really bit
+        assert len(eng.sched.finished) == 3
         for rid in rids:
             assert len(eng.result(rid)) == 16
-        assert len(eng._free_pages) == eng.n_pages - 1
+        assert len(eng.pool.free_pages) == eng.n_pages - 1
 
     def test_add_request_validation(self):
         import numpy as np
@@ -488,9 +491,9 @@ class TestEngineRound4:
         rids = [eng.add_request(rng.randint(1, 128, (8,)).astype(np.int32),
                                 max_new_tokens=16) for _ in range(3)]
         eng.run_until_done()
-        assert eng.preemptions >= 2
+        assert eng.sched.preemptions >= 2
         for rid in rids:
-            r = eng._finished[rid]
+            r = eng.sched.finished[rid]
             assert len(r.out) == 16
             assert r.prompt == r.prompt0 + r.out[:len(r.prompt) - 8] \
                 or len(r.prompt) == 8      # never double-folded
@@ -745,3 +748,96 @@ class TestPoolIsCarried:
                     for l in range(_POOL_L):
                         assert np.array_equal(a[l, p, w], aj[l, p, w]) == (
                             l <= j), (j, l, p, w)
+
+
+# --- one definition of the dense Llama block on raw arrays ------------------
+# models/llama.py holds the block once (block_qkv / block_out, around the
+# caller's attention): the engine's programs and the SPMD pipeline stage call
+# it, and the eager LlamaDecoderLayer, which runs through the op registry, is
+# held to it here.
+
+@pytest.fixture(scope="module", params=[2, 4], ids=["gqa", "mha"])
+def block_case(request):
+    """(cfg, model, layer 0's weights, x [2, 9, H], the eager layer's
+    output) for a float32 tiny Llama with 4 query heads over 2 or 4 KV
+    heads."""
+    from paddle_tpu.models.llama import BLOCK_KEYS
+    paddle.seed(0)
+    cfg = LlamaConfig.tiny(num_key_value_heads=request.param)
+    model = LlamaForCausalLM(cfg)
+    model.eval()
+    W = model.stacked_weights()
+    p = {k: W[k][0] for k in BLOCK_KEYS}
+    x = np.random.RandomState(1).randn(2, 9, cfg.hidden_size).astype(
+        np.float32)
+    with paddle.no_grad():
+        want = model.llama.layers[0](paddle.to_tensor(x)).numpy()
+    return cfg, model, p, x, want
+
+
+class TestLlamaBlockOnRawArrays:
+    # float32 throughout, activations of O(1): the two sides differ in the
+    # order of float32 operations alone (a RoPE table against sin/cos at the
+    # positions, where the attention scales), which reads as one ulp here
+    # (1.2e-7). 1e-5 absolute is eighty times that, and a hundredth of what
+    # rotating interleaved pairs in place of half-split lanes gives (1.5e-3)
+    ATOL = 1e-5
+
+    def test_halves_around_plain_attention_equal_the_eager_layer(
+            self, block_case):
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.models.llama import block_out, block_qkv
+        cfg, _, p, x, want = block_case
+        nh, kvh = cfg.num_attention_heads, cfg.num_key_value_heads
+        b, s, H = x.shape
+        rows = jnp.asarray(x).reshape(b * s, H)
+        pos = jnp.tile(jnp.arange(s, dtype=jnp.int32), b)
+        q, k, v = (a.reshape((b, s) + a.shape[1:]) for a in block_qkv(
+            p, rows, pos, nh, kvh, cfg.rms_norm_eps, cfg.rope_theta))
+        k, v = (jnp.repeat(a, nh // kvh, axis=2) for a in (k, v))
+        sc = jnp.einsum("bsnd,btnd->bnst", q, k) / np.sqrt(q.shape[-1])
+        sc = jnp.where(jnp.tril(jnp.ones((s, s), bool)), sc, -jnp.inf)
+        att = jnp.einsum("bnst,btnd->bsnd", jax.nn.softmax(sc, axis=-1), v)
+        got = block_out(p, rows, att.reshape(b * s, nh, -1),
+                        cfg.rms_norm_eps).reshape(x.shape)
+        np.testing.assert_allclose(np.asarray(got), want, atol=self.ATOL,
+                                   rtol=0)
+
+    def test_pipeline_stage_equals_the_eager_layer(self, block_case):
+        """The stage once rotated interleaved pairs where the model rotates
+        half-split lanes: another function of the same weights."""
+        import jax.numpy as jnp
+        from paddle_tpu.models.llama import make_decoder_stage
+        cfg, _, p, x, want = block_case
+        _, apply = make_decoder_stage(cfg)
+        got = apply(p, jnp.asarray(x))
+        np.testing.assert_allclose(np.asarray(got), want, atol=self.ATOL,
+                                   rtol=0)
+
+    def test_stacked_weights_and_their_specs(self, block_case):
+        from paddle_tpu.models.llama import BLOCK_KEYS, stacked_weight_specs
+        cfg, model, _, _, _ = block_case
+        W = model.stacked_weights()
+        specs = stacked_weight_specs("pp", "mp")
+        assert set(W) == set(specs) == set(BLOCK_KEYS) | {
+            "embed", "norm", "head"}
+        for k, a in W.items():
+            assert len(specs[k]) <= a.ndim, k
+            # the layer axis leads the block's leaves, and only those
+            assert (tuple(specs[k][:1]) == ("pp",)) == (k in BLOCK_KEYS), k
+            if k in BLOCK_KEYS:
+                assert a.shape[0] == cfg.num_hidden_layers, k
+        assert W["head"].shape == (cfg.hidden_size, cfg.vocab_size)
+
+    def test_tied_embeddings_give_the_head(self):
+        paddle.seed(0)
+        model = LlamaForCausalLM(LlamaConfig.tiny(tie_word_embeddings=True))
+        W = model.stacked_weights()
+        np.testing.assert_array_equal(W["head"], W["embed"].T)
+
+    def test_a_moe_model_is_refused_by_name(self):
+        paddle.seed(0)
+        model = LlamaForCausalLM(LlamaConfig.tiny_moe())
+        with pytest.raises(NotImplementedError, match="MoELayer"):
+            model.stacked_weights()

@@ -51,13 +51,14 @@ def _serve(eng, prompts, **req_kw):
 
 def _check_page_accounting(eng):
     """Pool conservation + per-slot allocation exactly covers each length."""
-    alloc = sum(int(eng._n_alloc[s]) for s in range(eng.max_batch))
-    assert alloc + len(eng._free_pages) + len(eng._lru) == eng.n_pages - 1
-    for s, r in enumerate(eng._slots):
+    alloc = sum(int(eng.sched.n_alloc[s]) for s in range(eng.max_batch))
+    assert (alloc + len(eng.pool.free_pages) + len(eng.pool.lru)
+            == eng.n_pages - 1)
+    for s, r in enumerate(eng.sched.slots):
         if r is None:
             continue
-        lens = int(eng._lens[s])
-        assert int(eng._n_alloc[s]) >= max(1, -(-lens // eng.page))
+        lens = int(eng.sched.lens[s])
+        assert int(eng.sched.n_alloc[s]) >= max(1, -(-lens // eng.page))
 
 
 # ---------------------------------------------------------------- the kernel
@@ -202,17 +203,17 @@ class TestRollback:
                       max_len=64, max_batch=2)
         rids = [eng.add_request(p[:12], max_new_tokens=24)
                 for p in _PROMPTS[:2]]
-        while eng._waiting or any(s is not None for s in eng._slots):
+        while eng.sched.waiting or any(s is not None for s in eng.sched.slots):
             eng.step()
             # after every step: allocation exactly covers the committed
             # length (truncation freed everything past it) and the pool sums
-            for s, r in enumerate(eng._slots):
+            for s, r in enumerate(eng.sched.slots):
                 # mid-prefill slots hold the whole prompt's reservation;
                 # the tight bound applies once decode/verify is running
                 if r is None or r.pos < len(r.prompt):
                     continue
-                lens = int(eng._lens[s])
-                assert int(eng._n_alloc[s]) == max(1, -(-lens // 4))
+                lens = int(eng.sched.lens[s])
+                assert int(eng.sched.n_alloc[s]) == max(1, -(-lens // 4))
             _check_page_accounting(eng)
         base = _serve(_engine(model, None, page_size=4, max_len=64,
                               max_batch=2),
@@ -223,8 +224,9 @@ class TestRollback:
     def test_pool_drains_clean_after_spec_serve(self, model):
         eng = _engine(model, SpecConfig(max_draft=4))
         _serve(eng, _PROMPTS)
-        assert sum(int(eng._n_alloc[s]) for s in range(eng.max_batch)) == 0
-        assert len(eng._free_pages) + len(eng._lru) == eng.n_pages - 1
+        assert sum(int(eng.sched.n_alloc[s])
+                   for s in range(eng.max_batch)) == 0
+        assert len(eng.pool.free_pages) + len(eng.pool.lru) == eng.n_pages - 1
 
 
 # ------------------------------------------------------------- prefix cache
@@ -320,7 +322,7 @@ class TestSpecConfigAndMetrics:
         eng = _engine(model, SpecConfig(max_draft=4), decode_block="auto")
         n_decode_dispatch = 0
         rids = [eng.add_request(p, max_new_tokens=20) for p in _PROMPTS]
-        while eng._waiting or any(s is not None for s in eng._slots):
+        while eng.sched.waiting or any(s is not None for s in eng.sched.slots):
             before = eng.spec_dispatches
             eng.step()
             if eng.spec_dispatches == before:
