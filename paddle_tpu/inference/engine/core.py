@@ -357,6 +357,7 @@ class LLMEngine(_SpecOrchestration):
             r.prefill_dispatches += 1
             self.prefill_dispatches += 1
             self._m.prefill.inc()
+            self._m.count_argmax("prefill", (r,))
         with _obs.trace_span("prefill", rid=r.rid, trace_id=r.trace_id,
                              tokens=n, start=start):
             nxt = self.runner.run_prefill(
@@ -458,6 +459,7 @@ class LLMEngine(_SpecOrchestration):
                                phase="decode")
             compile_call = not self.runner.has_decode_program(k)
             self._m.decode.inc()
+            self._m.count_argmax("decode", (r for _, r in live))
         # timed: the auto-fit below needs the wall time whatever is switched on
         with _obs.trace_span("decode", rid=[r.rid for _, r in live],
                              trace_id=[r.trace_id for _, r in live],
@@ -605,6 +607,7 @@ class LLMEngine(_SpecOrchestration):
             return                # growth preempted the probe target
         args = self._decode_args([(slot, r)])
         self._m.decode.inc()
+        self._m.count_argmax("decode", (r,))
         with _obs.trace_span("decode", rid=r.rid, trace_id=r.trace_id,
                              block=1, probe=1):
             toks = self.runner.run_decode(

@@ -31,6 +31,8 @@ class _EngineMetrics:
         self.occupancy = _obs.SERVING_OCCUPANCY.labels(**e)
         self.prefill = _obs.SERVING_DISPATCHES.labels(kind="prefill", **e)
         self.decode = _obs.SERVING_DISPATCHES.labels(kind="decode", **e)
+        self.argmax = {k: _obs.SERVING_ARGMAX_DISPATCHES.labels(kind=k, **e)
+                       for k in ("prefill", "decode", "verify")}
         self.tokens = _obs.SERVING_TOKENS.labels(**e)
         self.preempt = _obs.SERVING_PREEMPTIONS.labels(**e)
         self.hits = _obs.SERVING_CACHE_EVENTS.labels(event="hit", **e)
@@ -72,6 +74,13 @@ class _EngineMetrics:
         self.step_fail = {ph: _obs.SERVING_STEP_FAILURES.labels(phase=ph, **e)
                           for ph in ("prefill", "decode", "verify")}
         self.probes = _obs.SERVING_QUARANTINE_PROBES.labels(**e)
+
+    def count_argmax(self, kind, requests):
+        """Count one ``kind`` dispatch over ``requests`` if none of them
+        samples: every row then reaches the runner greedy (idle slots are
+        sent greedy) and its program takes the arg-max alone."""
+        if not any(r.do_sample for r in requests):
+            self.argmax[kind].inc()
 
 
 class _PoolMetrics:

@@ -20,6 +20,12 @@ TPU-native design (carried over from the monolithic serving engine):
   speculative decoding.
 - Sampling happens IN-GRAPH with per-slot parameters (greedy / temperature /
   top-k / top-p / seed), replicating models.llama._sample token-for-token.
+  What a dispatch's rows ask for decides the work (``_sample_rows``): when
+  every row is greedy (idle slots are sent greedy) the program takes the
+  arg-max and nothing else; one sampled row sends all rows through the
+  filter. The branch is ONE ``lax.cond`` on the whole batch, outside the
+  ``vmap`` over rows: on a per-row predicate under ``vmap`` it would lower
+  to a select that runs both branches.
 - KV lives in PAGES [L, n_pages, page, KVH, D]; page tables arrive from the
   scheduler per dispatch.  Pages are just indices here — allocation policy
   (refcounts, prefix cache, preemption) is the PagePool's business.
@@ -52,19 +58,31 @@ def _kernel_applies(device, mesh):
     TPU, when the attention operands are whole on one device — no mesh, or a
     one-device mesh (how a replica is pinned to its own chip).  A mesh of
     several devices splits heads (mp) or layers (pp) across them, which the
-    kernels do not take; it runs the ``*_ref`` path (ROADMAP S6)."""
+    kernels do not take; it runs the ``*_ref`` path (ROADMAP M8)."""
     return Place(device).is_tpu_place() and (mesh is None or mesh.size == 1)
 
 
-def _sample_row(logits, greedy, temp, topp, topk, seed):
-    """One row of in-graph sampling, replicating models.llama._sample +
-    ops.top_p_sampling (same filter order, same sort, same categorical
-    key/shape) so a SEEDED top_p<1 engine decode == model.generate.
-    (At top_p>=1.0, generate falls through to ops.multinomial on the global
-    RNG stream, which ignores the seed — no parity is possible there by
-    construction.) logits [V] f32; scalars traced."""
+def _sort_desc(probs):
+    """``probs`` [V] in descending order, and the index each came from:
+    ``probs[argsort(-probs)]`` and ``argsort(-probs)``, bit for bit, ties in
+    index order. One stable key-value sort: ``argsort`` sorts the same (key,
+    index) pairs and drops the keys, and fetching them again is a gather of
+    V single elements a row — on the TPU that gather was the sampler's cost
+    (PERF.md section 6, PR 31)."""
+    neg, idx = jax.lax.sort_key_val(
+        -probs, jnp.arange(probs.shape[-1], dtype=jnp.int32), is_stable=True)
+    return -neg, idx
+
+
+def _sample_row(logits, temp, topp, topk, seed):
+    """One SAMPLED row of in-graph sampling, replicating
+    models.llama._sample + ops.top_p_sampling (same filter order, same sort,
+    same categorical key/shape) so a SEEDED top_p<1 engine decode ==
+    model.generate. (At top_p>=1.0, generate falls through to
+    ops.multinomial on the global RNG stream, which ignores the seed — no
+    parity is possible there by construction.) logits [V] f32; scalars
+    traced. Greedy rows never need this: ``_sample_rows`` decides."""
     maxk = min(_MAXK, logits.shape[-1])
-    amax = jnp.argmax(logits)
     l = logits / jnp.where(temp > 0, temp, 1.0)
     probs = jax.nn.softmax(l)
     # top-k (0 = off): zero everything below the k-th largest prob
@@ -73,8 +91,7 @@ def _sample_row(logits, greedy, temp, topp, topk, seed):
     probs = jnp.where((topk > 0) & (probs < thresh), 0.0, probs)
     probs = probs / jnp.sum(probs)
     # top-p over the full sorted vocab (ops.top_p_sampling's formulation)
-    sort_idx = jnp.argsort(-probs)
-    sorted_p = probs[sort_idx]
+    sorted_p, sort_idx = _sort_desc(probs)
     cum = jnp.cumsum(sorted_p)
     keep = jnp.where(topp < 1.0, (cum - sorted_p) < topp, sorted_p >= 0)
     filtered = jnp.where(keep, sorted_p, 0.0)
@@ -84,8 +101,31 @@ def _sample_row(logits, greedy, temp, topp, topk, seed):
     # gumbel draw is bit-identical at equal keys
     choice = jax.random.categorical(
         key, jnp.log(jnp.maximum(filtered, 1e-30))[None, :], axis=-1)[0]
-    tok = sort_idx[choice]
-    return jnp.where(greedy > 0, amax, tok).astype(jnp.int32)
+    return sort_idx[choice]
+
+
+def _sample_rows(logits, greedy, temp, topp, topk, seeds):
+    """Next token of every row of one dispatch: logits [N, V] f32, the
+    per-row parameters [N]. All rows greedy (idle slots and the rows of an
+    idle verify slot arrive greedy): the arg-max alone. Any sampled row:
+    every row through ``_sample_row``, greedy rows keeping their arg-max.
+
+    The predicate is a scalar over the batch and the ``cond`` sits outside
+    the ``vmap``, so the compiled program holds a real conditional and a
+    greedy batch never sorts the vocabulary."""
+    amax = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def sampled():
+        tok = jax.vmap(_sample_row)(logits, temp, topp, topk, seeds)
+        return jnp.where(greedy > 0, amax, tok)
+
+    return jax.lax.cond(jnp.all(greedy > 0), lambda: amax, sampled)
+
+
+def _argmax_only(greedy):
+    """The host's reading of ``_sample_rows``' predicate, for the
+    ``runner.dispatch`` span."""
+    return int(np.all(np.asarray(greedy) > 0))
 
 
 class ModelRunner:
@@ -260,9 +300,8 @@ class ModelRunner:
                 h = rms_norm(x, W["norm"], eps)
                 logits = h.astype(jnp.float32) @ W["head"].astype(
                     jnp.float32)
-                # one vmapped sampler, not B inlined sort/cumsum subgraphs
-                nxt = jax.vmap(_sample_row)(logits, greedy, temp, topp,
-                                            topk, seeds + i * fold)
+                nxt = _sample_rows(logits, greedy, temp, topp, topk,
+                                   seeds + i * fold)
                 tokens = jnp.where(active > 0, nxt, tokens)
                 lens = lens + (active > 0).astype(lens.dtype)
                 return (tokens, lens, cache), nxt
@@ -301,7 +340,8 @@ class ModelRunner:
             h = rms_norm(x, W["norm"], eps)
             last = h[jnp.maximum(n_valid - 1, 0)]
             logits = last.astype(jnp.float32) @ W["head"].astype(jnp.float32)
-            nxt = _sample_row(logits, greedy, temp, topp, topk, seed)
+            nxt = _sample_rows(logits[None], greedy[None], temp[None],
+                               topp[None], topk[None], seed[None])[0]
             return nxt, cache2
 
         return jax.jit(prefill, donate_argnums=(1,))
@@ -351,9 +391,8 @@ class ModelRunner:
             # non-speculative block would have drawn, so fixed-seed
             # (fold=0) and greedy requests stay token-exact vs spec-off
             seeds_rep = rep(seeds) + row_j * rep(fold)
-            toks = jax.vmap(_sample_row)(
-                logits, rep(greedy), rep(temp), rep(topp), rep(topk),
-                seeds_rep)
+            toks = _sample_rows(logits, rep(greedy), rep(temp), rep(topp),
+                                rep(topk), seeds_rep)
             return toks.reshape(B, Kv), cache2
 
         return jax.jit(verify, donate_argnums=(1,))
@@ -391,7 +430,8 @@ class ModelRunner:
         """Dispatch one prefill chunk; returns the sampled next token as a
         DEVICE value (only the caller decides whether to sync on it — a
         mid-prompt chunk's sample is never read)."""
-        attrs = ({"kind": "prefill", "rows": int(n_valid), "start": int(start)}
+        attrs = ({"kind": "prefill", "rows": int(n_valid), "start": int(start),
+                  "argmax_only": _argmax_only(greedy)}
                  if _obs.enabled() else {})
         return self._launch(
             ("prefill",), self._prefill, attrs, tokens, np.int32(start), table,
@@ -412,7 +452,8 @@ class ModelRunner:
             # and the valid context each of them reads
             ctx = (np.asarray(lens) + 1)[np.asarray(active) > 0]
             attrs = {"kind": "decode", "rows": int(ctx.size),
-                     "ctx_sum": int(ctx.sum()), "k": int(k)}
+                     "ctx_sum": int(ctx.sum()), "k": int(k),
+                     "argmax_only": _argmax_only(greedy)}
         toks = self._launch(("decode", k), prog, attrs, tokens, lens, tables,
                             active, greedy, temp, topp, topk, seeds, fold)
         with _obs.trace_span("runner.wait"):
@@ -431,7 +472,8 @@ class ModelRunner:
             n = np.asarray(n_rows, np.int64)
             ctx_sum = int((n * (np.asarray(lens) + 1) + n * (n - 1) // 2).sum())
             attrs = {"kind": "verify", "rows": int(n.sum()),
-                     "ctx_sum": ctx_sum, "k": int(kv)}
+                     "ctx_sum": ctx_sum, "k": int(kv),
+                     "argmax_only": _argmax_only(greedy)}
         toks = self._launch(("verify", kv), prog, attrs, tokens, lens, tables,
                             n_rows, greedy, temp, topp, topk, seeds, fold)
         with _obs.trace_span("runner.wait"):

@@ -160,6 +160,7 @@ class _SpecOrchestration:
             compile_call = not self.runner.has_verify_program(Kv)
             self.spec_dispatches += 1
             self._m.verify.inc()
+            self._m.count_argmax("verify", (r for _, r in live))
         # timed: the adaptive draft length fits this wall time, switches or no
         with _obs.trace_span("verify", rid=[r.rid for _, r in live],
                              trace_id=[r.trace_id for _, r in live],

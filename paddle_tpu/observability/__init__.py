@@ -91,6 +91,10 @@ SERVING_OCCUPANCY = REGISTRY.gauge(
 SERVING_DISPATCHES = REGISTRY.counter(
     "serving_dispatches_total", "engine programs dispatched",
     ("engine", "kind"))                        # kind: prefill | decode | verify
+SERVING_ARGMAX_DISPATCHES = REGISTRY.counter(
+    "serving_argmax_dispatches_total",
+    "dispatches whose rows were all greedy: the program took the arg-max "
+    "and skipped the sampling filter", ("engine", "kind"))
 SERVING_TOKENS = REGISTRY.counter(
     "serving_generated_tokens_total", "tokens emitted to requests",
     ("engine",))

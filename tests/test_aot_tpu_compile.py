@@ -242,3 +242,50 @@ class TestServingProgramsCarryThePool:
                 else "paged_attention")
         assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text)
         assert "tpu_custom_call" in text
+
+    @pytest.mark.parametrize("kind", ["decode", "prefill", "verify"])
+    def test_a_greedy_batch_takes_a_real_conditional(self, v5e, kind):
+        """The sampler's batch-level branch survives the TPU compiler as a
+        ``conditional`` (not a select over both branches), the filter's
+        vocabulary-wide operations are all inside its sampled branch, and
+        its arg-max branch holds none of them (PERF.md section 6, PR 31)."""
+        import re
+        r = self.runner(False)
+        prog = {"decode": lambda: r._build_decode(1),
+                "prefill": r._build_prefill,
+                "verify": lambda: r._build_verify(self.KV)}[kind]()
+        W, cache, rest = self.arguments(kind, False,
+                                        SingleDeviceSharding(v5e[0]))
+        text = prog.lower(W, cache, *rest).compile().as_text()
+        bodies, name = {}, None            # computation -> its lines
+        for line in text.splitlines():
+            head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+            if head:
+                name = head.group(1)
+                bodies[name] = []
+            elif name is not None:
+                bodies[name].append(line)
+
+        def reach(comp, seen):
+            """``comp`` and every computation it calls, lines joined."""
+            if comp in seen or comp not in bodies:
+                return ""
+            seen.add(comp)
+            own = "\n".join(bodies[comp])
+            called = re.findall(
+                r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", own)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", own):
+                called += re.findall(r"%([\w.\-]+)", group)
+            return own + "".join("\n" + reach(c, seen) for c in called)
+
+        conds = re.findall(
+            r" conditional\(.*branch_computations=\{%([\w.\-]+), "
+            r"%([\w.\-]+)\}", text)
+        assert len(conds) == 1, conds
+        sampled, arg_max = (reach(c, set()) for c in conds[0])   # 0: False
+        filter_ops = r" (sort|gather|reduce-window|rng[\w\-]*|exponential)\("
+        assert not re.search(filter_ops, arg_max)
+        assert " sort(" in sampled and " reduce-window(" in sampled
+        # nothing of the filter is hoisted out of the branch
+        assert text.count(" sort(") == sampled.count(" sort(") >= 1
+

@@ -738,6 +738,57 @@ class TestStepLoopSpans:
         assert count["runner.launch"] == n
         assert count["engine.step"] >= n
 
+    @pytest.mark.parametrize("one_sampled", [False, True],
+                             ids=["all_greedy", "one_sampled_row"])
+    def test_argmax_only_dispatches_are_counted_and_marked(
+            self, replica, tmp_path, one_sampled):
+        """What the sampler's batch-level branch took, as the host read it
+        before dispatching: ``serving_argmax_dispatches_total`` beside
+        ``serving_dispatches_total``, ``argmax_only`` on ``runner.dispatch``.
+        Greedy traffic: every dispatch. One sampled request among greedy
+        ones: its own prefill chunks and every decode step it rides in."""
+        import jax
+        rep, eng = replica
+        obs.reset()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            prompts = _prompts(seed=15, n=3, shared=10, tail=7)
+            with rep._cv:       # all three arrive between the same steps
+                rids = [rep.submit(p, max_new_tokens=8) for p in prompts[:2]]
+                # the longest, so that no decode step runs without it
+                rids.append(rep.submit(prompts[2], max_new_tokens=24,
+                                       do_sample=one_sampled, top_k=5,
+                                       seed=3))
+            for rid in rids:
+                _drain(rep, rid)
+        finally:
+            jax.profiler.stop_trace()
+        snap = obs.snapshot(labels={"engine": eng._m.label})
+
+        def by_kind(name):
+            return {s["labels"]["kind"]: s["value"]
+                    for s in snap[name]["series"]}
+        total = by_kind("serving_dispatches_total")
+        argmax = by_kind("serving_argmax_dispatches_total")
+        marks = [(st["kind"], st["argmax_only"])
+                 for name, _, _, st in _loop_thread_events(tmp_path)
+                 if name == "runner.dispatch"]
+        for kind in ("prefill", "decode"):
+            assert total[kind] > 0
+            # the span says what the counter says
+            assert (sum(a for k, a in marks if k == kind) == argmax[kind]
+                    and sum(k == kind for k, _ in marks) == total[kind])
+        if not one_sampled:
+            assert argmax["prefill"] == total["prefill"]
+            assert argmax["decode"] == total["decode"]
+        else:
+            assert argmax["decode"] == 0
+            chunks = -(-len(prompts[2]) // eng.chunk)
+            assert argmax["prefill"] == total["prefill"] - chunks
+
     def test_a_submitter_held_out_by_a_slow_step_is_counted(self, replica):
         from paddle_tpu.testing.faults import FAULTS, Always
         rep, eng = replica
