@@ -63,10 +63,27 @@ class _TransientTier(Exception):
         self.err = err
 
 
+_NO_STATE_HANDOFF = ("the hand-off carries pages and no state, and the "
+                     "pages alone do not continue a request")
+
+
+def refuse_recurrent(plan, what, why):
+    """A model that keeps recurrent state has no pages for it and no prefix
+    chain: what cannot be right yet refuses by name at construction, it
+    does not serve wrong tokens."""
+    if plan.recurrent:
+        raise NotImplementedError(
+            f"{what} is not available for a model with recurrent-state "
+            f"layers: {why}")
+
+
 class LLMEngine(_SpecOrchestration):
-    """Continuous-batching paged-KV engine over a LlamaForCausalLM; the
-    speculative-decode orchestration comes from
-    :class:`~.spec._SpecOrchestration`."""
+    """Continuous-batching engine over a model that offers ``config`` and
+    ``serving_plan()`` (``models/serving_plan.py``: its kinds of layer on
+    raw arrays, their order, its weights): ``LlamaForCausalLM`` (paged KV
+    alone), ``SolarOpen2ForCausalLM`` (paged KV beside a pool of recurrent
+    state, a slot a request). The speculative-decode orchestration comes
+    from :class:`~.spec._SpecOrchestration`."""
 
     _engine_seq = 0   # observability label: one series set per engine
 
@@ -171,6 +188,19 @@ class LLMEngine(_SpecOrchestration):
         ``kv.spill`` / ``kv.restore``."""
         cfg = model.config
         self.cfg = cfg
+        plan = model.serving_plan()
+        for what, asked, why in (
+                ("prefix_cache", prefix_cache,
+                 "a cached prefix skips the prefill that builds the state, "
+                 "and no snapshot of it is kept with the pages"),
+                ("spec_decode", spec_decode is not None,
+                 "a rejected draft has already moved the state on, and "
+                 "there is no rollback of it"),
+                ("host_cache_bytes", host_cache_bytes is not None,
+                 "the spill tier keeps pages by prefix chain and carries "
+                 "no state")):
+            if asked:
+                refuse_recurrent(plan, what, why)
         self.max_batch = max_batch
         self.max_len = max_len
         self.page = page_size
@@ -363,7 +393,7 @@ class LLMEngine(_SpecOrchestration):
             nxt = self.runner.run_prefill(
                 toks, start, sched.slot_tables[slot], n,
                 0 if r.do_sample else 1, r.temperature, r.top_p, r.top_k,
-                self._next_seed(r))
+                self._next_seed(r), slot)
             if finishes:
                 # only the chunk that ends a prompt reads its sample
                 with _obs.trace_span("runner.wait"):
@@ -467,6 +497,7 @@ class LLMEngine(_SpecOrchestration):
             toks = self.runner.run_decode(
                 k, args[0], sched.lens, sched.slot_tables, *args[1:])  # [k, B]
         with _obs.trace_span("engine.emit"):
+            self._m.count_routing(self.runner.take_routing_counts())
             if self._auto_block and not compile_call:
                 # the host sync in run_decode makes the span's wall time a
                 # true dispatch sample
@@ -682,6 +713,8 @@ class LLMEngine(_SpecOrchestration):
         self._m.cached_pages.set(len(self.pool.key_page))
         self._m.reclaimable.set(len(self.pool.lru))
         self._m.free_pages.set(len(self.pool.free_pages))
+        if self.runner.plan.recurrent:
+            self._m.state_slots.set(n_active)
         if self.pool.host is not None:
             self._m.host_cached.set(len(self.pool.host))
 
@@ -727,6 +760,12 @@ class LLMEngine(_SpecOrchestration):
         """HBM bytes one KV page costs across all layers (both K and V,
         including int8 scales) — the unit of the page_pool budget."""
         return self.runner.kv_bytes_per_page()
+
+    def state_bytes_per_slot(self):
+        """HBM bytes of recurrent state a slot holds across all layers,
+        whatever its length (0 for a model that keeps none); with
+        :meth:`kv_bytes_per_page` the whole of what a request costs."""
+        return self.runner.state_bytes_per_slot()
 
     # ---------------------------------------------------------- KV tiering
     def _spill_page(self, p):
@@ -821,6 +860,7 @@ class LLMEngine(_SpecOrchestration):
         read in place).  Returns ``{"keys": [...], "block": tuple of
         [L, n, page, ...] numpy arrays}``, or None when even the first key
         misses everywhere — the puller then recomputes."""
+        refuse_recurrent(self.runner.plan, "export_pages", _NO_STATE_HANDOFF)
         host = self.pool.host
         served, dev, host_blocks = [], [], {}
         for i, key in enumerate(keys):
@@ -860,6 +900,7 @@ class LLMEngine(_SpecOrchestration):
         (cached, refcount 0), so the next admission walk claims it as an
         ordinary prefix hit.  Any failure stops the splice mid-chain — the
         un-spliced tail simply recomputes.  Returns pages spliced."""
+        refuse_recurrent(self.runner.plan, "import_pages", _NO_STATE_HANDOFF)
         if not payload:
             return 0
         keys, block = payload["keys"], payload["block"]

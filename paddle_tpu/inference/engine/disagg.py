@@ -69,7 +69,7 @@ from ... import observability as _obs
 from ...observability import flight as _flight
 from ...core.retry import RetryError, RetryPolicy, retry_call
 from ...testing.faults import FAULTS as _faults
-from .core import LLMEngine
+from .core import _NO_STATE_HANDOFF, LLMEngine, refuse_recurrent
 from .metrics import _PoolMetrics
 from .request import Request, RequestStatus
 
@@ -228,6 +228,12 @@ class DisaggEngine:
                  decode_meshes=None, prefill_engines=None,
                  decode_engines=None, remote_prefill=None,
                  async_handoff=True):
+        plans = [e.runner.plan for e in
+                 list(prefill_engines or []) + list(decode_engines or [])]
+        if model is not None:
+            plans.append(model.serving_plan())
+        for plan in plans:
+            refuse_recurrent(plan, "DisaggEngine", _NO_STATE_HANDOFF)
         self.max_batch = max_batch
         self.max_len = max_len
         self.page = page_size

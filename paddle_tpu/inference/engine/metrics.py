@@ -74,6 +74,11 @@ class _EngineMetrics:
         self.step_fail = {ph: _obs.SERVING_STEP_FAILURES.labels(phase=ph, **e)
                           for ph in ("prefill", "decode", "verify")}
         self.probes = _obs.SERVING_QUARANTINE_PROBES.labels(**e)
+        # a model with recurrent state / sparse experts (nothing moves them
+        # for any other)
+        self.state_slots = _obs.SERVING_STATE_SLOTS.labels(**e)
+        self.routing = [[fam.labels(kind=k, **e) for fam in _obs.SERVING_MOE]
+                        for k in ("decode", "prefill")]
 
     def count_argmax(self, kind, requests):
         """Count one ``kind`` dispatch over ``requests`` if none of them
@@ -81,6 +86,15 @@ class _EngineMetrics:
         sent greedy) and its program takes the arg-max alone."""
         if not any(r.do_sample for r in requests):
             self.argmax[kind].inc()
+
+    def count_routing(self, grown):
+        """Add what the expert layers' routing counts grew by (the runner's
+        ``take_routing_counts()``: ``[kind of dispatch, count]``, empty for
+        a model that has none) to the registry's counters."""
+        for row, counts in zip(self.routing, grown):
+            for counter, n in zip(row, counts):
+                if n:
+                    counter.inc(int(n))
 
 
 class _PoolMetrics:

@@ -29,14 +29,27 @@ TPU-native design (carried over from the monolithic serving engine):
 - KV lives in PAGES [L, n_pages, page, KVH, D]; page tables arrive from the
   scheduler per dispatch.  Pages are just indices here — allocation policy
   (refcounts, prefix cache, preemption) is the PagePool's business.
-- The model says what it is: ``model.stacked_weights()`` hands over the
-  weights stacked [L, ...], ``models.llama.stacked_weight_specs`` how they
-  split (layers over the pp axis, head/ffn dims over the mp axis; GSPMD
-  inserts the collectives), and ``block_qkv`` / ``block_out`` are the block
-  on either side of the attention. What is here is the engine's: the pool,
-  the write of a step's rows into its pages, which attention reads them.
+- The model says what it is (``models/serving_plan.py``): ``model.config``
+  and ``model.serving_plan(kernels)``, which gives its KINDS of layer on raw
+  arrays, each in two halves around what the runner does for it, the PERIOD
+  they run in, its weights leaf by leaf (stacked by kind) and how they
+  split (periods or layers over the pp axis, head/ffn dims over the mp
+  axis; GSPMD inserts the collectives). The dense Llama block is one kind
+  (``block_qkv`` / ``block_out`` around the attention) and one period; a
+  model whose layers differ runs a ``lax.scan`` for each run of like layers
+  and one around the period. What is here is the engine's: the pools BY
+  KIND OF CACHE, all carried through every loop and donated -
+  pages ``[L_pages, n_pages, page, KVH, D]`` for the layers that attend (a
+  layer's own index among them shifts the tables), and for the layers
+  with a recurrence a state pool ``[L_state, max_batch + 1, heads, dk,
+  dv]`` float32 and the tails of their convolutions ``[L_state, max_batch
+  + 1, taps - 1, channels]``, a row a slot and one more that idle rows
+  write to - the write of a step's rows into them, which attention or
+  recurrence reads them, and the sums of the layers' routing counts.
 """
 from __future__ import annotations
+
+import types
 
 import numpy as np
 import jax
@@ -45,12 +58,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ... import observability as _obs
 from ...core.device import Place
-from ...models.llama import (BLOCK_KEYS, block_out, block_qkv, rms_norm,
-                             stacked_weight_specs)
+from ...models.llama import rms_norm
 
 __all__ = ["ModelRunner"]
 
 _MAXK = 64        # static cap for per-slot dynamic top-k filtering
+# the rows of the routing-count sums: which program a layer's call was in
+_COUNT_ROWS = ("decode", "prefill")
 
 
 def _kernel_applies(device, mesh):
@@ -142,33 +156,32 @@ class ModelRunner:
         self.chunk = int(prefill_chunk)
         self.n_pages = int(n_pages)
         self.trash_page = self.n_pages - 1
-        L = cfg.num_hidden_layers
-        H = cfg.hidden_size
-        nh, kvh = cfg.num_attention_heads, cfg.num_key_value_heads
-        D = H // nh
-        self.nh, self.kvh, self.D = nh, kvh, D
         if use_kernel is None:
             use_kernel = _kernel_applies(self.devices[0], mesh)
         self.use_kernel = use_kernel
+        self.plan = plan = model.serving_plan(kernels=bool(use_kernel))
 
         with _obs.trace_span("engine.build.weights"):
-            W = model.stacked_weights()
-            dtype = W["wq"].dtype
             if mesh is not None:
-                pp = pp_axis if pp_axis in mesh.axis_names else None
-                mp = mp_axis if mp_axis in mesh.axis_names else None
-                specs = stacked_weight_specs(pp, mp)
-                # host -> mesh directly: jnp.asarray would stage every
-                # replica's weights on the default device first
-                self.W = {k: jax.device_put(v, NamedSharding(mesh, specs[k]))
-                          for k, v in W.items()}
-                cache_spec = NamedSharding(mesh, P(pp))
+                axes = {a: (a if a in mesh.axis_names else None)
+                        for a in (pp_axis, mp_axis, "ep")}
+                specs = plan.specs(axes[pp_axis], axes[mp_axis], axes["ep"])
+                cache_spec = NamedSharding(mesh, P(axes[pp_axis]))
             else:
-                self.W = {k: jnp.asarray(v) for k, v in W.items()}
                 cache_spec = None
+            # host -> mesh directly: jnp.asarray would stage every
+            # replica's weights on the default device first. Leaf by leaf:
+            # a model that hands its leaves over lets go of each here
+            self.W = {}
+            for k, v in plan.weights():
+                self.W[k] = (jnp.asarray(v) if mesh is None else
+                             jax.device_put(v, NamedSharding(mesh, specs[k])))
+            dtype = self.W["embed"].dtype
         self.cache_sharding = cache_spec
         self.kv_quant = (kv_cache_dtype == "int8")
         page_dtype = jnp.int8 if self.kv_quant else dtype
+        L = plan.layers_of("pages")
+        kvh, D = plan.kvh, plan.D
 
         def pool(dt, *tail):    # born on its own devices (None: the default)
             return jnp.zeros((L, self.n_pages, page_size, kvh) + tail, dt,
@@ -177,6 +190,26 @@ class ModelRunner:
             self.cache = (pool(page_dtype, D), pool(page_dtype, D))
             if self.kv_quant:
                 self.cache += (pool(jnp.float32), pool(jnp.float32))
+        if plan.recurrent:
+            # a row a slot, and one that idle rows write to; born after the
+            # weights are placed, so construction never holds them beside
+            # a second copy of anything
+            rows = (plan.layers_of("state"), self.max_batch + 1)
+            with _obs.trace_span("engine.build.state_pool"):
+                self.cache += (
+                    jnp.zeros(rows + (plan.state_heads, plan.state_dk,
+                                      plan.state_dv), jnp.float32,
+                              device=cache_spec),
+                    jnp.zeros(rows + (plan.conv_tail, plan.conv_channels),
+                              dtype, device=cache_spec))
+        if plan.counts:
+            # the layers' routing counts summed on the device, a row a kind
+            # of dispatch (_COUNT_ROWS); int32 that wraps, read as differences
+            self.cache += (jnp.zeros((len(_COUNT_ROWS), plan.counts),
+                                     jnp.int32),)
+        self._counts_seen = np.zeros((len(_COUNT_ROWS), plan.counts),
+                                     np.int64)
+        self._counts_grown = np.zeros_like(self._counts_seen)
         self._prefill = self._build_prefill()
         self._decode_programs: dict = {}
         self._verify_programs: dict = {}
@@ -193,17 +226,47 @@ class ModelRunner:
         return (jax.devices()[0],)
 
     # ---------------------------------------------------------------- layers
-    def _layer_fn(self, page_idx, within, tables, ctx, pos, mq=None):
-        """Shared per-layer body for decode, prefill, and speculative
-        verification (they differ only in how many rows ride the batch dim
-        and where those rows' pages are). With ``mq=(B, Q)`` the flat rows
+    @property
+    def _n_page_pools(self):
+        """The page pools lead ``self.cache``: K and V, and the two scale
+        pools of int8 pages; a recurrent model's state pool and tails
+        follow, then the routing counts of a model that has them."""
+        return 4 if self.kv_quant else 2
+
+    def _count(self, cache, counts, kind):
+        """Add one layer's routing counts (``None``: the layer has none) to
+        the sums' row of this kind of dispatch."""
+        if counts is None:
+            return cache
+        row = _COUNT_ROWS.index(kind)
+        return cache[:-1] + (cache[-1].at[row].add(counts),)
+
+    def _layer_fns(self, W, r, kind):
+        """The scan body of every kind of layer, for one dispatch's rows
+        ``r`` (``kind``: decode | prefill | verify): what decode, prefill and
+        speculative verification share; they differ only in how many rows
+        ride the batch dim and where those rows' pages and states are. A
+        kind's ``whole`` leaves join the layer's scanned-in ones unsliced."""
+        def with_whole(lk, layer):
+            if not lk.whole:
+                return layer
+            whole = {k: W[k] for k in lk.whole}
+            return lambda carry, wl: layer(carry, {**wl, **whole})
+        return {name: with_whole(lk, (
+            self._pages_layer if lk.cache == "pages"
+            else self._state_layer)(lk, r, kind))
+            for name, lk in self.plan.kinds.items()}
+
+    def _pages_layer(self, lk, r, kind):
+        """A layer that attends. With ``r.mq = (B, Q)`` the flat rows
         are B sequences x Q consecutive query positions and attention goes
         through the multi-query kernel (tables [B, S]; ctx [B] is row 0's
         context length, row j sees ctx+j); KV writes stay per-flat-row.
 
         The body's carry is ``(x, cache)``: the WHOLE stacked pools ride
-        beside the activations and layer ``l`` (scanned in as ``wl["l"]``)
-        scatters its rows into them at ``(l, page_idx, within)``. Attention
+        beside the activations and layer ``l`` (scanned in as ``wl["l"]``,
+        its index among the layers that attend) scatters its rows into
+        them at ``(l, page_idx, within)``. Attention
         then reads the pages as one flat stack of ``L * n_pages`` (a
         reshape of the two leading axes: a bitcast) through the tables
         shifted by ``l * n_pages``; the scale pools of int8 pages, a
@@ -211,12 +274,12 @@ class ModelRunner:
         layer. A pool that is scanned over instead is sliced out per
         layer, written back per layer and, being donated while still read,
         copied whole once a dispatch."""
-        nh, kvh, D = self.nh, self.kvh, self.D
-        eps = self.cfg.rms_norm_eps
-        theta = self.cfg.rope_theta
+        nh, D = self.plan.nh, self.plan.D
         use_kernel = self.use_kernel
         quant = self.kv_quant
         n_pages = self.n_pages
+        page_idx, within, tables, ctx, pos, mq = (
+            r.page_idx, r.within, r.tables, r.ctx, r.pos, r.mq)
 
         def layer(carry, wl):
             from ...ops.pallas.paged_attention import (
@@ -225,7 +288,7 @@ class ModelRunner:
                 quantize_kv)
             x, cache = carry
             l = wl["l"]
-            q, k, v = block_qkv(wl, x, pos, nh, kvh, eps, theta)
+            q, k, v = lk.first(wl, x, pos)
             if mq is None:
                 attn = paged_attention if use_kernel else paged_attention_ref
             else:
@@ -243,8 +306,9 @@ class ModelRunner:
                 vq, vsc = quantize_kv(v)
                 rows = (kq, vq, ksc, vsc)
             # write first, read after: the pool has one live value
-            cache = tuple(a.at[l, page_idx, within].set(r)
-                          for a, r in zip(cache, rows))
+            pages = tuple(a.at[l, page_idx, within].set(rw)
+                          for a, rw in zip(cache, rows))
+            cache = pages + cache[len(pages):]
             kp, vp = (a.reshape((-1,) + a.shape[2:]) for a in cache[:2])
             # the scales go in by the layer, under the tables as they came:
             # the TPU keeps a pool whose last axis is KVH in a layout of
@@ -252,20 +316,91 @@ class ModelRunner:
             kw = ({"k_scales": cache[2][l], "v_scales": cache[3][l],
                    "scale_tables": tables} if quant else {})
             att = attn(q, kp, vp, tables + l * n_pages, ctx, **kw)
-            x = block_out(wl, x, att, eps)
-            return (x, cache), None
+            x, counts = lk.second(wl, x, att, r.live)
+            return (x, self._count(cache, counts, kind)), None
 
         return layer
 
-    def _scan_layers(self, W, cache, x, layer):
-        """Run ``layer`` over the stacked weights. Only the weights and the
-        layer's index are scanned; the pools are carried and come back
-        updated in place (see :meth:`_layer_fn` for the copies that
-        avoids)."""
-        per_layer = {k: W[k] for k in BLOCK_KEYS}
-        per_layer["l"] = jnp.arange(cache[0].shape[0], dtype=jnp.int32)
-        (x, cache), _ = jax.lax.scan(layer, (x, cache), per_layer)
-        return x, cache
+    def _state_layer(self, lk, r, kind):
+        """A layer with a recurrence: its state ``[heads, dk, dv]`` and the
+        tail of its convolutions live in the two pools after the pages, at
+        ``(l, slot)``; ``l`` is the layer's index among its like.
+
+        A DECODE row is its slot's next token (``r.slots [B]``; an idle row
+        names the idle slot): the tails are gathered and scattered back (a
+        few MB), the states are not - ``kda_step`` updates the rows it is
+        told in place, in the pool flattened to ``[L * slots, ...]``. A
+        PREFILL chunk is ``r.n_valid`` consecutive positions of ONE slot:
+        its state and tail are sliced out, run through the chunk and
+        written back, and where the chunk starts the prompt (``r.start ==
+        0``) both begin at zero whatever the slot held - so admission,
+        and re-admission after a preemption, need no pass of their own.
+        The chunk's padding rows leave the state as it was."""
+        from ...ops.pallas.kda import kda_recurrence, kda_step, kda_step_ref
+        if kind == "verify":
+            raise NotImplementedError(
+                "speculative verification over recurrent state: rejected "
+                "drafts would need the state rolled back")
+        at = self._n_page_pools
+        step = kda_step if self.use_kernel else kda_step_ref
+
+        def layer(carry, wl):
+            x, cache = carry
+            l = wl["l"]
+            state, conv = cache[at], cache[at + 1]
+            if kind == "decode":
+                (q, k, v, g, beta), tails = lk.first(
+                    wl, x[:, None], conv[l, r.slots], None)
+                conv = conv.at[l, r.slots].set(tails)
+                flat = state.reshape((-1,) + state.shape[2:])
+                o, flat = step(flat, l * state.shape[1] + r.slots, q[:, 0],
+                               k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+                state = flat.reshape(state.shape)
+            else:
+                fresh = r.start == 0
+                (q, k, v, g, beta), tail = lk.first(
+                    wl, x, jnp.where(fresh, 0, conv[l, r.slot]), r.n_valid)
+                live = r.live
+                o, S = kda_recurrence(
+                    jnp.where(fresh, 0.0, state[l, r.slot]), q, k, v,
+                    jnp.where(live[:, None, None], g, 0.0),
+                    jnp.where(live[:, None], beta, 0.0))
+                state = state.at[l, r.slot].set(S)
+                conv = conv.at[l, r.slot].set(tail)
+            cache = cache[:at] + (state, conv) + cache[at + 2:]
+            x, counts = lk.second(wl, x, o, r.live)
+            return (x, self._count(cache, counts, kind)), None
+
+        return layer
+
+    def _run_layers(self, W, cache, x, fns):
+        """Run the model's layers: inside a period one ``lax.scan`` for
+        each run of like layers, over that kind's stacked leaves and the
+        layers' indices among their like (``wl["l"]``), and one scan over
+        the periods around them (none where the leaves have no period
+        axis). Only weights and indices are scanned; the pools are carried
+        and come back updated in place (see :meth:`_pages_layer` for the
+        copies that avoids)."""
+        plan = self.plan
+
+        def period(carry, Wp, p):
+            for name, n in plan.period:
+                lk = plan.kinds[name]
+                per_layer = {k: Wp[k] for k in lk.keys if k not in lk.whole}
+                l = jnp.arange(n, dtype=jnp.int32)
+                per_layer["l"] = l if p is None else l + p * n
+                carry, _ = jax.lax.scan(fns[name], carry, per_layer)
+            return carry
+
+        if plan.periods is None:
+            return period((x, cache), W, None)
+        leaves = {k: W[k] for name, _ in plan.period
+                  for k in plan.kinds[name].keys
+                  if k not in plan.kinds[name].whole}
+        carry, _ = jax.lax.scan(
+            lambda carry, wp: (period(carry, *wp), None), (x, cache),
+            (leaves, jnp.arange(plan.periods, dtype=jnp.int32)))
+        return carry
 
     # ------------------------------------------------------------- programs
     def _build_decode(self, K):
@@ -295,8 +430,15 @@ class ModelRunner:
                 page_idx = jnp.where(active > 0, page_idx, trash)
                 within = pos % page
                 ctx = jnp.where(active > 0, pos + 1, 1).astype(jnp.int32)
-                layer = self._layer_fn(page_idx, within, tables, ctx, pos)
-                x, cache = self._scan_layers(W, cache, x, layer)
+                rows = types.SimpleNamespace(
+                    page_idx=page_idx, within=within, tables=tables, ctx=ctx,
+                    pos=pos, mq=None, live=active)
+                if self.plan.recurrent:     # idle rows: the idle slot
+                    B = tokens.shape[0]
+                    rows.slots = jnp.where(
+                        active > 0, jnp.arange(B, dtype=jnp.int32), B)
+                x, cache = self._run_layers(
+                    W, cache, x, self._layer_fns(W, rows, "decode"))
                 h = rms_norm(x, W["norm"], eps)
                 logits = h.astype(jnp.float32) @ W["head"].astype(
                     jnp.float32)
@@ -309,6 +451,12 @@ class ModelRunner:
             (_, _, cache2), toks = jax.lax.scan(
                 one, (tokens, lens, cache),
                 jnp.arange(K, dtype=jnp.int32))
+            if self.plan.counts:
+                # the sums ride home behind the tokens, in the one array the
+                # host fetches anyway: [K, B + kinds * counts]
+                toks = jnp.concatenate(
+                    [toks, jnp.broadcast_to(cache2[-1].reshape(1, -1),
+                                            (K, cache2[-1].size))], axis=1)
             return toks, cache2                          # toks [K, B]
 
         return jax.jit(block, donate_argnums=(1,))
@@ -320,7 +468,7 @@ class ModelRunner:
         C = self.chunk
 
         def prefill(W, cache, tokens, start, table, n_valid,
-                    greedy, temp, topp, topk, seed):
+                    greedy, temp, topp, topk, seed, slot=None):
             # tokens [C] int32 (one slot's prompt chunk, zero-padded);
             # start scalar; table [S]; n_valid scalar <= C. Chunk rows ride
             # the paged-attention BATCH dim: row i gets ctx = start+i+1, so
@@ -335,8 +483,12 @@ class ModelRunner:
             within = pos % page
             ctx = jnp.where(valid, pos + 1, 1).astype(jnp.int32)
             tables = jnp.broadcast_to(table[None, :], (C, table.shape[0]))
-            layer = self._layer_fn(page_idx, within, tables, ctx, pos)
-            x, cache2 = self._scan_layers(W, cache, x, layer)
+            rows = types.SimpleNamespace(
+                page_idx=page_idx, within=within, tables=tables, ctx=ctx,
+                pos=pos, mq=None, live=valid, slot=slot, start=start,
+                n_valid=n_valid)
+            x, cache2 = self._run_layers(
+                W, cache, x, self._layer_fns(W, rows, "prefill"))
             h = rms_norm(x, W["norm"], eps)
             last = h[jnp.maximum(n_valid - 1, 0)]
             logits = last.astype(jnp.float32) @ W["head"].astype(jnp.float32)
@@ -381,9 +533,11 @@ class ModelRunner:
             # included); the multi-query kernel extends by +j per row
             cl = jnp.where(n_rows > 0, lens + 1, 1).astype(jnp.int32)
             x = W["embed"][tokens.reshape(-1)]            # [B*Kv, H]
-            layer = self._layer_fn(page_idx, within, tables, cl, pos,
-                                   mq=(B, Kv))
-            x, cache2 = self._scan_layers(W, cache, x, layer)
+            rows = types.SimpleNamespace(
+                page_idx=page_idx, within=within, tables=tables, ctx=cl,
+                pos=pos, mq=(B, Kv), live=valid)
+            x, cache2 = self._run_layers(
+                W, cache, x, self._layer_fns(W, rows, "verify"))
             h = rms_norm(x, W["norm"], eps)
             logits = h.astype(jnp.float32) @ W["head"].astype(jnp.float32)
             # seed schedule mirrors the decode block's `seeds + i*fold`:
@@ -426,17 +580,22 @@ class ModelRunner:
         return out
 
     def run_prefill(self, tokens, start, table, n_valid,
-                    greedy, temp, topp, topk, seed):
+                    greedy, temp, topp, topk, seed, slot=0):
         """Dispatch one prefill chunk; returns the sampled next token as a
         DEVICE value (only the caller decides whether to sync on it — a
-        mid-prompt chunk's sample is never read)."""
+        mid-prompt chunk's sample is never read). ``slot``: whose state the
+        chunk continues, for a model that keeps recurrent state (one that
+        keeps none is not told)."""
         attrs = ({"kind": "prefill", "rows": int(n_valid), "start": int(start),
                   "argmax_only": _argmax_only(greedy)}
                  if _obs.enabled() else {})
+        state = (np.int32(slot),) if self.plan.recurrent else ()
+        if state and attrs:
+            attrs["state_rows"] = 1
         return self._launch(
             ("prefill",), self._prefill, attrs, tokens, np.int32(start), table,
             np.int32(n_valid), np.int32(greedy), np.float32(temp),
-            np.float32(topp), np.int32(topk), np.int32(seed))
+            np.float32(topp), np.int32(topk), np.int32(seed), *state)
 
     def run_decode(self, k, tokens, lens, tables, active,
                    greedy, temp, topp, topk, seeds, fold):
@@ -454,10 +613,33 @@ class ModelRunner:
             attrs = {"kind": "decode", "rows": int(ctx.size),
                      "ctx_sum": int(ctx.sum()), "k": int(k),
                      "argmax_only": _argmax_only(greedy)}
+            if self.plan.recurrent:
+                attrs["state_rows"] = int(ctx.size)
         toks = self._launch(("decode", k), prog, attrs, tokens, lens, tables,
                             active, greedy, temp, topp, topk, seeds, fold)
         with _obs.trace_span("runner.wait"):
-            return np.asarray(toks)
+            toks = np.asarray(toks)
+        if self.plan.counts:
+            toks = self._strip_counts(toks, len(tokens))
+        return toks
+
+    def _strip_counts(self, toks, B):
+        """Take the routing sums off the back of a decode block's tokens
+        (the last step's are the block's) and keep what they grew by,
+        modulo the device's 32 bits - the chunks' since the block before
+        included - for :meth:`take_routing_counts`."""
+        seen = toks[-1, B:].astype(np.int64).reshape(self._counts_seen.shape)
+        self._counts_grown += (seen - self._counts_seen) % (1 << 32)
+        self._counts_seen = seen
+        return toks[:, :B]
+
+    def take_routing_counts(self):
+        """What the layers' routing counts grew by since the last call:
+        ``[kind of dispatch (decode, prefill), count]`` int64, empty for a
+        model that has none."""
+        grown, self._counts_grown = (self._counts_grown,
+                                     np.zeros_like(self._counts_grown))
+        return grown
 
     def run_verify(self, kv, tokens, lens, tables, n_rows,
                    greedy, temp, topp, topk, seeds, fold):
@@ -487,8 +669,10 @@ class ModelRunner:
             def cp(cache, s, d):
                 return tuple(a.at[:, d].set(a[:, s]) for a in cache)
             self._copy_page_fn = jax.jit(cp, donate_argnums=(0,))
+        n = self._n_page_pools
         self.cache = self._copy_page_fn(
-            self.cache, jnp.asarray(np.int32(src)), jnp.asarray(np.int32(dst)))
+            self.cache[:n], jnp.asarray(np.int32(src)),
+            jnp.asarray(np.int32(dst))) + self.cache[n:]
 
     def gather_pages(self, page_idx):
         """Pull ``page_idx`` pages out of the cache as a dense block (tuple
@@ -501,7 +685,8 @@ class ModelRunner:
             def gather(cache, idx):
                 return tuple(a[:, idx] for a in cache)
             fn = self._gather_fn[n] = jax.jit(gather)
-        return fn(self.cache, jnp.asarray(np.asarray(page_idx, np.int32)))
+        return fn(self.cache[:self._n_page_pools],
+                  jnp.asarray(np.asarray(page_idx, np.int32)))
 
     def scatter_pages(self, page_idx, block):
         """Write a dense page block into ``page_idx`` of this runner's cache
@@ -513,13 +698,25 @@ class ModelRunner:
             def scatter(cache, blk, idx):
                 return tuple(a.at[:, idx].set(b) for a, b in zip(cache, blk))
             fn = self._scatter_fn[n] = jax.jit(scatter, donate_argnums=(0,))
-        self.cache = fn(self.cache, block,
-                        jnp.asarray(np.asarray(page_idx, np.int32)))
+        at = self._n_page_pools
+        self.cache = fn(self.cache[:at], block, jnp.asarray(
+            np.asarray(page_idx, np.int32))) + self.cache[at:]
 
     def kv_bytes_per_page(self):
         """HBM bytes one KV page costs across all layers (both K and V,
         including int8 scales) — the unit of the page_pool budget."""
-        return sum(int(a.nbytes) for a in self.cache) // self.n_pages
+        return sum(int(a.nbytes) for a in
+                   self.cache[:self._n_page_pools]) // self.n_pages
+
+    def state_bytes_per_slot(self):
+        """HBM bytes one slot's recurrent state costs across all layers
+        (state and convolution tails; 0 for a model that keeps none): what
+        a slot holds whatever its length, beside its pages."""
+        if not self.plan.recurrent:
+            return 0
+        at = self._n_page_pools
+        return sum(int(a.nbytes) for a in
+                   self.cache[at:at + 2]) // (self.max_batch + 1)
 
     def pages_to_host(self, page_idx):
         """Gather ``page_idx`` pages and land them in host RAM as a tuple of

@@ -22,6 +22,7 @@ from ..nn.layer.container import LayerList
 from ..nn import functional as F
 from ..nn.functional.rope import fused_rotary_position_embedding
 from ..nn.initializer import Normal
+from .serving_plan import LayerKind, ServingPlan
 
 
 class LlamaConfig:
@@ -134,6 +135,24 @@ def stacked_weight_specs(pp, mp):
             "wq": col, "wk": col, "wv": col, "wo": row,
             "ln1": P(pp, None), "ln2": P(pp, None),
             "wg": col, "wu": col, "wd": row}
+
+
+def serving_plan(cfg, weights):
+    """The dense block as the serving engine runs it (``serving_plan.py``):
+    ONE kind of layer with paged KV, ``block_qkv`` and ``block_out`` around
+    the runner's attention, the leaves stacked ``[L, ...]`` with no period
+    axis. ``weights``: () -> iterator of (key, array)."""
+    nh, kvh = cfg.num_attention_heads, cfg.num_key_value_heads
+    eps, theta = cfg.rms_norm_eps, cfg.rope_theta
+    block = LayerKind(
+        cache="pages", keys=BLOCK_KEYS,
+        first=lambda wl, x, pos: block_qkv(wl, x, pos, nh, kvh, eps, theta),
+        second=lambda wl, x, att, live: (block_out(wl, x, att, eps), None))
+    return ServingPlan(
+        kinds={"block": block}, period=(("block", cfg.num_hidden_layers),),
+        periods=None, weights=weights,
+        specs=lambda pp, mp, ep=None: stacked_weight_specs(pp, mp),
+        nh=nh, kvh=kvh, D=cfg.hidden_size // nh)
 
 
 class KVCache:
@@ -364,6 +383,13 @@ class LlamaForCausalLM(Layer):
         W["head"] = (w(self.lm_head) if self.lm_head is not None
                      else W["embed"].T)
         return W
+
+    def serving_plan(self, kernels=False):
+        """What the serving engine needs of this model (``kernels``: the
+        block has none of its own). The weights go through host numpy, all
+        stacked at once."""
+        return serving_plan(self.config,
+                            lambda: iter(self.stacked_weights().items()))
 
     def new_kv_caches(self, batch, max_len, dtype="float32"):
         cfg = self.config

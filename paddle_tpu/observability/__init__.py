@@ -95,6 +95,28 @@ SERVING_ARGMAX_DISPATCHES = REGISTRY.counter(
     "serving_argmax_dispatches_total",
     "dispatches whose rows were all greedy: the program took the arg-max "
     "and skipped the sampling filter", ("engine", "kind"))
+SERVING_STATE_SLOTS = REGISTRY.gauge(
+    "serving_state_slots_in_use",
+    "slots whose recurrent state belongs to an admitted request", ("engine",))
+# a sparse expert layer's routing, summed on the device over every layer's
+# call and read with the tokens (engine/runner.py); kind: decode | prefill.
+# In the order of models.solar_open2.ROUTING_COUNTS.
+SERVING_MOE = (
+    REGISTRY.counter("serving_moe_calls_total",
+                     "expert-layer calls (one a layer a dispatch)",
+                     ("engine", "kind")),
+    REGISTRY.counter("serving_moe_rows_total",
+                     "rows routed (live rows of the calls)",
+                     ("engine", "kind")),
+    REGISTRY.counter("serving_moe_assignments_total",
+                     "(row, chosen expert) pairs computed here: the chosen "
+                     "experts this program holds", ("engine", "kind")),
+    REGISTRY.counter("serving_moe_experts_touched_total",
+                     "experts with at least one assignment, summed over "
+                     "the calls", ("engine", "kind")),
+    REGISTRY.counter("serving_moe_max_load_total",
+                     "the fullest expert's assignments, summed over the "
+                     "calls", ("engine", "kind")))
 SERVING_TOKENS = REGISTRY.counter(
     "serving_generated_tokens_total", "tokens emitted to requests",
     ("engine",))

@@ -11,6 +11,12 @@ auto_parallel/static/pir_pass.py:368). Token dispatch is a dense
 capacity-bucketed einsum (GShard-style) whose all-to-all is emitted by GSPMD
 from the shardings. No host-side routing — everything is jit-compatible dense
 math on the MXU.
+
+This is the TRAINING-side layer: a capacity router (softmax, then top-k,
+assignments past an expert's capacity dropped) over ``Tensor``s and the op
+registry. It is not what the serving engine runs: a served expert layer is
+dropless, routes as its model publishes and is told which experts it holds
+(``models/solar_open2.py:experts`` over ``ops/pallas/moe_gmm.py``).
 """
 from __future__ import annotations
 

@@ -8,6 +8,7 @@ The file name sorts first on purpose: tier-1 runs alphabetically into a
 timeout, and this is the one CPU-side guard of the on-chip path.
 """
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -21,6 +22,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,  # noqa: E402
                           SingleDeviceSharding)
 
 from paddle_tpu.ops.pallas import flash_attention as fa  # noqa: E402
+from paddle_tpu.ops.pallas import kda  # noqa: E402
+from paddle_tpu.ops.pallas import moe_gmm as mg  # noqa: E402
 from paddle_tpu.ops.pallas import paged_attention as pa  # noqa: E402
 from paddle_tpu.ops.pallas import quant_matmul as qm  # noqa: E402
 
@@ -39,7 +42,7 @@ def v5e():
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     with pytest.MonkeyPatch.context() as mp:
-        for mod in (fa, pa, qm):
+        for mod in (fa, pa, qm, kda, mg):
             mp.setattr(mod, "_interpret", lambda: False)
         yield topo.devices
 
@@ -174,12 +177,16 @@ class TestServingProgramsCarryThePool:
         to hold a weight, so it is not built from a model."""
         import types
         from paddle_tpu.inference.engine.runner import ModelRunner
+        from paddle_tpu.models.llama import serving_plan
         r = ModelRunner.__new__(ModelRunner)
-        r.cfg = types.SimpleNamespace(rms_norm_eps=1e-5, rope_theta=1e6)
+        r.cfg = types.SimpleNamespace(
+            rms_norm_eps=1e-5, rope_theta=1e6, num_attention_heads=NH,
+            num_key_value_heads=KVH, hidden_size=HIDDEN,
+            num_hidden_layers=self.L)
+        r.plan = serving_plan(r.cfg, None)
         r.mesh = None
         r.max_batch, r.page, r.chunk = SLOTS, PAGE, CHUNK
         r.n_pages, r.trash_page = self.N_PAGES, self.N_PAGES - 1
-        r.nh, r.kvh, r.D = NH, KVH, D
         r.use_kernel, r.kv_quant = True, int8
         return r
 
@@ -289,3 +296,95 @@ class TestServingProgramsCarryThePool:
         # nothing of the filter is hoisted out of the branch
         assert text.count(" sort(") == sampled.count(" sort(") >= 1
 
+
+
+class TestSolarOpen2Programs:
+    """The new cell's kernels and its two whole programs on the v5e's
+    compiler at the cell's own shapes (bench/configs/solar-open2-250b.json:
+    published widths, one period of 4 layers, 40 of 320 experts, 24,576
+    rows of the vocabulary, 64 slots x 3,072 tokens, chunks of 128): what
+    Mosaic refuses shows here first, and so does what the programs hold
+    beside their arguments."""
+    B, MAX_LEN, CHUNK, VOCAB, HELD = 64, 3072, 128, 24576, 40
+
+    def test_kda_step_at_64_rows(self, v5e):
+        f32 = jnp.float32
+        rows, heads, d = self.B, 64, 128
+        text = compile_for(
+            SingleDeviceSharding(v5e[0]), kda.kda_step,
+            ((3 * (rows + 1), heads, d, d), f32), ((rows,), jnp.int32),
+            ((rows, heads, d), f32), ((rows, heads, d), f32),
+            ((rows, heads, d), f32), ((rows, heads, d), f32),
+            ((rows, heads), f32))
+        assert re.search(r"%kda_step(\.\d+)? = .*custom-call\(", text)
+
+    @pytest.mark.parametrize("rows", [64 * 8, 128 * 8], ids=["decode", "chunk"])
+    @pytest.mark.parametrize("k,n", [(4096, 1280), (1280, 4096)],
+                             ids=["up", "down"])
+    def test_moe_gmm_over_a_stack_of_layers(self, v5e, rows, k, n):
+        text = compile_for(
+            SingleDeviceSharding(v5e[0]),
+            lambda x, w, gs: mg.moe_gmm(x, w, gs, jnp.float32),
+            bf16(rows, k), bf16(3 * self.HELD, k, n),
+            ((3 * self.HELD,), jnp.int32))
+        assert re.search(r"%moe_gmm(\.\d+)? = .*custom-call\(", text)
+
+    def runner_and_arguments(self, sharding):
+        from paddle_tpu.inference.engine.runner import _COUNT_ROWS, ModelRunner
+        from paddle_tpu.models import solar_open2 as so
+        cfg = so.SolarOpen2Config(vocab_size=self.VOCAB, num_hidden_layers=4,
+                                  experts_held=self.HELD)
+        n_pages = self.B * self.MAX_LEN // PAGE + 1
+        r = ModelRunner.__new__(ModelRunner)
+        r.cfg, r.mesh = cfg, None
+        r.max_batch, r.page, r.chunk = self.B, PAGE, self.CHUNK
+        r.n_pages, r.trash_page = n_pages, n_pages - 1
+        r.use_kernel, r.kv_quant = True, False
+        r.plan = plan = so.serving_plan(cfg, None, kernels=True)
+
+        def sds(shape, dt=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+        H = cfg.hidden_size
+        W = {"embed": sds((self.VOCAB, H)), "norm": sds((H,)),
+             "head": sds((H, self.VOCAB))}
+        for kind, n in plan.period:
+            for name, (shape, _) in so.layer_leaves(cfg, kind).items():
+                W[f"{kind}.{name}"] = sds((cfg.periods, n) + tuple(shape))
+        pages = sds((1, n_pages, PAGE, plan.kvh, plan.D))
+        state = sds((3, self.B + 1, 64, 128, 128), jnp.float32)
+        cache = (pages, pages, state,
+                 sds((3, self.B + 1, 3, cfg.conv_channels)),
+                 sds((len(_COUNT_ROWS), plan.counts), jnp.int32))
+        i32, f32 = jnp.int32, jnp.float32
+        table = self.MAX_LEN // PAGE
+        decode = [sds((self.B,), i32), sds((self.B,), i32),
+                  sds((self.B, table), i32), sds((self.B,), i32)] + [
+            sds((self.B,), dt) for dt in (i32, f32, f32, i32, i32, i32)]
+        prefill = [sds((self.CHUNK,), i32), sds((), i32), sds((table,), i32),
+                   sds((), i32)] + [
+            sds((), dt) for dt in (i32, f32, f32, i32, i32)] + [sds((), i32)]
+        return r, W, cache, {"decode": decode, "prefill": prefill}
+
+    @pytest.mark.parametrize("kind", ["decode", "prefill"])
+    def test_whole_program_fits_and_copies_no_pool(self, v5e, kind):
+        r, W, cache, rest = self.runner_and_arguments(
+            SingleDeviceSharding(v5e[0]))
+        prog = r._build_decode(1) if kind == "decode" else r._build_prefill()
+        compiled = prog.lower(W, cache, *rest[kind]).compile()
+        mem = compiled.memory_analysis()
+        held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        print(f"solar {kind}: arguments {mem.argument_size_in_bytes / 2**30:.2f}"
+              f" GiB, temporaries {mem.temp_size_in_bytes / 2**30:.3f} GiB")
+        assert held < 11 * 2**30
+        state_bytes = int(np.prod(cache[2].shape)) * 4
+        assert mem.temp_size_in_bytes < state_bytes // 2
+        text = compiled.as_text()
+        # neither the state pool nor a layer's experts is copied or sliced
+        # out in front of its kernel
+        for shape in (r"f32\[(?:3,65|195),64,128,128\]",
+                      r"bf16\[(?:1,)*40,(?:4096,1280|1280,4096)\]"):
+            assert not re.findall(
+                rf"= {shape}\S* (?:copy|dynamic-slice|gather)\(", text), shape
+        for name in ("moe_gmm", "paged_attention") + (
+                ("kda_step",) if kind == "decode" else ()):
+            assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text), name

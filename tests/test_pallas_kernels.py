@@ -220,3 +220,82 @@ def test_llama3_shaped_train_step_scans():
         losses.extend(np.asarray(out._data, np.float32).tolist())
     assert np.isfinite(losses).all()
     assert losses[-1] < losses[0]      # it actually trains
+
+
+# ---- the delta-rule step on a pool of states, and the experts' grouped products
+
+def _kda_inputs(B, H, D, R, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    pool = jax.random.normal(ks[0], (R, H, D, D), jnp.float32)
+    q = jax.random.normal(ks[1], (B, H, D))
+    k = jax.random.normal(ks[2], (B, H, D))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[3], (B, H, D))
+    g = -0.1 * jnp.abs(jax.random.normal(ks[4], (B, H, D)))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[5], (B, H)))
+    return pool, q, k, v, g, beta
+
+
+@pytest.mark.parametrize("H,D", [(32, 128), (4, 16)], ids=["two_blocks", "tiny"])
+def test_kda_step_updates_the_named_states_in_place(H, D):
+    """Five rows against a pool of nine states; rows 1 and 3 are idle and
+    name the idle state (8). The live rows' states and outputs are the
+    reference's; every state nobody named is bit for bit what it was."""
+    from paddle_tpu.ops.pallas.kda import kda_step, kda_step_ref
+    pool, q, k, v, g, beta = _kda_inputs(5, H, D, 9)
+    rows = jnp.array([3, 8, 0, 8, 5], jnp.int32)
+    o, new = kda_step(pool, rows, q, k, v, g, beta)
+    o_ref, new_ref = kda_step_ref(pool, rows, q, k, v, g, beta)
+    live = np.array([0, 2, 4])
+    np.testing.assert_allclose(np.asarray(o)[live], np.asarray(o_ref)[live],
+                               atol=2e-5)
+    named = np.array([3, 0, 5])
+    np.testing.assert_allclose(np.asarray(new)[named],
+                               np.asarray(new_ref)[named], atol=2e-5)
+    untouched = np.array([1, 2, 4, 6, 7])
+    assert np.array_equal(np.asarray(new)[untouched],
+                          np.asarray(pool)[untouched])
+    # the pool is an alias of the kernel's output, not a copy beside it
+    text = jax.jit(kda_step).lower(pool, rows, q, k, v, g, beta).as_text()
+    assert "kda_step" in text
+
+
+def test_kda_recurrence_is_the_step_repeated_and_skips_padding():
+    from paddle_tpu.ops.pallas.kda import kda_recurrence, kda_step_ref
+    pool, q, k, v, g, beta = _kda_inputs(6, 4, 16, 1, seed=1)
+    # positions 4 and 5 are padding: beta = 0 and g = 0 leave the state
+    g = g.at[4:].set(0.0)
+    beta = beta.at[4:].set(0.0)
+    o, S = kda_recurrence(pool[0], q, k, v, g, beta)
+    state = pool
+    for t in range(4):
+        o_t, state = kda_step_ref(state, jnp.array([0]), q[t:t + 1],
+                                  k[t:t + 1], v[t:t + 1], g[t:t + 1],
+                                  beta[t:t + 1])
+        np.testing.assert_allclose(np.asarray(o[t]), np.asarray(o_t[0]),
+                                   atol=1e-5)
+    np.testing.assert_allclose(np.asarray(S), np.asarray(state[0]), atol=1e-5)
+
+
+@pytest.mark.parametrize("sizes", [[5, 0, 17, 0, 3, 9], [0, 0, 0, 34, 0, 0],
+                                   [0, 0, 0, 0, 0, 0]],
+                         ids=["empty_experts", "one_expert", "no_rows"])
+def test_moe_gmm_matches_ragged_dot(sizes):
+    """Rows sorted by expert; experts nobody chose have empty groups; the
+    rows past the groups' sum (40 rows, 34 assigned) hold nothing read."""
+    from paddle_tpu.ops.pallas.moe_gmm import moe_gmm, moe_gmm_ref
+    ks = jax.random.split(jax.random.PRNGKey(2), 2)
+    lhs = jax.random.normal(ks[0], (40, 256), jnp.float32)
+    rhs = jax.random.normal(ks[1], (6, 256, 384), jnp.float32)
+    gs = jnp.asarray(sizes, jnp.int32)
+    out = moe_gmm(lhs, rhs, gs)
+    want = moe_gmm_ref(lhs, rhs, gs)
+    assert out.shape == want.shape == (40, 384)
+    n = int(gs.sum())
+    np.testing.assert_allclose(np.asarray(out)[:n], np.asarray(want)[:n],
+                               rtol=1e-5, atol=1e-3)
+    by_hand = np.concatenate(
+        [np.asarray(lhs)[a:b] @ np.asarray(rhs)[e] for e, (a, b) in enumerate(
+            zip(np.cumsum([0] + sizes[:-1]), np.cumsum(sizes)))])
+    np.testing.assert_allclose(np.asarray(want)[:n], by_hand, rtol=1e-5,
+                               atol=1e-3)
