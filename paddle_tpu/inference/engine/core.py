@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import math
 import time
+from collections import namedtuple
 
 import numpy as np
 import jax.numpy as jnp  # noqa: F401  (re-exported for monkeypatch parity)
@@ -61,6 +62,13 @@ class _TransientTier(Exception):
     def __init__(self, err):
         super().__init__(str(err))
         self.err = err
+
+
+# a decode dispatch that has been launched and whose tokens the host has
+# not emitted: what run_decode handed back (np.asarray of it waits), its
+# block size, its (slot, request) rows, when it was launched, and whether
+# its program had run before (a compile is no sample of a token's latency)
+_Flight = namedtuple("_Flight", "toks k rows t0 steady")
 
 
 _NO_STATE_HANDOFF = ("the hand-off carries pages and no state, and the "
@@ -281,6 +289,8 @@ class LLMEngine(_SpecOrchestration):
                                         max_delay=0.25, seed=seed))
         self._any_deadline = default_deadline is not None
         self._step_phase = ("admit", ())
+        self._flight = None             # the decode dispatch one step ahead
+        self._t_landed = 0.0            # when the last one's tokens came home
         self.step_failures = 0          # step dispatches that raised
         self.step_retries = 0           # transient-path retry invocations
         self.quarantine_probes = 0      # single-slot isolation probes run
@@ -359,7 +369,10 @@ class LLMEngine(_SpecOrchestration):
         """Cancel a request wherever it is: waiting (dequeued) or mid-serve
         (slot released — pages return through the refcount machinery, so
         prefix-cache pages other slots share stay live).  Returns True if
-        the request was found live; False if unknown or already terminal."""
+        the request was found live; False if unknown or already terminal.
+        The decode step in flight comes home first: what the request had
+        already been served is emitted, and it may have finished by it."""
+        self._drain()
         return self.sched.cancel(rid)
 
     def _next_seed(self, r):
@@ -380,6 +393,9 @@ class LLMEngine(_SpecOrchestration):
                 # about to write [start, start+n): un-share any page another
                 # slot still maps (a fully-cached prompt re-prefilling its
                 # final token into the last shared page lands here)
+                if self._would_preempt(
+                        (start + n - 1) // self.page - start // self.page + 1):
+                    self._drain()
                 sched.cow_unshare(slot, start, n)
             toks = np.zeros((self.chunk,), np.int32)
             toks[:n] = r.prompt[start:start + n]
@@ -394,6 +410,9 @@ class LLMEngine(_SpecOrchestration):
                 toks, start, sched.slot_tables[slot], n,
                 0 if r.do_sample else 1, r.temperature, r.top_p, r.top_k,
                 self._next_seed(r), slot)
+            # the chunk needed none of the tokens of the decode step in
+            # flight and is queued behind it: now they come home
+            self._drain()
             if finishes:
                 # only the chunk that ends a prompt reads its sample
                 with _obs.trace_span("runner.wait"):
@@ -411,7 +430,31 @@ class LLMEngine(_SpecOrchestration):
 
     def step(self):
         """One engine dispatch: a prefill chunk if any slot is mid-prompt,
-        else one decode token for every active slot. Returns #slots served.
+        else one decode block for every slot with a token still to come.
+        Returns #slots served.
+
+        The decode loop runs ONE step ahead. A step admits, plans and
+        launches its dispatch, and only then waits for and emits the tokens
+        of the decode step launched by the step before, which is still
+        ``_flight``: the rows that decoded there decode again from the
+        token the device kept (``runner.run_decode``'s ``take``), and the
+        plan needs no token, only counts — lengths advance at launch
+        (``sched.launch``), a request whose token in flight is its last
+        sits the dispatch out (``sched.room``), pages grow ahead of the
+        advanced lengths. So after a step returns, the last decode step's
+        tokens may still be in flight: a request's slot stays its own until
+        they are emitted, and the next step — or whatever else wants a slot
+        or a page as the host knows them — brings them home first
+        (``_drain``). A request that has an ``eos`` rides ahead too: if the
+        step in flight produced it, the row launched after it is waste,
+        its token is dropped when it lands, and its write falls in pages
+        that were the request's own.
+
+        What is not a plain decode after a plain decode lands everything
+        first: a verify step (its drafts need every token), the isolation
+        sweep, ``decode_block="auto"`` (its fit needs a dispatch's own wall
+        time), growth that would preempt.  A prefill chunk needs none of
+        the tokens and is launched behind the step in flight.
 
         This is the failure-isolation boundary: a step that raises never
         kills the engine.  Transient errors (``err.transient`` truthy) are
@@ -455,70 +498,149 @@ class LLMEngine(_SpecOrchestration):
                 self._prefill_chunk(slot)
                 return 1
         live = [(s, r) for s, r in enumerate(sched.slots) if r is not None]
-        if not live:
-            return 0
-        if self._spec is not None:
+        if self._spec is not None and live:
+            # nothing is in flight here: a speculating engine lands every
+            # decode in its own step, the drafts need all of the tokens
             with _obs.trace_span("engine.prepare"):
                 props = self._propose_drafts(live)
             if any(props.values()):
                 return self._spec_step(live, props)
             # no slot has a draft this step: the plain decode block below
             # amortizes dispatch cost better than a 1-row verify would
+        rows, k = self._plan_decode(live)
+        served = 0
+        if self._flight is not None and (
+                not rows or self._would_preempt(self._growth(rows, k))):
+            # nobody decodes on (every token in flight is a last one), or
+            # the pool is too dry to grow without preempting: the tokens in
+            # flight come home first
+            served = self._drain()
+            rows, k = self._plan_decode(
+                [(s, r) for s, r in live if sched.slots[s] is r])
+        if not rows:
+            return served
         with _obs.trace_span("engine.prepare"):
-            # block size: largest power of two <= every slot's remaining
-            # budget, capped by decode_block (or the RTT-adapted target in
-            # auto mode); any eos request needs per-token host inspection -> 1
-            cap = self._block_target if self._auto_block else self.decode_block
-            k = min(cap, min(r.max_new - len(r.out) for _, r in live))
-            if any(r.eos is not None for _, r in live):
-                k = 1
-            k = 1 << max(0, k.bit_length() - 1)              # floor to pow2
-            for slot, r in live:
+            for slot, r in rows:
                 if sched.slots[slot] is not r:
                     continue        # preempted by an earlier slot's growth
                 sched.ensure_page(slot, ahead=k)
-            # growth may have preempted members of `live` — drop them before
+            # growth may have preempted members of `rows` — drop them before
             # building the batch (a stale entry would re-allocate pages to an
             # empty slot and decode a request that is back in the queue)
-            live = [(s, r) for s, r in live if sched.slots[s] is r]
-            if not live:
-                return 0
-            args = self._decode_args(live)
-            self._step_phase = ("decode", tuple(s for s, _ in live))
-            _faults.maybe_fire("serving.step", rids=[r.rid for _, r in live],
+            rows = [(s, r) for s, r in rows if sched.slots[s] is r]
+            if not rows:
+                return served
+            args = self._decode_args(rows)
+            # the dispatch gets the lengths and tables as they are NOW:
+            # launch() and the next plan move the scheduler's own
+            lens, tables = sched.lens.copy(), sched.slot_tables.copy()
+            self._step_phase = ("decode", tuple(s for s, _ in rows))
+            _faults.maybe_fire("serving.step", rids=[r.rid for _, r in rows],
                                phase="decode")
-            compile_call = not self.runner.has_decode_program(k)
+            steady = self.runner.has_decode_program(k)
             self._m.decode.inc()
-            self._m.count_argmax("decode", (r for _, r in live))
+            self._m.decode_launches[self._flight is not None].inc()
+            self._m.count_argmax("decode", (r for _, r in rows))
         # timed: the auto-fit below needs the wall time whatever is switched on
-        with _obs.trace_span("decode", rid=[r.rid for _, r in live],
-                             trace_id=[r.trace_id for _, r in live],
+        with _obs.trace_span("decode", rid=[r.rid for _, r in rows],
+                             trace_id=[r.trace_id for _, r in rows],
                              timed=self._auto_block, block=k) as sp:
-            toks = self.runner.run_decode(
-                k, args[0], sched.lens, sched.slot_tables, *args[1:])  # [k, B]
+            t0 = time.perf_counter()
+            toks = self.runner.run_decode(k, args[0], lens, tables, *args[1:])
+            if self._auto_block:
+                # the fit wants the dispatch's own wall time: wait in here
+                toks = np.asarray(toks)
+        for slot, _ in rows:
+            sched.launch(slot, k)
+        before, self._flight = self._flight, _Flight(toks, k, rows, t0,
+                                                     steady)
+        if before is not None:
+            self._land(before)
+        if self._auto_block and steady:
+            self._record_block_sample(k, sp.dur)
+        if self._auto_block or self._spec is not None:
+            self._drain()       # neither plans on counts alone (step())
+        return len(rows)
+
+    def _plan_decode(self, live):
+        """Who of ``live`` decodes in the next dispatch, and its block size
+        — from counts alone, whatever is in flight: a request with no room
+        left (its last token is in flight) sits out; the block is the
+        largest power of two <= every row's room, capped by decode_block
+        (or the RTT-adapted target in auto mode); any eos request needs
+        per-token host inspection -> 1."""
+        sched = self.sched
+        rows = [(s, r) for s, r in live if sched.room(s) > 0]
+        if not rows:
+            return rows, 0
+        cap = self._block_target if self._auto_block else self.decode_block
+        k = min(cap, min(sched.room(s) for s, _ in rows))
+        if any(r.eos is not None for _, r in rows):
+            k = 1
+        return rows, 1 << max(0, k.bit_length() - 1)         # floor to pow2
+
+    def _growth(self, rows, k):
+        """Pages ``ensure_page(ahead=k)`` would claim for ``rows``."""
+        sched = self.sched
+        return sum(max(0, -(-(int(sched.lens[s]) + k) // self.page)
+                       - int(sched.n_alloc[s])) for s, _ in rows)
+
+    def _drain(self):
+        """Bring the decode step in flight home: wait for its tokens and
+        emit them (nothing in flight: nothing happens).  Returns #slots it
+        served.  Whoever is about to touch a slot or a page as the host
+        knows them calls this first."""
+        flight, self._flight = self._flight, None
+        return 0 if flight is None else self._land(flight)
+
+    def _would_preempt(self, pages):
+        """Whether claiming ``pages`` now could preempt a slot while tokens
+        are in flight: the pool has fewer, and a victim is folded (prompt +
+        output so far) from what has been emitted."""
+        return self._flight is not None and self.pool.n_available() < pages
+
+    def _land(self, flight):
+        """Wait for a launched decode dispatch's tokens (``runner.wait``,
+        inside what ``run_decode`` returned) and emit them."""
+        sched = self.sched
+        try:
+            toks = np.asarray(flight.toks)                   # [k, B]
+        except Exception:
+            # the tokens are lost, and with them those of a dispatch
+            # launched behind: lengths go back to what was emitted, and the
+            # isolation sweep decodes every row again from there
+            later, self._flight = self._flight, None
+            sched.recall()
+            rows = flight.rows + (later.rows if later is not None else [])
+            self._step_phase = ("decode",
+                                tuple(dict.fromkeys(s for s, _ in rows)))
+            raise
         with _obs.trace_span("engine.emit"):
             self._m.count_routing(self.runner.take_routing_counts())
-            if self._auto_block and not compile_call:
-                # the host sync in run_decode makes the span's wall time a
-                # true dispatch sample
-                self._record_block_sample(k, sp.dur)
-            if not compile_call and _obs.enabled():
-                # dispatch served k tokens for each live slot; exclude the
-                # compile call so the histogram reflects steady-state latency
-                for _ in live:
-                    self._m.token_latency.observe(sp.dur / k)
-            for j in range(k):
-                for slot, r in live:
-                    if sched.slots[slot] is not r:           # released mid-block
+            now = time.perf_counter()
+            if flight.steady and _obs.enabled():
+                # what this dispatch added to its rows' streams: the time
+                # since its launch or, launched ahead, since the dispatch
+                # before landed; a compile call is no sample
+                per_token = (now - max(flight.t0, self._t_landed)) / flight.k
+                for _ in flight.rows:
+                    self._m.token_latency.observe(per_token)
+            self._t_landed = now
+            for j in range(flight.k):
+                for slot, r in flight.rows:
+                    if sched.slots[slot] is not r:
+                        # released since the launch: mid-block, by the eos
+                        # of the step before, by a cancel or a deadline
                         continue
-                    sched.lens[slot] += 1
-                    sched.emit(slot, int(toks[j, slot]))
-        return len(live)
+                    sched.land(slot, int(toks[j, slot]))
+        return len(flight.rows)
 
-    def _decode_args(self, live):
-        """Host arrays of one decode dispatch over the ``live`` slots, in
+    def _decode_args(self, rows):
+        """Host arrays of one decode dispatch over ``rows``, in
         ``run_decode``'s order without ``lens`` and ``tables``: tokens,
-        active, then the per-slot sampling parameters."""
+        active, the per-slot sampling parameters, then ``take`` — a row
+        with a token in flight decodes from the one the device kept, the
+        others from the last one emitted."""
         B = self.max_batch
         tokens = np.zeros((B,), np.int32)
         active = np.zeros((B,), np.int32)
@@ -528,23 +650,34 @@ class LLMEngine(_SpecOrchestration):
         topk = np.zeros((B,), np.int32)
         seeds = np.zeros((B,), np.int32)
         fold = np.zeros((B,), np.int32)
-        for slot, r in live:
+        take = np.zeros((B,), np.int32)
+        for slot, r in rows:
             active[slot] = 1
-            tokens[slot] = r.out[-1]
+            if self.sched.in_flight[slot]:
+                take[slot] = 1
+            else:
+                tokens[slot] = r.out[-1]
             greedy[slot] = 0 if r.do_sample else 1
             temp[slot] = r.temperature
             topp[slot] = r.top_p
             topk[slot] = r.top_k
             seeds[slot] = self._next_seed(r)
             fold[slot] = 1 if r.seed is None else 0
-        return tokens, active, greedy, temp, topp, topk, seeds, fold
+        return tokens, active, greedy, temp, topp, topk, seeds, fold, take
 
     # ----------------------------------------------------- failure isolation
     def _survive_step_failure(self, e):
         """Handle an exception that escaped :meth:`_step_impl`.  Transient
         errors re-dispatch through the shared backoff policy; everything
         else is attributed to a request and quarantined.  Returns the #slots
-        the recovery path ended up serving."""
+        the recovery path ended up serving.  Whatever failed, the decode
+        step in flight comes home first, so a surviving request loses no
+        token to the sweep (the failure then found nothing launched: the
+        scheduler's lengths advance only once a launch has returned)."""
+        try:
+            self._drain()
+        except Exception as lost:  # noqa: BLE001 — _land named the rows
+            e = lost
         phase, slots = self._step_phase
         if phase == "admit":
             # failed outside any dispatch — host-side bookkeeping, an
@@ -641,8 +774,8 @@ class LLMEngine(_SpecOrchestration):
         self._m.count_argmax("decode", (r,))
         with _obs.trace_span("decode", rid=r.rid, trace_id=r.trace_id,
                              block=1, probe=1):
-            toks = self.runner.run_decode(
-                1, args[0], sched.lens, sched.slot_tables, *args[1:])
+            toks = np.asarray(self.runner.run_decode(
+                1, args[0], sched.lens, sched.slot_tables, *args[1:]))
         sched.lens[slot] += 1
         sched.emit(slot, int(toks[0, slot]))
 
@@ -702,6 +835,9 @@ class LLMEngine(_SpecOrchestration):
                 and steps < max_steps:
             self.step()
             steps += 1
+        # what is still in flight is waste: the row of a request whose eos
+        # the step before produced
+        self._drain()
         return steps
 
     def _refresh_gauges(self):
@@ -778,6 +914,12 @@ class LLMEngine(_SpecOrchestration):
         def attempt():
             try:
                 _faults.maybe_fire("kv.spill", page=int(p))
+                if self._flight is not None:
+                    # the gather is queued behind the step in flight: read
+                    # its tokens first, so that wait is runner.wait's, not
+                    # the copy's. Emitting them waits for the step: a slot
+                    # they free could be the one this page is claimed for
+                    np.asarray(self._flight.toks)
                 return self.runner.pages_to_host([int(p)])
             except Exception as err:
                 if getattr(err, "transient", False):
@@ -861,6 +1003,7 @@ class LLMEngine(_SpecOrchestration):
         [L, n, page, ...] numpy arrays}``, or None when even the first key
         misses everywhere — the puller then recomputes."""
         refuse_recurrent(self.runner.plan, "export_pages", _NO_STATE_HANDOFF)
+        self._drain()
         host = self.pool.host
         served, dev, host_blocks = [], [], {}
         for i, key in enumerate(keys):
@@ -903,6 +1046,7 @@ class LLMEngine(_SpecOrchestration):
         refuse_recurrent(self.runner.plan, "import_pages", _NO_STATE_HANDOFF)
         if not payload:
             return 0
+        self._drain()
         keys, block = payload["keys"], payload["block"]
         host = self.pool.host
         n = 0
@@ -1023,7 +1167,12 @@ class LLMEngine(_SpecOrchestration):
         """Finalize EVERY live request (waiting and running) as FAILED with
         ``error`` recorded — the front door calls this when a replica's
         step loop dies, so inflight requests end with a typed terminal
-        status instead of hanging their streams forever."""
+        status instead of hanging their streams forever.  What the decode
+        step in flight had served them is emitted first, if it can be."""
+        try:
+            self._drain()
+        except Exception:  # noqa: BLE001 — the loop is dead, they fail anyway
+            self.step_failures += 1
         self.sched.fail_all(error)
 
     def status(self, rid):

@@ -31,6 +31,10 @@ class _EngineMetrics:
         self.occupancy = _obs.SERVING_OCCUPANCY.labels(**e)
         self.prefill = _obs.SERVING_DISPATCHES.labels(kind="prefill", **e)
         self.decode = _obs.SERVING_DISPATCHES.labels(kind="decode", **e)
+        # decode dispatches by whether the one before was still unread
+        self.decode_launches = [
+            _obs.SERVING_DECODE_LAUNCHES.labels(ahead=a, **e)
+            for a in ("0", "1")]
         self.argmax = {k: _obs.SERVING_ARGMAX_DISPATCHES.labels(kind=k, **e)
                        for k in ("prefill", "decode", "verify")}
         self.tokens = _obs.SERVING_TOKENS.labels(**e)

@@ -18,6 +18,11 @@ TPU-native design (carried over from the monolithic serving engine):
   slot its last token — token-level continuous batching (Orca-style).  A
   third VERIFY program scores K+1 consecutive positions per request for
   speculative decoding.
+- A decode dispatch is LAUNCHED, not awaited: ``run_decode`` returns at
+  once with a handle that waits when the host asks for the tokens, and the
+  program keeps each row's last token on the device for the dispatch after
+  it, which takes it from there for the rows the engine names. So the
+  engine launches step N+1 before it reads step N (``LLMEngine.step``).
 - Sampling happens IN-GRAPH with per-slot parameters (greedy / temperature /
   top-k / top-p / seed), replicating models.llama._sample token-for-token.
   What a dispatch's rows ask for decides the work (``_sample_rows``): when
@@ -142,6 +147,33 @@ def _argmax_only(greedy):
     return int(np.all(np.asarray(greedy) > 0))
 
 
+class _DecodeTokens:
+    """One decode dispatch's tokens, on the device until somebody asks:
+    ``np.asarray`` of it is host tokens [k, B], the routing counts a model
+    sends home behind them taken off. The first asking waits for the
+    program (``runner.wait``); dispatches are read in the order they were
+    launched."""
+
+    __slots__ = ("_runner", "_dev", "_host", "_rows")
+
+    def __init__(self, runner, dev, rows):
+        self._runner, self._dev, self._host, self._rows = (
+            runner, dev, None, rows)
+
+    @property
+    def unread(self):
+        return self._host is None
+
+    def __array__(self, dtype=None, copy=None):
+        if self._host is None:
+            with _obs.trace_span("runner.wait"):
+                host = np.asarray(self._dev)
+            if self._runner.plan.counts:
+                host = self._runner._strip_counts(host, self._rows)
+            self._host, self._dev = host, None
+        return self._host if dtype is None else self._host.astype(dtype)
+
+
 class ModelRunner:
     """Weights + paged KV + jitted forwards over one mesh (slice)."""
 
@@ -210,6 +242,13 @@ class ModelRunner:
         self._counts_seen = np.zeros((len(_COUNT_ROWS), plan.counts),
                                      np.int64)
         self._counts_grown = np.zeros_like(self._counts_seen)
+        # the last decode dispatch's tokens: the rows' last ones as the next
+        # dispatch takes them back on the device (``_build_decode``), and
+        # what ``run_decode`` handed the engine, which knows if they were read
+        self._last = jnp.zeros(
+            (self.max_batch,), jnp.int32,
+            device=None if mesh is None else NamedSharding(mesh, P()))
+        self._tokens = None
         self._prefill = self._build_prefill()
         self._decode_programs: dict = {}
         self._verify_programs: dict = {}
@@ -409,17 +448,31 @@ class ModelRunner:
         which a per-token loop pays in full; a K-block pays 1/K of it per
         token. The host sees the K sampled tokens afterwards, so eos
         requests cap K at 1 (every token must be inspected). Mirrors
-        generate()'s tokens_per_dispatch."""
+        generate()'s tokens_per_dispatch.
+
+        The feedback goes on ACROSS dispatches too: the program hands back,
+        beside the K tokens a row, each row's last one ``[B]`` as a value of
+        its own, and takes the dispatch before's as ``prev`` with a mask
+        ``take [B]``. A row whose mask is set decodes from ``prev`` — the
+        device's own token, which the host may not have read yet — and
+        every other row from the host's ``tokens`` (a row fresh from
+        prefill; every row when nothing is in flight). So the engine can
+        launch step N+1 while step N's tokens are still on their way home
+        (``LLMEngine.step``). One program a K whatever the dispatch before
+        was: ``prev`` has one shape."""
         page = self.page
         eps = self.cfg.rms_norm_eps
         trash = self.trash_page
 
         def block(W, cache, tokens, lens, tables, active,
-                  greedy, temp, topp, topk, seeds, fold):
+                  greedy, temp, topp, topk, seeds, fold, take, prev):
             # tokens [B] int32; lens [B] tokens already cached; tables
             # [B, S] page ids; active [B] 0/1; sampling params [B].
             # fold [B]: 1 -> vary the sampling key per block step (seedless
             # requests); 0 -> reuse it (fixed-seed generate parity).
+            # take [B]: 1 -> the row's token is prev's, not tokens'.
+            tokens = jnp.where(take > 0, prev, tokens)
+
             def one(carry, i):
                 tokens, lens, cache = carry
                 x = W["embed"][tokens]                   # [B, H]
@@ -448,7 +501,7 @@ class ModelRunner:
                 lens = lens + (active > 0).astype(lens.dtype)
                 return (tokens, lens, cache), nxt
 
-            (_, _, cache2), toks = jax.lax.scan(
+            (last, _, cache2), toks = jax.lax.scan(
                 one, (tokens, lens, cache),
                 jnp.arange(K, dtype=jnp.int32))
             if self.plan.counts:
@@ -457,7 +510,12 @@ class ModelRunner:
                 toks = jnp.concatenate(
                     [toks, jnp.broadcast_to(cache2[-1].reshape(1, -1),
                                             (K, cache2[-1].size))], axis=1)
-            return toks, cache2                          # toks [K, B]
+            if self.mesh is not None:
+                # the next dispatch's ``prev``: held to the placement the
+                # first one's was given, so a K compiles once
+                last = jax.lax.with_sharding_constraint(
+                    last, NamedSharding(self.mesh, P()))
+            return (toks, last), cache2                  # toks [K, B]
 
         return jax.jit(block, donate_argnums=(1,))
 
@@ -598,30 +656,48 @@ class ModelRunner:
             np.float32(topp), np.int32(topk), np.int32(seed), *state)
 
     def run_decode(self, k, tokens, lens, tables, active,
-                   greedy, temp, topp, topk, seeds, fold):
-        """Dispatch one K-token decode block; returns host tokens [k, B]
-        (the np.asarray sync makes the caller's wall time a true dispatch
-        sample)."""
+                   greedy, temp, topp, topk, seeds, fold, take=None):
+        """Launch one K-token decode block and return WITHOUT waiting for
+        it, as ``run_prefill`` does: what comes back is a
+        :class:`_DecodeTokens`, which answers ``np.asarray`` with host
+        tokens [k, B] — waiting for them under ``runner.wait`` the first
+        time it is asked. The copy home starts here.
+
+        ``take`` [B] (none: no row): the rows that decode from the token
+        the dispatch before left ON THE DEVICE, whether or not the host has
+        read it; the others decode from ``tokens``. Every argument is a
+        HOST array, and the dispatch's own from here on: their way to the
+        device may outlast the call, so the caller hands over copies of
+        what it goes on to change."""
         prog = self._decode_programs.get(k)
         if prog is None:
             prog = self._decode_programs[k] = self._build_decode(k)
+        B = len(tokens)
+        if take is None:
+            take = np.zeros((B,), np.int32)
         attrs = {}
         if _obs.enabled():
             # what a roofline needs of this dispatch: the rows that decode
-            # and the valid context each of them reads
+            # and the valid context each of them reads; ahead: launched
+            # while the dispatch before's tokens were unread
             ctx = (np.asarray(lens) + 1)[np.asarray(active) > 0]
             attrs = {"kind": "decode", "rows": int(ctx.size),
                      "ctx_sum": int(ctx.sum()), "k": int(k),
-                     "argmax_only": _argmax_only(greedy)}
+                     "argmax_only": _argmax_only(greedy),
+                     "ahead": int(self.decode_unread)}
             if self.plan.recurrent:
                 attrs["state_rows"] = int(ctx.size)
-        toks = self._launch(("decode", k), prog, attrs, tokens, lens, tables,
-                            active, greedy, temp, topp, topk, seeds, fold)
-        with _obs.trace_span("runner.wait"):
-            toks = np.asarray(toks)
-        if self.plan.counts:
-            toks = self._strip_counts(toks, len(tokens))
-        return toks
+        toks, self._last = self._launch(
+            ("decode", k), prog, attrs, tokens, lens, tables, active, greedy,
+            temp, topp, topk, seeds, fold, take, self._last)
+        toks.copy_to_host_async()
+        self._tokens = _DecodeTokens(self, toks, B)
+        return self._tokens
+
+    @property
+    def decode_unread(self):
+        """Whether the last decode dispatch's tokens are still unread."""
+        return self._tokens is not None and self._tokens.unread
 
     def _strip_counts(self, toks, B):
         """Take the routing sums off the back of a decode block's tokens

@@ -9,7 +9,10 @@ the injected ``copy_page`` callable (the copy half of copy-on-write, bound
 to :meth:`~.runner.ModelRunner.copy_page` by the engine).  The
 :class:`~.core.LLMEngine` facade drives it: ``admit()`` at step entry,
 ``emit()`` per generated token, ``release()/preempt_youngest()`` on the
-failure and pool-pressure paths.
+failure and pool-pressure paths.  The decode loop runs one step ahead of
+what has been emitted: ``launch()`` counts a dispatch's tokens into a slot's
+length when they are launched, ``room()`` says who may still launch one,
+``land()`` emits one that has come home (``LLMEngine.step``).
 
 ``detach()`` / ``admit_prefilled()`` are the disaggregation seam: detach
 lifts a freshly-prefilled request out of its slot WITHOUT dropping its page
@@ -58,6 +61,9 @@ class Scheduler:
                                     np.int32)
         self.lens = np.zeros((self.max_batch,), np.int32)
         self.n_alloc = np.zeros((self.max_batch,), np.int32)
+        # tokens of a slot that a decode dispatch has launched and the host
+        # has not read yet; ``lens`` counts them already (``launch``)
+        self.in_flight = np.zeros((self.max_batch,), np.int32)
         self.waiting: deque = deque()
         self.finished: dict = {}
         self._admit_seq = 0
@@ -321,6 +327,7 @@ class Scheduler:
         self.slots[slot] = None
         self.lens[slot] = 0
         self.n_alloc[slot] = 0
+        self.in_flight[slot] = 0
         if status is not None:
             self.finalize(r, status, error=error)
 
@@ -393,6 +400,34 @@ class Scheduler:
         self.slot_tables[slot, needed:] = self.slot_tables[slot, needed - 1]
         self.n_alloc[slot] = needed
 
+    def launch(self, slot, k):
+        """A decode dispatch of ``k`` tokens for this slot has been
+        launched: its length counts them from now on — the next dispatch
+        is planned, and its pages grown, on the length the slot will have
+        — and they stay ``in_flight`` until :meth:`land` hands them over."""
+        self.lens[slot] += k
+        self.in_flight[slot] += k
+
+    def room(self, slot):
+        """Tokens this slot's request may still have LAUNCHED: what is left
+        of its budget (and of ``max_len``) after those in flight.  0: the
+        token in flight is its last, the next dispatch leaves it out."""
+        r = self.slots[slot]
+        return max(0, min(r.max_new - len(r.out) - int(self.in_flight[slot]),
+                          self.max_len - int(self.lens[slot])))
+
+    def land(self, slot, token):
+        """One launched token has come home: emit it."""
+        self.in_flight[slot] -= 1
+        self.emit(slot, token)
+
+    def recall(self):
+        """Forget every token in flight (their dispatch failed, or its
+        tokens were lost): lengths go back to what was emitted.  What the
+        dispatch wrote past them is never attended."""
+        self.lens -= self.in_flight
+        self.in_flight[:] = 0
+
     def emit(self, slot, token):
         """Record one generated token; release the slot when finished."""
         r = self.slots[slot]
@@ -408,7 +443,8 @@ class Scheduler:
                                trace_id=r.trace_id, ttft=r.ttft)
         hit_eos = (r.eos is not None and r.out[-1] == r.eos)
         if (len(r.out) >= r.max_new or hit_eos
-                or int(self.lens[slot]) >= self.max_len):
+                or int(self.lens[slot] - self.in_flight[slot])
+                >= self.max_len):
             self.release(slot, RequestStatus.EOS if hit_eos
                          else RequestStatus.FINISHED)
 

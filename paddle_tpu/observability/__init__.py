@@ -95,6 +95,11 @@ SERVING_ARGMAX_DISPATCHES = REGISTRY.counter(
     "serving_argmax_dispatches_total",
     "dispatches whose rows were all greedy: the program took the arg-max "
     "and skipped the sampling filter", ("engine", "kind"))
+SERVING_DECODE_LAUNCHES = REGISTRY.counter(
+    "serving_decode_launches_total",
+    "decode dispatches, by whether they were launched ahead: while the "
+    "decode dispatch before's tokens were still unread by the host",
+    ("engine", "ahead"))                       # ahead: "0" | "1"
 SERVING_STATE_SLOTS = REGISTRY.gauge(
     "serving_state_slots_in_use",
     "slots whose recurrent state belongs to an admitted request", ("engine",))

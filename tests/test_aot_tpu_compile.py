@@ -215,8 +215,10 @@ class TestServingProgramsCarryThePool:
                               sds((table,), i32), sds((), i32)] + sampling
         sampling = [sds((B,), dt) for dt in (i32, f32, f32, i32, i32, i32)]
         tokens = sds((B, self.KV) if kind == "verify" else (B,), i32)
+        # decode: the mask and the dispatch before's tokens (take, prev)
+        ahead = [sds((B,), i32)] * 2 if kind == "decode" else []
         return W, cache, [tokens, sds((B,), i32), sds((B, table), i32),
-                          sds((B,), i32)] + sampling
+                          sds((B,), i32)] + sampling + ahead
 
     @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
     @pytest.mark.parametrize("kind", ["decode", "prefill", "verify"])
@@ -359,7 +361,8 @@ class TestSolarOpen2Programs:
         table = self.MAX_LEN // PAGE
         decode = [sds((self.B,), i32), sds((self.B,), i32),
                   sds((self.B, table), i32), sds((self.B,), i32)] + [
-            sds((self.B,), dt) for dt in (i32, f32, f32, i32, i32, i32)]
+            sds((self.B,), dt) for dt in (i32, f32, f32, i32, i32, i32,
+                                          i32, i32)]        # .., take, prev
         prefill = [sds((self.CHUNK,), i32), sds((), i32), sds((table,), i32),
                    sds((), i32)] + [
             sds((), dt) for dt in (i32, f32, f32, i32, i32)] + [sds((), i32)]

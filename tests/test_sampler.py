@@ -184,7 +184,8 @@ class TestOneBranchOverTheBatch:
         else:
             prog = r._build_decode(int(kind[-1]))
             args = [np.zeros(B, i32), np.zeros(B, i32),
-                    np.zeros((B, S), i32), np.ones(B, i32)] + per_slot
+                    np.zeros((B, S), i32), np.ones(B, i32)] + per_slot + [
+                np.zeros(B, i32), np.zeros(B, i32)]         # take, prev
         jaxpr = jax.make_jaxpr(prog)(r.W, r.cache, *args).jaxpr
         conds = [e for e in _walk(jaxpr) if e.primitive.name == "cond"]
         assert len(conds) == 1

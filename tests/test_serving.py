@@ -624,7 +624,8 @@ def _program_and_args(r, kind, tables):
         k = int(kind[6:])
         return r._build_decode(k), (
             np.array([5, 9, 7], i32), np.array([3, 10, 13], i32), tables,
-            np.array([1, 1, 0], i32), *sampling(B), np.zeros(B, i32))
+            np.array([1, 1, 0], i32), *sampling(B), np.zeros(B, i32),
+            np.zeros(B, i32), np.zeros(B, i32))      # .., fold, take, prev
     if kind == "prefill":
         return r._prefill, (
             np.arange(1, 9, dtype=i32), i32(4), tables[0], i32(6),
@@ -715,6 +716,8 @@ class TestPoolIsCarried:
         def dispatch(pools):
             toks, after = prog(r.W, tuple(jnp.asarray(p) for p in pools),
                                *args)
+            if kind.startswith("decode"):   # (the block's, each row's last)
+                toks = toks[0]
             return np.asarray(toks), [np.asarray(a) for a in after]
 
         toks, after = dispatch(before)
