@@ -67,12 +67,19 @@ class _TransientTier(Exception):
 # a decode dispatch that has been launched and whose tokens the host has
 # not emitted: what run_decode handed back (np.asarray of it waits), its
 # block size, its (slot, request) rows, when it was launched, and whether
-# its program had run before (a compile is no sample of a token's latency)
-_Flight = namedtuple("_Flight", "toks k rows t0 steady")
+# its program had run before (a compile is no sample of a token's latency);
+# of a block dispatch also, by slot, which of the block's positions it
+# yields: (the first, how many)
+_Flight = namedtuple("_Flight", "toks k rows t0 steady emits")
 
 
 _NO_STATE_HANDOFF = ("the hand-off carries pages and no state, and the "
                      "pages alone do not continue a request")
+
+
+_NO_BLOCK_HANDOFF = ("pages travel by the prefix chain of a prompt whose "
+                     "last token is re-prefilled for its sample, and a "
+                     "block model's prefill yields none")
 
 
 def refuse_recurrent(plan, what, why):
@@ -85,13 +92,34 @@ def refuse_recurrent(plan, what, why):
             f"layers: {why}")
 
 
+def refuse_blocks(plan, what, why):
+    """A model that generates by blocks yields no token at a prompt's last
+    position and several a dispatch: what assumes otherwise and is not
+    made right for it refuses by name at construction."""
+    if plan.block:
+        raise NotImplementedError(
+            f"{what} is not available for a model that generates by "
+            f"blocks: {why}")
+
+
+class _Result(list):
+    """A finished request's tokens from a model that generates by blocks;
+    ``steps[i]`` is the denoising step at which token ``i`` was unmasked
+    inside its block."""
+    steps = ()
+
+
 class LLMEngine(_SpecOrchestration):
     """Continuous-batching engine over a model that offers ``config`` and
     ``serving_plan()`` (``models/serving_plan.py``: its kinds of layer on
     raw arrays, their order, its weights): ``LlamaForCausalLM`` (paged KV
     alone), ``SolarOpen2ForCausalLM`` (paged KV beside a pool of recurrent
-    state, a slot a request). The speculative-decode orchestration comes
-    from :class:`~.spec._SpecOrchestration`."""
+    state, a slot a request), ``SDARForCausalLM`` (paged KV under a
+    block-causal mask; it generates by blocks: a decode dispatch is one
+    block of ``block_length`` positions a sequence, unmasked over several
+    forward passes, and a request's first token arrives with its first
+    block). The speculative-decode orchestration comes from
+    :class:`~.spec._SpecOrchestration`."""
 
     _engine_seq = 0   # observability label: one series set per engine
 
@@ -209,6 +237,23 @@ class LLMEngine(_SpecOrchestration):
                  "no state")):
             if asked:
                 refuse_recurrent(plan, what, why)
+        for what, asked, why in (
+                ("prefix_cache", prefix_cache,
+                 "admission re-prefills a cached prompt's last token for "
+                 "the sample it yields, and a block model's prefill yields "
+                 "none"),
+                ("spec_decode", spec_decode is not None,
+                 "a verify step scores drafts under a causal mask, one "
+                 "token a position"),
+                ("host_cache_bytes", host_cache_bytes is not None,
+                 "the spill tier rides on the prefix cache"),
+                (f"decode_block={decode_block!r}",
+                 decode_block not in (1, "auto"),
+                 "a decode dispatch is one block of the model's own "
+                 "length, not a number of steps")):
+            if asked:
+                refuse_blocks(plan, what, why)
+        self.block = plan.block
         self.max_batch = max_batch
         self.max_len = max_len
         self.page = page_size
@@ -259,7 +304,7 @@ class LLMEngine(_SpecOrchestration):
             prefix_cache=self.prefix_cache, copy_page=self.runner.copy_page,
             metrics=self._m, max_waiting=max_waiting,
             shed_min_free_ratio=shed_min_free_ratio,
-            restore_chain=self._restore_chain)
+            restore_chain=self._restore_chain, block=plan.block)
         self.prefill_dispatches = 0        # total prefill programs run
         self._next_rid = 0
         self._seed_counter = np.int64(seed) * 1_000_003
@@ -388,7 +433,7 @@ class LLMEngine(_SpecOrchestration):
             self._step_phase = ("prefill", (slot,))
             _faults.maybe_fire("serving.step", rids=[r.rid], phase="prefill")
             start = r.pos
-            n = min(self.chunk, len(r.prompt) - start)
+            n = min(self.chunk, self._prefill_end(r) - start)
             if self.prefix_cache:
                 # about to write [start, start+n): un-share any page another
                 # slot still maps (a fully-cached prompt re-prefilling its
@@ -399,7 +444,8 @@ class LLMEngine(_SpecOrchestration):
                 sched.cow_unshare(slot, start, n)
             toks = np.zeros((self.chunk,), np.int32)
             toks[:n] = r.prompt[start:start + n]
-            finishes = (start + n) == len(r.prompt)
+            # a block model's chunk yields no token: its first block does
+            finishes = not self.block and (start + n) == len(r.prompt)
             r.prefill_dispatches += 1
             self.prefill_dispatches += 1
             self._m.prefill.inc()
@@ -427,6 +473,14 @@ class LLMEngine(_SpecOrchestration):
                     self.prefill_sink(slot, token)
                 else:
                     sched.emit(slot, token)
+
+    def _prefill_end(self, r):
+        """Where ``r``'s prefill ends: at its prompt's end, or, for a model
+        that generates by blocks, at the last whole block of it (the
+        ``len % block`` tokens left over are the known head of the first
+        block it generates)."""
+        n = len(r.prompt)
+        return n - n % self.block if self.block else n
 
     def step(self):
         """One engine dispatch: a prefill chunk if any slot is mid-prompt,
@@ -494,7 +548,7 @@ class LLMEngine(_SpecOrchestration):
             if point is not None and point.delay:
                 time.sleep(point.delay)
         for slot, r in enumerate(sched.slots):
-            if r is not None and r.pos < len(r.prompt):
+            if r is not None and r.pos < self._prefill_end(r):
                 self._prefill_chunk(slot)
                 return 1
         live = [(s, r) for s, r in enumerate(sched.slots) if r is not None]
@@ -530,7 +584,8 @@ class LLMEngine(_SpecOrchestration):
             rows = [(s, r) for s, r in rows if sched.slots[s] is r]
             if not rows:
                 return served
-            args = self._decode_args(rows)
+            emits = self._block_emits(rows)
+            args = self._decode_args(rows, emits)
             # the dispatch gets the lengths and tables as they are NOW:
             # launch() and the next plan move the scheduler's own
             lens, tables = sched.lens.copy(), sched.slot_tables.copy()
@@ -541,6 +596,8 @@ class LLMEngine(_SpecOrchestration):
             self._m.decode.inc()
             self._m.decode_launches[self._flight is not None].inc()
             self._m.count_argmax("decode", (r for _, r in rows))
+            if self.block:
+                self._m.blocks.inc(len(rows))
         # timed: the auto-fit below needs the wall time whatever is switched on
         with _obs.trace_span("decode", rid=[r.rid for _, r in rows],
                              trace_id=[r.trace_id for _, r in rows],
@@ -549,11 +606,12 @@ class LLMEngine(_SpecOrchestration):
             toks = self.runner.run_decode(k, args[0], lens, tables, *args[1:])
             if self._auto_block:
                 # the fit wants the dispatch's own wall time: wait in here
-                toks = np.asarray(toks)
+                # (what came back keeps what it read)
+                np.asarray(toks)
         for slot, _ in rows:
-            sched.launch(slot, k)
+            sched.launch(slot, k, emits[slot][1] if emits else None)
         before, self._flight = self._flight, _Flight(toks, k, rows, t0,
-                                                     steady)
+                                                     steady, emits)
         if before is not None:
             self._land(before)
         if self._auto_block and steady:
@@ -568,11 +626,14 @@ class LLMEngine(_SpecOrchestration):
         left (its last token is in flight) sits out; the block is the
         largest power of two <= every row's room, capped by decode_block
         (or the RTT-adapted target in auto mode); any eos request needs
-        per-token host inspection -> 1."""
+        per-token host inspection -> 1. A model that generates by blocks
+        has its own: one block a dispatch, whatever the room."""
         sched = self.sched
         rows = [(s, r) for s, r in live if sched.room(s) > 0]
         if not rows:
             return rows, 0
+        if self.block:
+            return rows, self.block
         cap = self._block_target if self._auto_block else self.decode_block
         k = min(cap, min(sched.room(s) for s, _ in rows))
         if any(r.eos is not None for _, r in rows):
@@ -626,6 +687,18 @@ class LLMEngine(_SpecOrchestration):
                 for _ in flight.rows:
                     self._m.token_latency.observe(per_token)
             self._t_landed = now
+            if flight.emits is not None:
+                self._m.count_block_forwards(self.runner.take_block_counts())
+                # a stand-in for the runner's tokens (a test's) has no steps
+                steps = getattr(flight.toks, "steps", None)
+                for slot, r in flight.rows:
+                    if sched.slots[slot] is not r:
+                        continue    # cancelled or timed out since the launch
+                    lo, n = flight.emits[slot]
+                    sched.land_block(
+                        slot, toks[lo:lo + n, slot],
+                        [-1] * n if steps is None else steps[lo:lo + n, slot])
+                return len(flight.rows)
             for j in range(flight.k):
                 for slot, r in flight.rows:
                     if sched.slots[slot] is not r:
@@ -635,14 +708,26 @@ class LLMEngine(_SpecOrchestration):
                     sched.land(slot, int(toks[j, slot]))
         return len(flight.rows)
 
-    def _decode_args(self, rows):
+    def _decode_args(self, rows, emits=None):
         """Host arrays of one decode dispatch over ``rows``, in
         ``run_decode``'s order without ``lens`` and ``tables``: tokens,
         active, the per-slot sampling parameters, then ``take`` — a row
         with a token in flight decodes from the one the device kept, the
-        others from the last one emitted."""
+        others from the last one emitted. A block model's ``tokens`` are
+        the blocks ``[B, Q]`` as ``emits`` (:meth:`_block_emits`) cuts
+        them."""
         B = self.max_batch
         tokens = np.zeros((B,), np.int32)
+        if self.block:
+            # the block as the host knows it: the prompt's tokens past its
+            # last whole block (a request's first block only), -1 where a
+            # token is to come, -2 past the request's budget
+            tokens = np.zeros((B, self.block), np.int32)
+            for slot, (lo, n) in emits.items():
+                tokens[slot, :lo] = self.sched.slots[slot].prompt[
+                    int(self.sched.lens[slot]):]
+                tokens[slot, lo:lo + n] = -1
+                tokens[slot, lo + n:] = -2
         active = np.zeros((B,), np.int32)
         greedy = np.ones((B,), np.int32)
         temp = np.ones((B,), np.float32)
@@ -653,7 +738,9 @@ class LLMEngine(_SpecOrchestration):
         take = np.zeros((B,), np.int32)
         for slot, r in rows:
             active[slot] = 1
-            if self.sched.in_flight[slot]:
+            if self.block:
+                pass            # a block starts from [MASK]: nothing carried
+            elif self.sched.in_flight[slot]:
                 take[slot] = 1
             else:
                 tokens[slot] = r.out[-1]
@@ -664,6 +751,20 @@ class LLMEngine(_SpecOrchestration):
             seeds[slot] = self._next_seed(r)
             fold[slot] = 1 if r.seed is None else 0
         return tokens, active, greedy, temp, topp, topk, seeds, fold, take
+
+    def _block_emits(self, rows):
+        """By slot, which positions of its next block a row yields: ``(the
+        first, how many)`` — past the prompt's tokens the block holds
+        known, up to what is left of the request's budget. ``None`` for a
+        model that generates a token a step."""
+        if not self.block:
+            return None
+        sched = self.sched
+        out = {}
+        for slot, r in rows:
+            lo = max(0, len(r.prompt) - int(sched.lens[slot]))
+            out[slot] = (lo, min(self.block - lo, sched.room(slot)))
+        return out
 
     # ----------------------------------------------------- failure isolation
     def _survive_step_failure(self, e):
@@ -758,26 +859,31 @@ class LLMEngine(_SpecOrchestration):
         self.sched.release(slot, RequestStatus.FAILED, error=err)
 
     def _decode_probe(self, slot):
-        """One-slot k=1 decode dispatch — the isolation probe run for each
-        member of a failed batch.  A raise here pins the failure on this
+        """One-slot decode dispatch (one token, or one block) — the
+        isolation probe run for each member of a failed batch.  A raise here pins the failure on this
         slot; success emits the token the probe decoded anyway, so a
         surviving request loses no work to the sweep."""
         sched = self.sched
         r = sched.slots[slot]
         self._step_phase = ("decode", (slot,))
         _faults.maybe_fire("serving.step", rids=[r.rid], phase="decode")
-        sched.ensure_page(slot, ahead=1)
+        k = self.block or 1
+        sched.ensure_page(slot, ahead=k)
         if sched.slots[slot] is not r:
             return                # growth preempted the probe target
-        args = self._decode_args([(slot, r)])
+        rows = [(slot, r)]
+        emits = self._block_emits(rows)
+        args = self._decode_args(rows, emits)
         self._m.decode.inc()
         self._m.count_argmax("decode", (r,))
+        if self.block:
+            self._m.blocks.inc()
         with _obs.trace_span("decode", rid=r.rid, trace_id=r.trace_id,
-                             block=1, probe=1):
-            toks = np.asarray(self.runner.run_decode(
-                1, args[0], sched.lens, sched.slot_tables, *args[1:]))
-        sched.lens[slot] += 1
-        sched.emit(slot, int(toks[0, slot]))
+                             block=k, probe=1):
+            toks = self.runner.run_decode(
+                k, args[0], sched.lens.copy(), sched.slot_tables, *args[1:])
+        sched.launch(slot, k, emits[slot][1] if emits else None)
+        self._land(_Flight(toks, k, rows, 0.0, False, emits))
 
     def audit_refcounts(self):
         """Cross-check every page-accounting structure against the others;
@@ -1003,6 +1109,7 @@ class LLMEngine(_SpecOrchestration):
         [L, n, page, ...] numpy arrays}``, or None when even the first key
         misses everywhere — the puller then recomputes."""
         refuse_recurrent(self.runner.plan, "export_pages", _NO_STATE_HANDOFF)
+        refuse_blocks(self.runner.plan, "export_pages", _NO_BLOCK_HANDOFF)
         self._drain()
         host = self.pool.host
         served, dev, host_blocks = [], [], {}
@@ -1044,6 +1151,7 @@ class LLMEngine(_SpecOrchestration):
         ordinary prefix hit.  Any failure stops the splice mid-chain — the
         un-spliced tail simply recomputes.  Returns pages spliced."""
         refuse_recurrent(self.runner.plan, "import_pages", _NO_STATE_HANDOFF)
+        refuse_blocks(self.runner.plan, "import_pages", _NO_BLOCK_HANDOFF)
         if not payload:
             return 0
         self._drain()
@@ -1115,7 +1223,15 @@ class LLMEngine(_SpecOrchestration):
         return keys
 
     def result(self, rid):
-        return self.sched.finished[rid].out
+        """A finished request's tokens. From a model that generates by
+        blocks the list also says, under ``.steps``, at which denoising
+        step each token was unmasked inside its block."""
+        r = self.sched.finished[rid]
+        if not self.block:
+            return r.out
+        out = _Result(r.out)
+        out.steps = tuple(r.steps)
+        return out
 
     def ttft(self, rid):
         """Seconds from add_request to the first generated token."""

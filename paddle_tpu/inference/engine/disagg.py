@@ -69,7 +69,8 @@ from ... import observability as _obs
 from ...observability import flight as _flight
 from ...core.retry import RetryError, RetryPolicy, retry_call
 from ...testing.faults import FAULTS as _faults
-from .core import _NO_STATE_HANDOFF, LLMEngine, refuse_recurrent
+from .core import (_NO_BLOCK_HANDOFF, _NO_STATE_HANDOFF, LLMEngine,
+                   refuse_blocks, refuse_recurrent)
 from .metrics import _PoolMetrics
 from .request import Request, RequestStatus
 
@@ -234,6 +235,7 @@ class DisaggEngine:
             plans.append(model.serving_plan())
         for plan in plans:
             refuse_recurrent(plan, "DisaggEngine", _NO_STATE_HANDOFF)
+            refuse_blocks(plan, "DisaggEngine", _NO_BLOCK_HANDOFF)
         self.max_batch = max_batch
         self.max_len = max_len
         self.page = page_size

@@ -83,6 +83,12 @@ class _EngineMetrics:
         self.state_slots = _obs.SERVING_STATE_SLOTS.labels(**e)
         self.routing = [[fam.labels(kind=k, **e) for fam in _obs.SERVING_MOE]
                         for k in ("decode", "prefill")]
+        # a model that generates by blocks
+        self.blocks = _obs.SERVING_BLOCKS.labels(**e)
+        self.block_forwards = [
+            _obs.SERVING_BLOCK_FORWARDS.labels(kind="denoise", **e),
+            _obs.SERVING_BLOCK_FORWARDS.labels(kind="commit", **e),
+            _obs.SERVING_BLOCK_SEQUENCE_FORWARDS.labels(**e)]
 
     def count_argmax(self, kind, requests):
         """Count one ``kind`` dispatch over ``requests`` if none of them
@@ -99,6 +105,15 @@ class _EngineMetrics:
             for counter, n in zip(row, counts):
                 if n:
                     counter.inc(int(n))
+
+
+    def count_block_forwards(self, grown):
+        """Add what the block program's counts grew by (the runner's
+        ``take_block_counts()``: denoising forwards, committing forwards,
+        live sequences summed over both) to the registry's counters."""
+        for counter, n in zip(self.block_forwards, grown):
+            if n:
+                counter.inc(int(n))
 
 
 class _PoolMetrics:

@@ -89,6 +89,9 @@ class Request:
         self.top_k = int(top_k)
         self.seed = seed
         self.out: list[int] = []
+        # a model that generates by blocks: the denoising step each token
+        # of ``out`` was unmasked at
+        self.steps: list[int] = []
         self.pos = 0                 # prompt tokens already prefilled
         self.slot = None
         self.done = False

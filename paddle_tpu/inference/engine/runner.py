@@ -23,6 +23,12 @@ TPU-native design (carried over from the monolithic serving engine):
   program keeps each row's last token on the device for the dispatch after
   it, which takes it from there for the rows the engine names. So the
   engine launches step N+1 before it reads step N (``LLMEngine.step``).
+- A model that GENERATES BY BLOCKS (``plan.block`` = Q > 0) gets another
+  decode program under the same name and the same call (``_build_block``):
+  a dispatch is ONE BLOCK of Q positions a live sequence - up to
+  ``plan.denoising_steps`` forwards of B x Q rows that each unmask some of
+  the block's positions, and one that commits the finished block's keys
+  and values - and its prefill chunk sees whole blocks and samples nothing.
 - Sampling happens IN-GRAPH with per-slot parameters (greedy / temperature /
   top-k / top-p / seed), replicating models.llama._sample token-for-token.
   What a dispatch's rows ask for decides the work (``_sample_rows``): when
@@ -70,6 +76,9 @@ __all__ = ["ModelRunner"]
 _MAXK = 64        # static cap for per-slot dynamic top-k filtering
 # the rows of the routing-count sums: which program a layer's call was in
 _COUNT_ROWS = ("decode", "prefill")
+# what a block program counts on the device beside them: its forwards by
+# kind, and the live sequences summed over all of them
+BLOCK_COUNTS = ("denoise", "commit", "sequence_forwards")
 
 
 def _kernel_applies(device, mesh):
@@ -141,6 +150,18 @@ def _sample_rows(logits, greedy, temp, topp, topk, seeds):
     return jax.lax.cond(jnp.all(greedy > 0), lambda: amax, sampled)
 
 
+def _sample_rows_conf(logits, greedy, temp, topp, topk, seeds):
+    """``_sample_rows`` and, beside each row's token, its probability under
+    the row's own distribution (softmax of the logits over the row's
+    temperature; a greedy row's over 1): the confidence that orders the
+    unmasking of a block."""
+    tok = _sample_rows(logits, greedy, temp, topp, topk, seeds)
+    scaled = logits / jnp.where((greedy > 0) | (temp <= 0), 1.0,
+                                temp)[:, None]
+    at = jnp.take_along_axis(scaled, tok[:, None], axis=1)[:, 0]
+    return tok, jnp.exp(at - jax.nn.logsumexp(scaled, axis=-1))
+
+
 def _argmax_only(greedy):
     """The host's reading of ``_sample_rows``' predicate, for the
     ``runner.dispatch`` span."""
@@ -152,13 +173,16 @@ class _DecodeTokens:
     ``np.asarray`` of it is host tokens [k, B], the routing counts a model
     sends home behind them taken off. The first asking waits for the
     program (``runner.wait``); dispatches are read in the order they were
-    launched."""
+    launched. A block dispatch's tokens are the block's positions ``[Q,
+    B]``, and ``steps`` (after the first asking) says at which denoising
+    step each was unmasked (-1: it came known)."""
 
-    __slots__ = ("_runner", "_dev", "_host", "_rows")
+    __slots__ = ("_runner", "_dev", "_host", "_rows", "steps")
 
     def __init__(self, runner, dev, rows):
         self._runner, self._dev, self._host, self._rows = (
             runner, dev, None, rows)
+        self.steps = None
 
     @property
     def unread(self):
@@ -168,8 +192,11 @@ class _DecodeTokens:
         if self._host is None:
             with _obs.trace_span("runner.wait"):
                 host = np.asarray(self._dev)
-            if self._runner.plan.counts:
+            if self._runner._counts_seen.size:
                 host = self._runner._strip_counts(host, self._rows)
+            Q = self._runner.plan.block
+            if Q:
+                host, self.steps = host[:Q], host[Q:]
             self._host, self._dev = host, None
         return self._host if dtype is None else self._host.astype(dtype)
 
@@ -234,13 +261,26 @@ class ModelRunner:
                               device=cache_spec),
                     jnp.zeros(rows + (plan.conv_tail, plan.conv_channels),
                               dtype, device=cache_spec))
+        if plan.block:
+            if self.page % plan.block or self.chunk % plan.block:
+                raise ValueError(
+                    f"a model that generates by blocks of {plan.block} needs "
+                    f"page_size ({self.page}) and prefill_chunk "
+                    f"({self.chunk}) to be whole blocks")
+            # the block program's own counts (BLOCK_COUNTS), as the routing
+            # counts below: summed on the device, read as differences
+            self._block_counts_at = len(self.cache)
+            self.cache += (jnp.zeros((len(BLOCK_COUNTS),), jnp.int32),)
         if plan.counts:
             # the layers' routing counts summed on the device, a row a kind
             # of dispatch (_COUNT_ROWS); int32 that wraps, read as differences
             self.cache += (jnp.zeros((len(_COUNT_ROWS), plan.counts),
                                      jnp.int32),)
-        self._counts_seen = np.zeros((len(_COUNT_ROWS), plan.counts),
-                                     np.int64)
+        # what rides home behind a decode dispatch's tokens, flat: the
+        # block counts, then the routing counts
+        self._counts_seen = np.zeros(
+            (len(BLOCK_COUNTS) if plan.block else 0)
+            + len(_COUNT_ROWS) * plan.counts, np.int64)
         self._counts_grown = np.zeros_like(self._counts_seen)
         # the last decode dispatch's tokens: the rows' last ones as the next
         # dispatch takes them back on the device (``_build_decode``), and
@@ -312,7 +352,11 @@ class ModelRunner:
         sixteenth of their size, are written the same way and read by the
         layer. A pool that is scanned over instead is sliced out per
         layer, written back per layer and, being donated while still read,
-        copied whole once a dispatch."""
+        copied whole once a dispatch.
+
+        ``r.horizon == "block"`` (a block program's rows): the Q rows of a
+        sequence are one block and each sees ``ctx + Q - 1`` tokens, the
+        block's own, just written, included."""
         nh, D = self.plan.nh, self.plan.D
         use_kernel = self.use_kernel
         quant = self.kv_quant
@@ -322,9 +366,9 @@ class ModelRunner:
 
         def layer(carry, wl):
             from ...ops.pallas.paged_attention import (
-                paged_attention, paged_attention_multiquery,
-                paged_attention_multiquery_ref, paged_attention_ref,
-                quantize_kv)
+                paged_attention, paged_attention_blocks,
+                paged_attention_multiquery, paged_attention_multiquery_ref,
+                paged_attention_ref, quantize_kv)
             x, cache = carry
             l = wl["l"]
             q, k, v = lk.first(wl, x, pos)
@@ -332,12 +376,20 @@ class ModelRunner:
                 attn = paged_attention if use_kernel else paged_attention_ref
             else:
                 Bq, Q = mq
-                base = (paged_attention_multiquery if use_kernel
-                        else paged_attention_multiquery_ref)
+                # a block's rows see the whole block (its kernel stands in
+                # the trace as ``paged_attention``); verification's rows
+                # their own horizon, the default
+                base, hz = (paged_attention_multiquery if use_kernel
+                            else paged_attention_multiquery_ref), {}
+                if getattr(r, "horizon", None) == "block":
+                    if use_kernel:
+                        base = paged_attention_blocks
+                    else:
+                        hz = {"horizon": "block"}
 
                 def attn(qx, kp, vp, tb, cl, **kw):
                     out = base(qx.reshape(Bq, Q, nh, D), kp, vp, tb, cl,
-                               **kw)
+                               **kw, **hz)
                     return out.reshape(Bq * Q, nh, D)
             rows = (k, v)
             if quant:                       # int8 pages + their scale pools
@@ -460,6 +512,12 @@ class ModelRunner:
         launch step N+1 while step N's tokens are still on their way home
         (``LLMEngine.step``). One program a K whatever the dispatch before
         was: ``prev`` has one shape."""
+        if self.plan.block:
+            if K != self.plan.block:
+                raise ValueError(
+                    f"this model generates by blocks of {self.plan.block}: "
+                    f"a decode dispatch is one block, not {K} steps")
+            return self._build_block()
         page = self.page
         eps = self.cfg.rms_norm_eps
         trash = self.trash_page
@@ -519,11 +577,120 @@ class ModelRunner:
 
         return jax.jit(block, donate_argnums=(1,))
 
+    def _build_block(self):
+        """The decode program of a model that generates by blocks
+        (``plan.block`` = Q): ONE BLOCK of Q positions a live sequence a
+        dispatch, under the name and the call of the K-step program.
+
+        ``tokens [B, Q]`` is the block as the host knows it: a token where
+        one is known (the prompt's last ``len % Q``, in a request's first
+        block), -1 where one is to be generated, -2 where the position is
+        past the request's budget (its last block): that one stays
+        ``[MASK]`` through every step and yields nothing. What is masked is
+        kept as a mask of its own from there on, never read off the ids.
+        ``lens [B]`` is where the block starts: the tokens committed before
+        it, whole blocks.
+
+        Up to ``plan.denoising_steps`` forwards of the ``B x Q`` rows
+        (``mq = (B, Q)``, the block's horizon: every row attends to the
+        committed prefix and to the WHOLE block), the masked positions fed
+        ``plan.mask_token``; each writes the block's K and V into its pages
+        before it attends, runs the head on all rows, samples a token and
+        its confidence at every position (the logits AT a position are that
+        position's), and ``plan.unmask`` says which masked positions keep
+        theirs. A ``lax.scan`` where the rule takes a fixed number of
+        positions a step; a ``lax.while_loop`` on "any live row still
+        masked" where it may finish early (``plan.early_exit``). Then one
+        forward of the finished block, no head: the K and V it writes are
+        what later blocks read.
+
+        Returns ``(out [2Q, B + counts], prev)``: the block's tokens ``[Q,
+        B]`` over the step each was unmasked at (-1: it came known), and
+        behind them the block counts (``BLOCK_COUNTS``) and the layers'
+        routing sums. A new block starts from ``[MASK]`` rows and needs no
+        token of the block before it: ``take`` and ``prev`` carry nothing
+        here and ``prev`` goes back as it came."""
+        plan = self.plan
+        Q, D = plan.block, plan.denoising_steps
+        page = self.page
+        eps = self.cfg.rms_norm_eps
+        trash = self.trash_page
+        B = self.max_batch
+        at = self._block_counts_at
+
+        def block(W, cache, tokens, lens, tables, active,
+                  greedy, temp, topp, topk, seeds, fold, take, prev):
+            live = active > 0
+            row_j = jnp.tile(jnp.arange(Q, dtype=jnp.int32), B)  # [B*Q]
+
+            def rep(a):
+                return jnp.repeat(a, Q)
+
+            pos = rep(lens.astype(jnp.int32)) + row_j
+            page_idx = jnp.take_along_axis(
+                tables, (pos // page).reshape(B, Q), axis=1).reshape(-1)
+            # inactive slots write into the trash page, never a live one
+            page_idx = jnp.where(rep(live), page_idx, trash)
+            rows = types.SimpleNamespace(
+                page_idx=page_idx, within=pos % page, tables=tables,
+                ctx=jnp.where(live, lens + 1, 1).astype(jnp.int32), pos=pos,
+                mq=(B, Q), live=rep(active), horizon="block")
+            sample = [rep(a) for a in (greedy, temp, topp, topk)]
+
+            def forward(cache, toks):
+                return self._run_layers(
+                    W, cache, W["embed"][toks.reshape(-1)],
+                    self._layer_fns(W, rows, "decode"))
+
+            def denoise(carry, s):
+                toks, masked, steps, cache = carry
+                x, cache = forward(
+                    cache, jnp.where(masked | cut, plan.mask_token, toks))
+                h = rms_norm(x, W["norm"], eps)
+                logits = h.astype(jnp.float32) @ W["head"].astype(
+                    jnp.float32)
+                x0, conf = _sample_rows_conf(
+                    logits, *sample, rep(seeds) + (s * Q + row_j) * rep(fold))
+                keep = masked & plan.unmask(conf.reshape(B, Q), masked, s)
+                return (jnp.where(keep, x0.reshape(B, Q), toks),
+                        masked & ~keep, jnp.where(keep, s, steps), cache)
+
+            cut = tokens == -2
+            start = (jnp.maximum(tokens, 0), (tokens == -1) & live[:, None],
+                     jnp.full((B, Q), -1, jnp.int32), cache)
+            if plan.early_exit:
+                n, (toks, _, steps, cache) = jax.lax.while_loop(
+                    lambda c: (c[0] < D) & jnp.any(c[1][1]),
+                    lambda c: (c[0] + 1, denoise(c[1], c[0])),
+                    (jnp.int32(0), start))
+            else:
+                (toks, _, steps, cache), _ = jax.lax.scan(
+                    lambda c, s: (denoise(c, s), None), start,
+                    jnp.arange(D, dtype=jnp.int32))
+                n = jnp.int32(D)
+            _, cache = forward(                         # commit: no head
+                cache, jnp.where(cut, plan.mask_token, toks))
+            grown = jnp.stack([n, jnp.int32(1),
+                               (n + 1) * jnp.sum(live, dtype=jnp.int32)])
+            cache = cache[:at] + (cache[at] + grown,) + cache[at + 1:]
+            out = jnp.concatenate([toks.T, steps.T])            # [2Q, B]
+            tail = jnp.concatenate([c.reshape(-1) for c in cache[at:]])
+            out = jnp.concatenate(
+                [out, jnp.broadcast_to(tail[None], (2 * Q, tail.size))],
+                axis=1)
+            if self.mesh is not None:
+                prev = jax.lax.with_sharding_constraint(
+                    prev, NamedSharding(self.mesh, P()))
+            return (out, prev), cache
+
+        return jax.jit(block, donate_argnums=(1,))
+
     def _build_prefill(self):
         page = self.page
         eps = self.cfg.rms_norm_eps
         trash = self.trash_page
         C = self.chunk
+        Q = self.plan.block
 
         def prefill(W, cache, tokens, start, table, n_valid,
                     greedy, temp, topp, topk, seed, slot=None):
@@ -531,7 +698,10 @@ class ModelRunner:
             # start scalar; table [S]; n_valid scalar <= C. Chunk rows ride
             # the paged-attention BATCH dim: row i gets ctx = start+i+1, so
             # in-chunk causality and attention to the already-cached prefix
-            # both fall out of the per-row context length.
+            # both fall out of the per-row context length. A model that
+            # generates by blocks of Q: a row sees to the end of its own
+            # block (the chunk's K and V are all written before any row
+            # attends), never past the chunk, which holds whole blocks.
             x = W["embed"][tokens]                       # [C, H]
             offs = jnp.arange(C, dtype=jnp.int32)
             pos = start.astype(jnp.int32) + offs
@@ -539,7 +709,12 @@ class ModelRunner:
             page_idx = table[pos // page]
             page_idx = jnp.where(valid, page_idx, trash)
             within = pos % page
-            ctx = jnp.where(valid, pos + 1, 1).astype(jnp.int32)
+            if Q:       # causal from block to block, open inside a block
+                seen = jnp.minimum((pos // Q + 1) * Q,
+                                   start.astype(jnp.int32) + n_valid)
+            else:
+                seen = pos + 1
+            ctx = jnp.where(valid, seen, 1).astype(jnp.int32)
             tables = jnp.broadcast_to(table[None, :], (C, table.shape[0]))
             rows = types.SimpleNamespace(
                 page_idx=page_idx, within=within, tables=tables, ctx=ctx,
@@ -547,6 +722,8 @@ class ModelRunner:
                 n_valid=n_valid)
             x, cache2 = self._run_layers(
                 W, cache, x, self._layer_fns(W, rows, "prefill"))
+            if Q:       # a block model's chunk emits no token: no head
+                return jnp.int32(0), cache2
             h = rms_norm(x, W["norm"], eps)
             last = h[jnp.maximum(n_valid - 1, 0)]
             logits = last.astype(jnp.float32) @ W["head"].astype(jnp.float32)
@@ -687,6 +864,9 @@ class ModelRunner:
                      "ahead": int(self.decode_unread)}
             if self.plan.recurrent:
                 attrs["state_rows"] = int(ctx.size)
+            if self.plan.block:     # the most forwards the dispatch makes
+                attrs.update(block=self.plan.block,
+                             forwards=self.plan.denoising_steps + 1)
         toks, self._last = self._launch(
             ("decode", k), prog, attrs, tokens, lens, tables, active, greedy,
             temp, topp, topk, seeds, fold, take, self._last)
@@ -704,7 +884,7 @@ class ModelRunner:
         (the last step's are the block's) and keep what they grew by,
         modulo the device's 32 bits - the chunks' since the block before
         included - for :meth:`take_routing_counts`."""
-        seen = toks[-1, B:].astype(np.int64).reshape(self._counts_seen.shape)
+        seen = toks[-1, B:].astype(np.int64)
         self._counts_grown += (seen - self._counts_seen) % (1 << 32)
         self._counts_seen = seen
         return toks[:, :B]
@@ -713,8 +893,19 @@ class ModelRunner:
         """What the layers' routing counts grew by since the last call:
         ``[kind of dispatch (decode, prefill), count]`` int64, empty for a
         model that has none."""
-        grown, self._counts_grown = (self._counts_grown,
-                                     np.zeros_like(self._counts_grown))
+        n = len(_COUNT_ROWS) * self.plan.counts
+        at = self._counts_grown.size - n
+        grown = self._counts_grown[at:].reshape(len(_COUNT_ROWS), -1).copy()
+        self._counts_grown[at:] = 0
+        return grown
+
+    def take_block_counts(self):
+        """What the block program's own counts (``BLOCK_COUNTS``) grew by
+        since the last call; empty for a model that generates a token a
+        step."""
+        n = len(BLOCK_COUNTS) if self.plan.block else 0
+        grown = self._counts_grown[:n].copy()
+        self._counts_grown[:n] = 0
         return grown
 
     def run_verify(self, kv, tokens, lens, tables, n_rows,

@@ -12,7 +12,10 @@ to :meth:`~.runner.ModelRunner.copy_page` by the engine).  The
 failure and pool-pressure paths.  The decode loop runs one step ahead of
 what has been emitted: ``launch()`` counts a dispatch's tokens into a slot's
 length when they are launched, ``room()`` says who may still launch one,
-``land()`` emits one that has come home (``LLMEngine.step``).
+``land()`` emits one that has come home (``LLMEngine.step``).  For a model
+that generates by blocks a dispatch advances a slot's length by a whole
+block of positions and lands fewer tokens where some of the block came known
+or the budget cuts it (``launch(slot, k, tokens)``, ``land_block()``).
 
 ``detach()`` / ``admit_prefilled()`` are the disaggregation seam: detach
 lifts a freshly-prefilled request out of its slot WITHOUT dropping its page
@@ -41,8 +44,9 @@ class Scheduler:
     def __init__(self, pool, max_batch, max_len, page_size, pages_per_slot,
                  prefix_cache=False, copy_page=None, metrics=None,
                  max_waiting=None, shed_min_free_ratio=0.0,
-                 restore_chain=None):
+                 restore_chain=None, block=0):
         self.pool = pool
+        self.block = int(block)     # > 0: the model generates by blocks
         self.max_batch = int(max_batch)
         self.max_len = int(max_len)
         self.page = int(page_size)
@@ -64,6 +68,9 @@ class Scheduler:
         # tokens of a slot that a decode dispatch has launched and the host
         # has not read yet; ``lens`` counts them already (``launch``)
         self.in_flight = np.zeros((self.max_batch,), np.int32)
+        # the POSITIONS those dispatches advanced ``lens`` by: as many as
+        # the tokens, except where a block holds known or cut positions
+        self.ahead = np.zeros((self.max_batch,), np.int32)
         self.waiting: deque = deque()
         self.finished: dict = {}
         self._admit_seq = 0
@@ -328,6 +335,7 @@ class Scheduler:
         self.lens[slot] = 0
         self.n_alloc[slot] = 0
         self.in_flight[slot] = 0
+        self.ahead[slot] = 0
         if status is not None:
             self.finalize(r, status, error=error)
 
@@ -400,13 +408,15 @@ class Scheduler:
         self.slot_tables[slot, needed:] = self.slot_tables[slot, needed - 1]
         self.n_alloc[slot] = needed
 
-    def launch(self, slot, k):
-        """A decode dispatch of ``k`` tokens for this slot has been
+    def launch(self, slot, k, tokens=None):
+        """A decode dispatch of ``k`` positions for this slot has been
         launched: its length counts them from now on — the next dispatch
         is planned, and its pages grown, on the length the slot will have
-        — and they stay ``in_flight`` until :meth:`land` hands them over."""
+        — and its ``tokens`` (``k`` of them unless a block says fewer)
+        stay ``in_flight`` until :meth:`land` hands them over."""
         self.lens[slot] += k
-        self.in_flight[slot] += k
+        self.ahead[slot] += k
+        self.in_flight[slot] += k if tokens is None else tokens
 
     def room(self, slot):
         """Tokens this slot's request may still have LAUNCHED: what is left
@@ -419,14 +429,33 @@ class Scheduler:
     def land(self, slot, token):
         """One launched token has come home: emit it."""
         self.in_flight[slot] -= 1
+        self.ahead[slot] -= 1
         self.emit(slot, token)
+
+    def land_block(self, slot, tokens, steps):
+        """One launched block has come home: its positions are committed,
+        and the tokens it yields are emitted in order, each with the
+        denoising step it was unmasked at. An ``eos`` among them ends the
+        request at this block; what follows it is dropped."""
+        r = self.slots[slot]
+        for token, step in zip(tokens, steps):
+            self.in_flight[slot] -= 1
+            r.steps.append(int(step))
+            self.emit(slot, int(token))
+            if self.slots[slot] is not r:
+                return
+        # only now: while its tokens are emitted the block still counts as
+        # ahead, so ``emit`` does not read a request that ends exactly at
+        # ``max_len`` as past it at the block's first token
+        self.ahead[slot] -= self.block
 
     def recall(self):
         """Forget every token in flight (their dispatch failed, or its
         tokens were lost): lengths go back to what was emitted.  What the
         dispatch wrote past them is never attended."""
-        self.lens -= self.in_flight
+        self.lens -= self.ahead
         self.in_flight[:] = 0
+        self.ahead[:] = 0
 
     def emit(self, slot, token):
         """Record one generated token; release the slot when finished."""
@@ -443,7 +472,7 @@ class Scheduler:
                                trace_id=r.trace_id, ttft=r.ttft)
         hit_eos = (r.eos is not None and r.out[-1] == r.eos)
         if (len(r.out) >= r.max_new or hit_eos
-                or int(self.lens[slot] - self.in_flight[slot])
+                or int(self.lens[slot] - self.ahead[slot])
                 >= self.max_len):
             self.release(slot, RequestStatus.EOS if hit_eos
                          else RequestStatus.FINISHED)

@@ -98,6 +98,10 @@ class EngineReplica:
         self.alive = True
         self.error = None
         self._cv = threading.Condition(threading.RLock())
+        # threads waiting in _engine_lock: the step loop lets them in
+        # between two steps (a lock has no fairness of its own)
+        self._lock_waiters = 0
+        self._waiters_lock = threading.Lock()
         # rid -> {"toks": [undelivered], "status": last published} — written
         # by _publish (engine condition held), read/drained by poll under
         # the light condition only.  Lock order: engine cv, then outbox cv.
@@ -176,10 +180,19 @@ class EngineReplica:
                 with _obs.trace_span("replica.publish"):
                     self._publish()
                     self._cv.notify_all()
-                # between two steps the condition is dropped and taken again
-                # at once: whoever waits in _engine_lock gets in here, or not
+                # between two steps the condition is dropped, and whoever
+                # waits in _engine_lock gets in: dropped and taken again at
+                # once it came back to this thread nearly every time, and a
+                # submitter waited through seconds of steps while slots
+                # stood empty (PERF.md section 7 item 2). A waiter that does
+                # not take it within 50 ms is not waited for.
                 with _obs.trace_span("replica.lock"):
                     self._cv.release()
+                    give_up = time.monotonic() + 0.05
+                    while (self._lock_waiters  # graftlint: disable=concurrency
+                           and time.monotonic() < give_up):
+                        # the condition is NOT held here: released above
+                        time.sleep(0)  # graftlint: disable=concurrency
                     self._cv.acquire()
 
     def _watch_steps(self):
@@ -270,10 +283,16 @@ class EngineReplica:
     def _engine_lock(self, op):
         """Hold the engine condition for ``op`` on a thread other than the
         step loop, counting how long it took to get
-        (``frontend_engine_lock_wait_seconds``): the loop takes the condition
-        back as soon as it drops it, so this wait can last many steps."""
-        with _obs.trace_span("replica.lock_wait", op=op) as sp:
-            self._cv.acquire()
+        (``frontend_engine_lock_wait_seconds``): the loop holds it through a
+        step and lets the threads counted here in between two."""
+        with self._waiters_lock:
+            self._lock_waiters += 1
+        try:
+            with _obs.trace_span("replica.lock_wait", op=op) as sp:
+                self._cv.acquire()
+        finally:
+            with self._waiters_lock:
+                self._lock_waiters -= 1
         try:
             if sp.dur is not None:
                 _obs.FRONTEND_LOCK_WAIT.observe(sp.dur, replica=self.name,

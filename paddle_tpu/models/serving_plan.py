@@ -41,13 +41,37 @@ repeated ``plan.periods`` times. Every leaf of a kind's layers is stacked
 kind). ``plan.weights()`` yields ``(key, array)`` leaf by leaf, so a model
 that cannot afford a second copy lets go of each as the runner places it;
 ``plan.specs(pp, mp)`` are their ``PartitionSpec``s.
+
+A model that GENERATES BY BLOCKS says so with ``plan.block`` = Q > 0 (0: one
+token a sequence a forward, chosen at the last position under a causal
+mask). Its mask is causal from block to block and open both ways inside a
+block of Q aligned positions; a decode dispatch is one block a sequence:
+up to ``plan.denoising_steps`` forwards over the block's Q rows, which start
+as ``plan.mask_token`` wherever no token is known, each followed by
+``plan.unmask(conf [B, Q], masked [B, Q], step) -> [B, Q] bool`` - the
+positions (among the masked) whose sampled token is kept after that
+forward, by the confidence the sampler gave each - and one more forward of
+the finished block that commits its keys and values. ``plan.early_exit``:
+the rule may finish a block in fewer steps, so the loop asks the device
+whether any live row is still masked.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Callable, Optional
 
-__all__ = ["LayerKind", "ServingPlan"]
+import jax
+import jax.numpy as jnp
+
+__all__ = ["LayerKind", "ServingPlan", "stack_leaves"]
+
+
+def stack_leaves(parts, shape):
+    """Like layers' leaves stacked into one of ``shape``, in ONE program,
+    so that nothing but the stacked leaf is made beside its parts (eager
+    ``jnp.stack`` expands each part into a copy first): what a model's
+    ``plan.weights()`` yields when it hands its parameters over."""
+    return jax.jit(lambda *p: jnp.stack(p).reshape(shape))(*parts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,6 +102,12 @@ class ServingPlan:
     conv_tail: int = 0
     conv_channels: int = 0
     counts: int = 0             # length of a layer's routing counts (0: none)
+    # generation by blocks (0: one token a sequence a forward)
+    block: int = 0
+    mask_token: int = 0
+    denoising_steps: int = 0
+    unmask: Optional[Callable] = None   # (conf, masked, step) -> [B, Q] bool
+    early_exit: bool = False
 
     def layers_of(self, cache):
         """How many layers keep a cache of this kind, over all periods."""

@@ -42,14 +42,11 @@ from ..core.tensor import Parameter, Tensor
 from ..nn.layer.layers import Layer
 from ..ops.pallas.kda import kda_recurrence
 from ..ops.pallas.moe_gmm import moe_gmm, moe_gmm_ref
+from .dropless import ROUTING_COUNTS, routed_experts, routing_counts
 from .llama import rms_norm
-from .serving_plan import LayerKind, ServingPlan
+from .serving_plan import LayerKind, ServingPlan, stack_leaves
 
 __all__ = ["SolarOpen2Config", "SolarOpen2ForCausalLM", "ROUTING_COUNTS"]
-
-# what one expert layer's routing gives for one call, in this order
-ROUTING_COUNTS = ("calls", "rows", "assignments", "experts_touched",
-                  "max_load")
 
 
 class SolarOpen2Config:
@@ -266,50 +263,26 @@ def experts(p, x, live, c, gmm=moe_gmm_ref, l=0):
     Returns ``x + shared(h) + sum over the chosen HELD experts`` and the
     routing counts (``ROUTING_COUNTS``, int32).
 
-    The experts' three matrices may come as one layer's ``[E, in, out]`` or
-    as the whole stack of like layers ``[..., E, in, out]`` with ``l`` this
-    layer's index among them: the stack goes to ``gmm`` as it lies, as
-    ``layers x E`` groups of which only this layer's have rows (a slice of
-    it would be a copy of 0.4 GB a matrix in front of the kernel).
-
-    Dropless: every (row, chosen held expert) pair is an assignment; they
-    are sorted by expert, the rows gathered in that order, and the three
-    grouped products (``gmm``) see ``group_sizes`` of whatever they are -
-    no capacity. Pairs whose expert lives on another chip sort past the
-    last group and weigh nothing."""
-    e, k = c.experts_held, c.num_experts_per_tok
-    n = x.shape[0]
+    The router is this family's (sigmoid scores, a correction bias for the
+    choice only, the chosen renormalised); the sum over the chosen experts
+    held here and the counts are ``models/dropless.py``'s, which every
+    sparse model of the engine shares (``l``: this layer's index where the
+    experts' matrices come as the whole stack of like layers)."""
     h = rms_norm(x, p["ln2"], c.rms_norm_eps)
     score = jax.nn.sigmoid(_mm32(h, p["router"]))                # [N, R]
     _, chosen = jax.lax.top_k(
-        score + p["router_bias"].astype(jnp.float32), k)
+        score + p["router_bias"].astype(jnp.float32),
+        c.num_experts_per_tok)
     weight = jnp.take_along_axis(score, chosen, axis=1)
     if c.norm_topk_prob:
         weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
     weight = weight * c.routed_scaling_factor
-    local = chosen - c.expert_offset
-    held = (local >= 0) & (local < e) & (live > 0)[:, None]
-    group = jnp.where(held, local, e).reshape(-1)                # [N * k]
-    order = jnp.argsort(group, stable=True)
-    sizes = jnp.sum(group[:, None] == jnp.arange(e, dtype=group.dtype),
-                    axis=0, dtype=jnp.int32)
-    wg, wu, wd = (p[n].reshape((-1,) + p[n].shape[-2:])
-                  for n in ("wg", "wu", "wd"))
-    groups = jax.lax.dynamic_update_slice(
-        jnp.zeros((wg.shape[0],), jnp.int32), sizes, (l * e,))
-    rows = h[order // k]
-    act = (jax.nn.silu(gmm(rows, wg, groups, jnp.float32))
-           * gmm(rows, wu, groups, jnp.float32)).astype(x.dtype)
-    out = gmm(act, wd, groups, jnp.float32)                      # [N * k, H]
-    # rows past the groups hold nothing a sum may see
-    out = jnp.where(held.reshape(-1)[order][:, None],
-                    out * weight.reshape(-1)[order][:, None], 0.0)
-    routed = out[jnp.argsort(order)].reshape(n, k, -1).sum(axis=1)
+    routed, held, sizes = routed_experts(
+        p, h, chosen, weight, live, c.experts_held, c.expert_offset, gmm, l,
+        x.dtype)
     shared = _mm32((jax.nn.silu(_mm32(h, p["sg"]))
                     * _mm32(h, p["su"])).astype(x.dtype), p["sd"])
-    counts = jnp.stack([jnp.int32(1), jnp.sum(live > 0, dtype=jnp.int32),
-                        jnp.sum(held, dtype=jnp.int32),
-                        jnp.sum(sizes > 0, dtype=jnp.int32), jnp.max(sizes)])
+    counts = routing_counts(live, held, sizes)
     return x + (shared + routed).astype(x.dtype), counts
 
 
@@ -436,7 +409,7 @@ class SolarOpen2ForCausalLM(Layer):
             layers = [l for l in self.layers if l.kind == kind]
             for name in layer_leaves(c, kind):
                 parts = [take(l._parameters[name]) for l in layers]
-                stacked = _stack(parts, (c.periods, n) + parts[0].shape)
+                stacked = stack_leaves(parts, (c.periods, n) + parts[0].shape)
                 del parts
                 yield f"{kind}.{name}", stacked
 
@@ -484,12 +457,6 @@ def serving_plan(c, weights, kernels=False):
         state_dk=c.linear_head_dim, state_dv=c.linear_head_dim,
         conv_tail=c.short_conv_kernel_size - 1,
         conv_channels=c.conv_channels, counts=len(ROUTING_COUNTS))
-
-
-def _stack(parts, shape):
-    """One program, so that nothing but the stacked leaf is made beside its
-    parts (eager ``jnp.stack`` expands each part into a copy first)."""
-    return jax.jit(lambda *p: jnp.stack(p).reshape(shape))(*parts)
 
 
 def _keys(c, kind):

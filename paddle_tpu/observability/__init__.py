@@ -122,6 +122,21 @@ SERVING_MOE = (
     REGISTRY.counter("serving_moe_max_load_total",
                      "the fullest expert's assignments, summed over the "
                      "calls", ("engine", "kind")))
+# a model that generates by blocks (engine/runner.py:_build_block): the
+# forwards are counted on the device and read with the tokens
+SERVING_BLOCKS = REGISTRY.counter(
+    "serving_blocks_total",
+    "blocks dispatched: live sequences summed over block dispatches",
+    ("engine",))
+SERVING_BLOCK_FORWARDS = REGISTRY.counter(
+    "serving_block_forwards_total",
+    "forward passes of block dispatches, by kind: denoise (they unmask "
+    "positions) | commit (the finished block's keys and values)",
+    ("engine", "kind"))
+SERVING_BLOCK_SEQUENCE_FORWARDS = REGISTRY.counter(
+    "serving_block_sequence_forwards_total",
+    "live sequences summed over the forward passes of block dispatches",
+    ("engine",))
 SERVING_TOKENS = REGISTRY.counter(
     "serving_generated_tokens_total", "tokens emitted to requests",
     ("engine",))

@@ -29,6 +29,12 @@ NEG_INF = -1e30
 _BLOCK_ELEMS = 128 * 128     # a KV head's key tile a trip: see _pages_per_block
 
 
+# which query of its sequence's q_len a row counts as, for its horizon: its
+# own (row r is query r % q_len), or the last one's for every row of a block
+_REACH = {"row": lambda r, q_len: r % q_len,
+          "block": lambda r, q_len: q_len - 1}
+
+
 def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
@@ -42,7 +48,7 @@ def _pages_per_block(page_size, head_dim):
 
 
 def _walk(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, *rest, scale, page_size, ppb,
-          n_slots, kv_heads, q_len):
+          n_slots, kv_heads, q_len, horizon="row"):
     """The one body of the four entry points: program ``b`` of a grid over
     rows walks row ``b``'s context in blocks of ``ppb`` consecutive table
     entries, ``ceil((ctx + q_len - 1) / block)`` of them and no more.
@@ -72,7 +78,9 @@ def _walk(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, *rest, scale, page_size, ppb,
     Rows are kv-head-major ([B, H * q_len, D], row = qh * q_len + j): every
     KV head's rows are one contiguous slice, and with one query that layout
     is [B, H, D] itself. Row j's causal horizon is ctx + j (ctx = context
-    of row 0, itself included)."""
+    of row 0, itself included); with ``horizon="block"`` every row has the
+    last row's, ctx + q_len - 1: the q_len positions are one block whose
+    rows see the context and each other (generation by blocks)."""
     # int8 pages bring their two scale operands, bf16 pages none
     *scales, o_ref, kbuf, vbuf, sem, first, m_s, l_s, acc_s = rest
     ks_ref, vs_ref = scales or (None, None)
@@ -122,7 +130,8 @@ def _walk(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, *rest, scale, page_size, ppb,
     col = jax.lax.broadcasted_iota(jnp.int32, (HQ, cols), 1)
     r = jax.lax.broadcasted_iota(jnp.int32, (HQ, cols), 0)
     own = col % kv_heads == r // (HQ // kv_heads)
-    age = jnp.where(own, col // kv_heads - r % q_len, jnp.int32(2 ** 30))
+    age = jnp.where(own, col // kv_heads - _REACH[horizon](r, q_len),
+                    jnp.int32(2 ** 30))
 
     def trip(i, carry):
         slot = (slot0 + i) % 2
@@ -170,7 +179,8 @@ def _walk(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, *rest, scale, page_size, ppb,
 
 
 def _paged_call(qf, k_pages, v_pages, block_tables, context_lens,
-                k_scales, v_scales, scale_tables, *, scale, q_len):
+                k_scales, v_scales, scale_tables, *, scale, q_len,
+                horizon="row"):
     """``pallas_call`` of :func:`_walk` on kv-head-major rows
     ``qf [B, H * q_len, D]``.
 
@@ -216,7 +226,7 @@ def _paged_call(qf, k_pages, v_pages, block_tables, context_lens,
         ])
     kern = functools.partial(
         _walk, scale=scale, page_size=page_size, ppb=ppb, n_slots=S,
-        kv_heads=KVH, q_len=q_len)
+        kv_heads=KVH, q_len=q_len, horizon=horizon)
     return pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, HQ, D), qf.dtype),
@@ -298,10 +308,10 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, context_lens,
     return jnp.stack(out).astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale",))
+@functools.partial(jax.jit, static_argnames=("scale", "horizon"))
 def paged_attention_multiquery(q, k_pages, v_pages, block_tables,
                                context_lens, *, k_scales=None, v_scales=None,
-                               scale_tables=None, scale=None):
+                               scale_tables=None, scale=None, horizon="row"):
     """Verification attention: Q consecutive query positions per sequence
     against the paged KV cache (speculative decoding scores the pending
     token plus all drafts in ONE forward).
@@ -311,6 +321,10 @@ def paged_attention_multiquery(q, k_pages, v_pages, block_tables,
     context_lens:  [B] int32       cache tokens visible to row 0 (incl. its
                                    own just-written entry); row j's causal
                                    horizon is context_lens[b] + j
+    horizon:       "row" (above: verification) | "block": the Q positions
+                   are ONE block and every row sees context_lens[b] + Q - 1
+                   tokens, the whole block included (generation by blocks:
+                   causal from block to block, both ways inside one)
     k_pages/v_pages/block_tables/k_scales/v_scales/scale_tables: as
                    paged_attention
     returns        [B, Q, H, D]
@@ -326,17 +340,38 @@ def paged_attention_multiquery(q, k_pages, v_pages, block_tables,
     # head h, query position = row % Q
     qf = jnp.transpose(q, (0, 2, 1, 3)).reshape(B, H * Q, D)
     out = _paged_call(qf, k_pages, v_pages, block_tables, context_lens,
-                      k_scales, v_scales, scale_tables, scale=scale, q_len=Q)
+                      k_scales, v_scales, scale_tables, scale=scale, q_len=Q,
+                      horizon=horizon)
     return jnp.transpose(out.reshape(B, H, Q, D), (0, 2, 1, 3))
+
+
+def _paged_attention_blocks(q, k_pages, v_pages, block_tables, context_lens,
+                            *, k_scales=None, v_scales=None,
+                            scale_tables=None, scale=None):
+    """:func:`paged_attention_multiquery` with ``horizon="block"``: the Q
+    positions a sequence are one block of a model that generates by blocks.
+    A function of its own for its NAME: a kernel stands in the device trace
+    under the innermost ``jit``'s, and a decode dispatch's attention is
+    ``paged_attention`` there whatever the rows it takes (the benchmark's
+    readers are anchored on it)."""
+    return paged_attention_multiquery.__wrapped__(
+        q, k_pages, v_pages, block_tables, context_lens, k_scales=k_scales,
+        v_scales=v_scales, scale_tables=scale_tables, scale=scale,
+        horizon="block")
+
+
+_paged_attention_blocks.__name__ = "paged_attention"
+paged_attention_blocks = jax.jit(_paged_attention_blocks,
+                                 static_argnames=("scale",))
 
 
 def paged_attention_multiquery_ref(q, k_pages, v_pages, block_tables,
                                    context_lens, *, k_scales=None,
                                    v_scales=None, scale_tables=None,
-                                   scale=None):
+                                   scale=None, horizon="row"):
     """jnp reference for the multi-query kernel (dense gather, per-row
-    causal horizon ctx + j) — golden for the kernel test and the engine's
-    CPU path."""
+    causal horizon ctx + j, or ctx + Q - 1 for every row of a block) —
+    golden for the kernel test and the engine's CPU path."""
     B, Q, H, D = q.shape
     P, page_size, KVH, _ = k_pages.shape
     S = block_tables.shape[1]
@@ -355,9 +390,10 @@ def paged_attention_multiquery_ref(q, k_pages, v_pages, block_tables,
             v = (v.astype(jnp.float32) *
                  v_scales[sp].reshape(S * page_size, KVH)[..., None])
         cl = context_lens[b_i]
-        # row j attends tokens [0, cl + j)
+        # row j attends tokens [0, cl + j); a block's rows all [0, cl + Q - 1)
         mask = (jnp.arange(S * page_size)[None, :]
-                < cl + jnp.arange(Q)[:, None])             # [Q, T]
+                < cl + (jnp.arange(Q) if horizon == "row"
+                        else jnp.full((Q,), Q - 1))[:, None])   # [Q, T]
         qh = jnp.transpose(q[b_i], (1, 0, 2)).reshape(
             KVH, group, Q, D).astype(jnp.float32)
         kh = jnp.moveaxis(k, 1, 0).astype(jnp.float32)     # [KVH, T, D]

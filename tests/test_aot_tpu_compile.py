@@ -391,3 +391,79 @@ class TestSolarOpen2Programs:
         for name in ("moe_gmm", "paged_attention") + (
                 ("kda_step",) if kind == "decode" else ()):
             assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text), name
+
+
+class TestSDARPrograms:
+    """The block-generation cell's two whole programs on the v5e's compiler
+    at the cell's own shapes (bench/configs/sdar-30b-a3b-chat.json:
+    published widths, 6 layers, all 128 experts, the whole vocabulary, 64
+    slots x 3,072 tokens, chunks of 128, blocks of 4 in 4 steps): the
+    multi-query paged attention with a block's horizon and ``moe_gmm`` over
+    the stack of six layers' experts, inside a scan over denoising steps."""
+    B, MAX_LEN, CHUNK = 64, 3072, 128
+
+    def runner_and_arguments(self, sharding, remasking="sequential"):
+        from paddle_tpu.inference.engine.runner import (
+            _COUNT_ROWS, BLOCK_COUNTS, ModelRunner)
+        from paddle_tpu.models import sdar
+        cfg = sdar.SDARConfig(num_hidden_layers=6, remasking=remasking)
+        n_pages = self.B * self.MAX_LEN // PAGE + 1
+        r = ModelRunner.__new__(ModelRunner)
+        r.cfg, r.mesh = cfg, None
+        r.max_batch, r.page, r.chunk = self.B, PAGE, self.CHUNK
+        r.n_pages, r.trash_page = n_pages, n_pages - 1
+        r.use_kernel, r.kv_quant = True, False
+        r.plan = plan = sdar.serving_plan(cfg, None, kernels=True)
+        r._block_counts_at = 2
+
+        def sds(shape, dt=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+        H, V, L = cfg.hidden_size, cfg.vocab_size, cfg.num_hidden_layers
+        W = {"embed": sds((V, H)), "norm": sds((H,)), "head": sds((H, V))}
+        for name, (shape, _) in sdar.layer_leaves(cfg).items():
+            W[name] = sds((L,) + tuple(shape))
+        pages = sds((L, n_pages, PAGE, plan.kvh, plan.D))
+        cache = (pages, pages, sds((len(BLOCK_COUNTS),), jnp.int32),
+                 sds((len(_COUNT_ROWS), plan.counts), jnp.int32))
+        i32, f32 = jnp.int32, jnp.float32
+        table = self.MAX_LEN // PAGE
+        decode = [sds((self.B, plan.block), i32), sds((self.B,), i32),
+                  sds((self.B, table), i32), sds((self.B,), i32)] + [
+            sds((self.B,), dt) for dt in (i32, f32, f32, i32, i32, i32,
+                                          i32, i32)]        # .., take, prev
+        prefill = [sds((self.CHUNK,), i32), sds((), i32), sds((table,), i32),
+                   sds((), i32)] + [
+            sds((), dt) for dt in (i32, f32, f32, i32, i32)]
+        return r, W, cache, {"decode": decode, "prefill": prefill}
+
+    @pytest.mark.parametrize("kind,remasking", [
+        ("decode", "sequential"), ("decode", "low_confidence_dynamic"),
+        ("prefill", "sequential")])
+    def test_whole_program_fits_and_copies_no_pool(self, v5e, kind, remasking):
+        import time
+        r, W, cache, rest = self.runner_and_arguments(
+            SingleDeviceSharding(v5e[0]), remasking)
+        prog = (r._build_decode(r.plan.block) if kind == "decode"
+                else r._build_prefill())
+        t0 = time.perf_counter()
+        compiled = prog.lower(W, cache, *rest[kind]).compile()
+        seconds = time.perf_counter() - t0
+        mem = compiled.memory_analysis()
+        print(f"sdar {kind} ({remasking}): arguments "
+              f"{mem.argument_size_in_bytes / 2**30:.2f} GiB, temporaries "
+              f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, compiled in "
+              f"{seconds:.0f} s")
+        assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                < 13 * 2**30)
+        text = compiled.as_text()
+        # neither a page pool nor the layers' experts is copied or sliced
+        # out in front of its kernel
+        for shape in (r"bf16\[(?:6,)?\d+,16,4,128\]",
+                      r"bf16\[(?:6,128|768),(?:2048,768|768,2048)\]"):
+            assert not re.findall(
+                rf"= {shape}\S* (?:copy|dynamic-slice|gather)\(", text), shape
+        for name in ("moe_gmm", "paged_attention"):
+            assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text), name
+        if kind == "decode":
+            # the loop over denoising steps and the layers' scans are whiles
+            assert text.count(" while(") >= 2

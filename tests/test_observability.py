@@ -711,9 +711,26 @@ class TestStepLoopSpans:
         # between the first leaf's start and the last one's end
         for (_, _, end, _), (name, start, _, _) in zip(leaves, leaves[1:]):
             assert start >= end, (name, start, end)
+        # ... turn by turn of the loop (from one replica.lock to the next):
+        # in the median turn the leaves hold 80 % of the wall time, and
+        # 80 % of the whole. (The one sum over the trace was held to 95 %:
+        # true while three quarters of an unloaded trace were the timed
+        # idle waits, and false under the driver's six workers, where the
+        # working part stretches, the waits do not, and a lost time slice
+        # falls between two leaves as well as inside one: 91 % there. Work
+        # outside every leaf would show in every turn; the median turn reads
+        # 85-90 %, loaded or not, at this model's leaves of tens of
+        # microseconds.)
+        turns = [i for i, (name, *_) in enumerate(leaves)
+                 if name == "replica.lock"]
+        cover = sorted(
+            sum(end - start for _, start, end, _ in leaves[lo:hi])
+            / (leaves[hi][1] - leaves[lo][1])
+            for lo, hi in zip(turns, turns[1:]))
+        assert len(cover) > 8 and cover[len(cover) // 2] >= 0.80, cover
         wall = leaves[-1][2] - leaves[0][1]
         covered = sum(end - start for _, start, end, _ in leaves)
-        assert covered >= 0.95 * wall, (covered, wall)
+        assert covered >= 0.80 * wall, (covered, wall)
         # every step says what it ran
         kinds = {st.get("kind") for name, _, _, st in events
                  if name == "engine.step"}
@@ -788,6 +805,31 @@ class TestStepLoopSpans:
             assert argmax["decode"] == 0
             chunks = -(-len(prompts[2]) // eng.chunk)
             assert argmax["prefill"] == total["prefill"] - chunks
+
+    def test_a_submitter_gets_in_between_two_steps(self, replica):
+        """The loop hands the engine condition to whoever waits for it
+        before it steps again: a submit that arrives while a request of 40
+        paced steps is being served waits a step or two, not for the
+        request's end (a lock has no fairness of its own: dropped and taken
+        again at once it came back to the loop nearly every time)."""
+        from paddle_tpu.testing.faults import FAULTS, Always
+        rep, eng = replica
+        FAULTS.install("serving.slow_step", Always(), delay=0.02)
+        try:
+            first = rep.submit(_prompts(seed=16, n=1)[0], max_new_tokens=40)
+            deadline = 100
+            while not any(s is not None for s in eng.sched.slots) and deadline:
+                deadline -= 1
+                time.sleep(0.01)
+            t0 = time.monotonic()
+            second = rep.submit(_prompts(seed=17, n=1)[0], max_new_tokens=2)
+            waited = time.monotonic() - t0
+            # the first request still has most of its 0.8 s of steps to go
+            assert first not in eng.sched.finished
+        finally:
+            FAULTS.reset()
+        assert waited < 0.3, waited
+        assert len(_drain(rep, second)) == 2 and len(_drain(rep, first)) == 40
 
     def test_a_submitter_held_out_by_a_slow_step_is_counted(self, replica):
         from paddle_tpu.testing.faults import FAULTS, Always
