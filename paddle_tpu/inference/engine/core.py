@@ -519,6 +519,11 @@ class LLMEngine(_SpecOrchestration):
         an already-dispatched XLA program is best-effort (the donated cache
         buffer may be unrecoverable) — the engine still degrades per-request
         instead of crashing the loop."""
+        if _obs.enabled():
+            # this thread waits on this engine's device: its spans feed
+            # loop_seconds_total (observability.tracing's device watch)
+            _obs.watch_device(self.runner.device_busy, engine=self._m.label,
+                              waits=("runner.wait",))
         with _obs.trace_span("engine.step") as sp:
             if self._any_deadline:
                 with _obs.trace_span("engine.admit"):
@@ -598,9 +603,13 @@ class LLMEngine(_SpecOrchestration):
             self._m.count_argmax("decode", (r for _, r in rows))
             if self.block:
                 self._m.blocks.inc(len(rows))
+        # the rows' ids only where the flight recorder keeps their events
+        rids = tids = None
+        if _flight.enabled():
+            rids = [r.rid for _, r in rows]
+            tids = [r.trace_id for _, r in rows]
         # timed: the auto-fit below needs the wall time whatever is switched on
-        with _obs.trace_span("decode", rid=[r.rid for _, r in rows],
-                             trace_id=[r.trace_id for _, r in rows],
+        with _obs.trace_span("decode", rid=rids, trace_id=tids,
                              timed=self._auto_block, block=k) as sp:
             t0 = time.perf_counter()
             toks = self.runner.run_decode(k, args[0], lens, tables, *args[1:])
@@ -955,8 +964,6 @@ class LLMEngine(_SpecOrchestration):
         self._m.cached_pages.set(len(self.pool.key_page))
         self._m.reclaimable.set(len(self.pool.lru))
         self._m.free_pages.set(len(self.pool.free_pages))
-        if self.runner.plan.recurrent:
-            self._m.state_slots.set(n_active)
         if self.pool.host is not None:
             self._m.host_cached.set(len(self.pool.host))
 

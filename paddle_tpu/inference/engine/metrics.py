@@ -78,9 +78,7 @@ class _EngineMetrics:
         self.step_fail = {ph: _obs.SERVING_STEP_FAILURES.labels(phase=ph, **e)
                           for ph in ("prefill", "decode", "verify")}
         self.probes = _obs.SERVING_QUARANTINE_PROBES.labels(**e)
-        # a model with recurrent state / sparse experts (nothing moves them
-        # for any other)
-        self.state_slots = _obs.SERVING_STATE_SLOTS.labels(**e)
+        # a model with sparse experts (nothing moves them for any other)
         self.routing = [[fam.labels(kind=k, **e) for fam in _obs.SERVING_MOE]
                         for k in ("decode", "prefill")]
         # a model that generates by blocks
@@ -93,8 +91,9 @@ class _EngineMetrics:
     def count_argmax(self, kind, requests):
         """Count one ``kind`` dispatch over ``requests`` if none of them
         samples: every row then reaches the runner greedy (idle slots are
-        sent greedy) and its program takes the arg-max alone."""
-        if not any(r.do_sample for r in requests):
+        sent greedy) and its program takes the arg-max alone.  With metrics
+        off the rows are not looked at."""
+        if _obs.enabled() and not any(r.do_sample for r in requests):
             self.argmax[kind].inc()
 
     def count_routing(self, grown):

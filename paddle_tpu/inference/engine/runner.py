@@ -293,6 +293,7 @@ class ModelRunner:
         self._decode_programs: dict = {}
         self._verify_programs: dict = {}
         self._launched = set()      # programs that have run (and so compiled)
+        self._newest = None         # the first output of the last launch
         self._copy_page_fn = None
         self._gather_fn = {}
         self._scatter_fn = {}
@@ -807,12 +808,27 @@ class ModelRunner:
                 out, self.cache = prog(self.W, self.cache,
                                        *[jnp.asarray(a) for a in args])
             self._launched.add(key)
-            return out
-        with _obs.trace_span("runner.dispatch", **attrs):
-            args = [jnp.asarray(a) for a in args]
-        with _obs.trace_span("runner.launch", kind=key[0]):
-            out, self.cache = prog(self.W, self.cache, *args)
+        else:
+            with _obs.trace_span("runner.dispatch", **attrs):
+                moved = []
+                for a in args:
+                    moved.append(jnp.asarray(a))
+                    # where the device finishes during the fan, the device
+                    # watch places it within one argument, not the fan
+                    _obs.tick()
+                args = moved
+            with _obs.trace_span("runner.launch", kind=key[0]):
+                out, self.cache = prog(self.W, self.cache, *args)
+        # never donated: the decode tokens, the prefill sample, the verify
+        # tokens
+        self._newest = out[0] if isinstance(out, tuple) else out
         return out
+
+    def device_busy(self):
+        """Whether the program launched last is still queued or running on
+        the device (what launched before it ran before it): the step loop's
+        probe for ``observability.watch_device``. Never waits."""
+        return self._newest is not None and not self._newest.is_ready()
 
     def run_prefill(self, tokens, start, table, n_valid,
                     greedy, temp, topp, topk, seed, slot=0):
@@ -825,8 +841,6 @@ class ModelRunner:
                   "argmax_only": _argmax_only(greedy)}
                  if _obs.enabled() else {})
         state = (np.int32(slot),) if self.plan.recurrent else ()
-        if state and attrs:
-            attrs["state_rows"] = 1
         return self._launch(
             ("prefill",), self._prefill, attrs, tokens, np.int32(start), table,
             np.int32(n_valid), np.int32(greedy), np.float32(temp),
@@ -862,11 +876,6 @@ class ModelRunner:
                      "ctx_sum": int(ctx.sum()), "k": int(k),
                      "argmax_only": _argmax_only(greedy),
                      "ahead": int(self.decode_unread)}
-            if self.plan.recurrent:
-                attrs["state_rows"] = int(ctx.size)
-            if self.plan.block:     # the most forwards the dispatch makes
-                attrs.update(block=self.plan.block,
-                             forwards=self.plan.denoising_steps + 1)
         toks, self._last = self._launch(
             ("decode", k), prog, attrs, tokens, lens, tables, active, greedy,
             temp, topp, topk, seeds, fold, take, self._last)

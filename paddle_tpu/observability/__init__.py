@@ -18,6 +18,8 @@ Usage::
     with obs.trace_span("my.phase", rows=4) as sp:   # TraceAnnotation (with
         ...                           # its attributes) + span_seconds; and the
     sp.dur                            # flight recorder for a traced request
+    obs.watch_device(probe, engine="0")   # this thread's spans also feed
+                                      # loop_seconds_total (see .tracing)
     obs.disable()
 
 Cost model: disabled (the default), every instrumented call site pays one
@@ -35,13 +37,14 @@ from . import flight  # noqa: F401  (request tracing / flight recorder)
 from . import registry as _registry
 from .registry import (DEFAULT_BUCKETS, REGISTRY, MetricsRegistry,  # noqa: F401
                        enabled, merge_snapshots, render_snapshot)
-from .tracing import SPAN_SECONDS, trace_span  # noqa: F401
+from .tracing import SPAN_SECONDS, tick, trace_span, watch_device  # noqa: F401
 
 __all__ = [
     "MetricsRegistry", "REGISTRY", "DEFAULT_BUCKETS",
     "enable", "disable", "enabled", "reset",
     "snapshot", "render_prometheus", "render_snapshot", "merge_snapshots",
-    "trace_span", "record_collective", "start_metrics_server", "flight",
+    "trace_span", "watch_device", "tick", "record_collective",
+    "start_metrics_server", "flight",
 ]
 
 
@@ -53,6 +56,15 @@ def start_metrics_server(port: int = 0, addr: str = "127.0.0.1"):
     return _start(port=port, addr=addr)
 
 # ---- standard families -------------------------------------------------------
+# a watched thread's time between two span boundaries (tracing.py,
+# watch_device): span is the innermost one open ("none": outside every span),
+# device says whether a program the thread launched was queued or running
+LOOP_SECONDS = REGISTRY.counter(
+    "loop_seconds_total",
+    "wall time of a watched thread by the innermost open span and by whether "
+    "a program it launched was queued or running on the device (busy) or "
+    "not (dry)", ("engine", "span", "device"))
+
 # dispatch (core/dispatch.py, fed through the op_recorder slot)
 DISPATCH_OPS = REGISTRY.counter(
     "dispatch_ops_total", "ops dispatched through apply_op", ("op",))
@@ -100,9 +112,6 @@ SERVING_DECODE_LAUNCHES = REGISTRY.counter(
     "decode dispatches, by whether they were launched ahead: while the "
     "decode dispatch before's tokens were still unread by the host",
     ("engine", "ahead"))                       # ahead: "0" | "1"
-SERVING_STATE_SLOTS = REGISTRY.gauge(
-    "serving_state_slots_in_use",
-    "slots whose recurrent state belongs to an admitted request", ("engine",))
 # a sparse expert layer's routing, summed on the device over every layer's
 # call and read with the tokens (engine/runner.py); kind: decode | prefill.
 # In the order of models.solar_open2.ROUTING_COUNTS.

@@ -34,6 +34,9 @@ __all__ = ["MetricsRegistry", "REGISTRY", "enabled", "DEFAULT_BUCKETS",
            "render_snapshot", "merge_snapshots"]
 
 _ENABLED = False
+# how many times the switch went on: a device watch (tracing.py) counts no
+# interval that began before the last time it did
+_TURNED_ON = 0
 
 
 def enabled() -> bool:
@@ -42,8 +45,11 @@ def enabled() -> bool:
 
 
 def _set_enabled(on: bool) -> None:
-    global _ENABLED
-    _ENABLED = bool(on)
+    global _ENABLED, _TURNED_ON
+    on = bool(on)
+    if on and not _ENABLED:
+        _TURNED_ON += 1
+    _ENABLED = on
 
 
 _NAME_RE = re.compile(r"[a-zA-Z_:][a-zA-Z0-9_:]*$")

@@ -433,6 +433,127 @@ class TestTraceSpan:
         assert sp.dur is not None
 
 
+# ------------------------------------------------------------ the device watch
+
+def _loop_seconds(engine):
+    """``{(span, device): seconds}`` of ``loop_seconds_total`` for one
+    engine label, the series that hold time."""
+    fam = obs.snapshot(prefix="loop_seconds_total",
+                       labels={"engine": engine}).get("loop_seconds_total")
+    return {(s["labels"]["span"], s["labels"]["device"]): s["value"]
+            for s in (fam or {}).get("series", []) if s["value"]}
+
+
+class TestDeviceWatch:
+    """The rule, on a fake clock and a fake probe: an interval between two
+    span boundaries of a watched thread goes to the innermost span open
+    through it ("none" outside every span); dry where the probe read idle
+    at its start, busy where it read busy at both ends, half each where it
+    went from busy to idle."""
+
+    @pytest.fixture
+    def watched(self, metrics, monkeypatch):
+        from paddle_tpu.observability import tracing
+        clock = [100.0]
+        monkeypatch.setattr(tracing.time, "perf_counter", lambda: clock[0])
+        device = {"busy": False, "reads": 0}
+
+        def probe():
+            device["reads"] += 1
+            return device["busy"]
+        obs.watch_device(probe, engine="fake")
+        device["probe"] = probe
+        yield clock, device
+        obs.watch_device(None)
+
+    def test_dry_busy_and_the_split_go_to_the_innermost_span(self, watched):
+        clock, device = watched
+        with obs.trace_span("outer"):   # the first boundary counts nothing
+            clock[0] += 1.0             # idle at the start: dry
+            device["busy"] = True       # a launch inside the interval
+            with obs.trace_span("inner"):
+                clock[0] += 2.0         # busy at both ends: busy
+            clock[0] += 4.0
+            device["busy"] = False      # busy -> idle: half each
+        clock[0] += 8.0                 # outside every span, idle: dry
+        with obs.trace_span("last"):
+            pass
+        assert _loop_seconds("fake") == {
+            ("outer", "dry"): 1.0 + 2.0, ("inner", "busy"): 2.0,
+            ("outer", "busy"): 2.0, ("none", "dry"): 8.0}
+        assert device["reads"] == 6     # once a boundary
+
+    def test_a_wait_on_the_device_is_busy_and_a_tick_cuts_a_long_span(
+            self, watched):
+        """Under a span the registrant names as a wait, busy -> idle is
+        busy: the wait ended because the device finished. A tick inside a
+        long span narrows where the device finished to one piece of it."""
+        clock, device = watched
+        obs.watch_device(device["probe"], engine="fake", waits=("wait",))
+        device["busy"] = True
+        with obs.trace_span("wait"):
+            clock[0] += 3.0
+            device["busy"] = False
+        device["busy"] = True           # the next launch
+        with obs.trace_span("fan"):
+            clock[0] += 1.0
+            obs.tick()                  # busy at both ends of the piece
+            clock[0] += 1.0
+            device["busy"] = False
+            obs.tick()                  # finished within this piece: half
+            clock[0] += 1.0             # idle from the piece's end: dry
+        assert _loop_seconds("fake") == {
+            ("wait", "busy"): 3.0, ("fan", "busy"): 1.5, ("fan", "dry"): 1.5}
+
+    def test_an_interval_across_the_switch_going_off_is_not_counted(
+            self, watched):
+        clock, _ = watched
+        with obs.trace_span("a"):
+            clock[0] += 1.0
+        obs.disable()
+        clock[0] += 50.0
+        obs.enable()
+        clock[0] += 1.0
+        with obs.trace_span("b"):       # starts afresh: the 52 s go nowhere
+            clock[0] += 2.0
+        assert _loop_seconds("fake") == {("a", "dry"): 1.0, ("b", "dry"): 2.0}
+
+    def test_a_thread_that_registered_nothing_is_untouched(self, watched):
+        _, device = watched
+
+        def elsewhere():
+            with obs.trace_span("test.unwatched"):
+                with obs.trace_span("test.unwatched.inner"):
+                    pass
+        t = threading.Thread(target=elsewhere)
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+        assert device["reads"] == 0
+        fam = obs.snapshot(prefix="loop_seconds_total").get(
+            "loop_seconds_total", {"series": []})
+        assert not [s for s in fam["series"] if s["value"]]
+
+    def test_metrics_off_calls_no_probe_and_spans_stay_the_shared_noop(self):
+        from paddle_tpu.observability import flight, tracing
+        obs.disable()
+        flight.disable()
+        reads = []
+        obs.watch_device(lambda: reads.append(1) or True, engine="off")
+        try:
+            sp = obs.trace_span("test.watch.off")
+            assert sp is tracing._NOOP
+            with sp:
+                pass
+            with obs.trace_span("test.watch.timed", timed=True):
+                pass                    # the clock, for its caller alone
+            obs.tick()
+        finally:
+            obs.watch_device(None)
+        assert reads == []
+        assert _loop_seconds("off") == {}
+
+
 # ------------------------------------------------- profiler scheduler/export
 
 class TestScheduler:
@@ -857,3 +978,74 @@ class TestStepLoopSpans:
         waits = obs.snapshot(prefix="serving_queue_wait_seconds",
                              labels={"engine": eng._m.label})
         assert waits["serving_queue_wait_seconds"]["series"][0]["count"] == 2
+
+    @pytest.fixture
+    def replica_set(self, metrics, model):
+        from paddle_tpu.inference.frontend.replica import ReplicaSet
+        eng = _engine(model, max_batch=4)
+        rs = ReplicaSet([eng], poll_interval=0.01)
+        # compile both programs before anything is measured
+        rs.result(rs.submit(_prompts(seed=9, n=1)[0], max_new_tokens=3),
+                  timeout=60)
+        yield rs, eng
+        rs.close()
+
+    @staticmethod
+    def _slow_steps(rs, eng, prompts, max_new_tokens):
+        """Serve ``prompts`` with every step 30 ms longer on the host
+        (``serving.slow_step``), from a zeroed registry until the loop has
+        stopped; ``(loop_seconds_total by (span, device), span_seconds by
+        span, engine.step's count)`` of the engine."""
+        from paddle_tpu.testing.faults import FAULTS, Always
+        time.sleep(0.05)
+        with rs.replicas[0]._cv:
+            # the loop waits inside replica.idle: no boundary while the
+            # registry is zeroed
+            obs.reset()
+        FAULTS.install("serving.slow_step", Always(), delay=0.03)
+        try:
+            handles = [rs.submit(p, max_new_tokens=max_new_tokens)
+                       for p in prompts]
+            for h in handles:
+                assert len(rs.result(h, timeout=60)[0]) == max_new_tokens
+        finally:
+            FAULTS.reset()
+        rs.close()                      # the loop's last span has ended
+        spans = {s["labels"]["span"]: s for s in
+                 obs.snapshot(prefix="span_seconds")["span_seconds"]["series"]}
+        return (_loop_seconds(eng._m.label),
+                {name: s["sum"] for name, s in spans.items()},
+                spans["engine.step"]["count"])
+
+    def test_a_slow_host_step_is_time_the_device_sat_dry(self, replica_set):
+        """The 30 ms a step sleeps lie under ``engine.step`` and no span of
+        its own. Prompts of one chunk and one new token each: a step's one
+        program is its chunk, whose sample the step reads, so the device
+        has nothing queued when the next step sleeps."""
+        rs, eng = replica_set
+        loop, _, steps = self._slow_steps(
+            rs, eng, _prompts(seed=21, n=6, shared=2, tail=4), 1)
+        assert steps >= 6
+        assert loop[("engine.step", "dry")] >= 0.8 * 0.03 * steps, (loop,
+                                                                    steps)
+
+    def test_loop_seconds_add_up_to_the_loops_own_spans(self, replica_set):
+        """Every second of the loop between the registry's zeroing and its
+        end is counted once: under the top-level span it lies in
+        (``engine.step`` holds the engine's own spans) or under "none"."""
+        rs, eng = replica_set
+        loop, span_sum, _ = self._slow_steps(
+            rs, eng, _prompts(seed=22, n=3, shared=10, tail=7), 8)
+        top = ("replica.publish", "replica.lock", "replica.idle", "none")
+        by_top = dict.fromkeys(("engine.step",) + top, 0.0)
+        for (span, _), seconds in loop.items():
+            by_top[span if span in top else "engine.step"] += seconds
+        for name in ("engine.step",) + top[:3]:
+            assert by_top[name] == pytest.approx(span_sum[name], rel=0.01)
+        assert sum(loop.values()) == pytest.approx(
+            sum(span_sum[n] for n in ("engine.step",) + top[:3])
+            + by_top["none"], rel=0.01)
+        # decode steps ran one ahead: the device was busy for part of it
+        assert sum(v for (_, device), v in loop.items() if device == "busy") > 0
+        assert {span for span, _ in loop} >= {"engine.step", "runner.dispatch",
+                                              "runner.launch", "runner.wait"}

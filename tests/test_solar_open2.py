@@ -354,7 +354,7 @@ def test_routing_counters_against_a_hand_count():
     assert 0 < touched <= read("serving_moe_assignments_total", "decode")
     assert (read("serving_moe_max_load_total", "decode")
             <= read("serving_moe_rows_total", "decode"))
-    gauge = snap["serving_state_slots_in_use"]["series"]
+    gauge = snap["serving_active_slots"]["series"]
     assert len(gauge) == 1
 
 
