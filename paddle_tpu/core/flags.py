@@ -80,10 +80,6 @@ define_flag("tpu_matmul_precision", "highest",
             "matmuls true fp32 on the MXU (multi-pass bf16); bf16 inputs are "
             "unaffected, so bf16 training keeps full MXU throughput")
 define_flag("log_level", 0, "VLOG-style verbosity for framework logging")
-define_flag("flash_layout_direct", False,
-            "flash attention reads [B,S,H,D] operands directly (no relayout "
-            "copies) via in-kernel per-head lane slicing; measured slower on "
-            "v5e at GPT-2 shapes, may win at other geometries")
 define_flag("weight_only_use_kernel", True,
             "route weight_only_linear through the Pallas in-kernel-dequant "
             "matmul on TPU no-grad calls; False uses the XLA dequant "

@@ -84,6 +84,14 @@ JIT_EVENTS = REGISTRY.counter(
     "to_static lifecycle events (capture/cache_hit/retrace/"
     "guard_divergence/eager_call/echo_mismatch)", ("event", "fn"))
 
+# training flash kernels (ops/pallas/flash_attention.py), set when a kernel's
+# tiling is fixed at trace time: the newest trace's shares of its tile grid
+FLASH_TILE_SHARE = REGISTRY.gauge(
+    "flash_attention_tile_share",
+    "% of the (Sq/bq) x (Sk/bk) tile grid of the newest traced flash kernel "
+    "that runs (kind=computed) and that takes the causal mask "
+    "(kind=masked)", ("kernel", "kind"))
+
 # serving engine (inference/serving.py); one label per engine instance
 SERVING_TTFT = REGISTRY.histogram(
     "serving_ttft_seconds", "submit-to-first-token latency", ("engine",))

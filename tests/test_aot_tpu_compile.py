@@ -66,29 +66,54 @@ def bf16(*shape):
     return shape, jnp.bfloat16
 
 
+def kernel_calls(text):
+    """Instruction names of the Mosaic kernels in a compiled program."""
+    return re.findall(r"^\s*%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                      text, re.M)
+
+
 class TestFlashAttention:
     def test_gpt2_train_shape_forward_and_backward(self, v5e):
+        """One kernel each, fwd, dq and dk/dv, in the row-block kernels the
+        causal shape takes: the benchmark's roofline counts steps as
+        calls / 3 a layer and finds the kernels by these names."""
+        B, S, H, Dh = GPT2
+        assert fa._pick_rows(jnp.zeros((B * H, S, Dh), jnp.bfloat16))
         text = compile_for(
             SingleDeviceSharding(v5e[0]),
             fwd_bwd(functools.partial(fa.flash_attention_bshd, causal=True)),
             bf16(*GPT2), bf16(*GPT2), bf16(*GPT2))
-        assert text.count("tpu_custom_call") >= 3       # fwd, dq, dk/dv
+        calls = kernel_calls(text)
+        assert len(calls) == 3, calls
+        for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+            assert sum(re.search(name + r"[._]", c) is not None
+                       for c in calls) == 1, (name, calls)
 
-    def test_layout_direct_variant(self, v5e):
-        B, S, H, Dh = GPT2
-        bq, bk, hb = fa._bshd_config(B, S, S, H, Dh, jnp.bfloat16)
-        compile_for(
-            SingleDeviceSharding(v5e[0]),
-            fwd_bwd(lambda q, k, v: fa._flash_bshd(
-                q, k, v, Dh ** -0.5, True, bq, bk, hb)),
-            bf16(*GPT2), bf16(*GPT2), bf16(*GPT2))
-
-    def test_llama_gqa_shape(self, v5e):
-        """The one-chip side of chip_smoke's dp=2 x mp=2 parity step."""
-        compile_for(
+    @pytest.mark.parametrize("B,S,H,Dh,dtype", [
+        (4, 2048, 16, 128, jnp.bfloat16),
+        (16, 1024, 12, 64, jnp.float32),
+    ], ids=["d128-s2048", "gpt2-f32"])
+    def test_row_blocks_compile_where_picked(self, v5e, B, S, H, Dh, dtype):
+        """Off GPT-2's shape, what the VMEM estimate admits compiles too: a
+        wider head over longer rows, and float32 operands."""
+        assert fa._pick_rows(jnp.zeros((B * H, S, Dh), dtype))
+        shape = ((B, S, H, Dh), dtype)
+        text = compile_for(
             SingleDeviceSharding(v5e[0]),
             fwd_bwd(functools.partial(fa.flash_attention_bshd, causal=True)),
-            bf16(4, 128, NH, D), bf16(4, 128, KVH, D), bf16(4, 128, KVH, D))
+            shape, shape, shape)
+        assert len(kernel_calls(text)) == 3
+
+    def test_llama_gqa_shape(self, v5e):
+        """The one-chip side of chip_smoke's dp=2 x mp=2 parity step, and
+        the one-chip Llama training shape: GQA keeps one head a step."""
+        for S in (128, 1024):
+            text = compile_for(
+                SingleDeviceSharding(v5e[0]),
+                fwd_bwd(functools.partial(fa.flash_attention_bshd,
+                                          causal=True)),
+                bf16(4, S, NH, D), bf16(4, S, KVH, D), bf16(4, S, KVH, D))
+            assert len(kernel_calls(text)) == 3
 
     def test_split_operands_are_refused(self, v5e):
         """XLA does not partition a Mosaic kernel, which is why the dispatch

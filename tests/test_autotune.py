@@ -91,7 +91,49 @@ class TestFlashBlocks:
         c2 = _block_candidates(128, 128, 128, jnp.float32)
         assert c2 == [(128, 128)]
 
-    def test_flash_matches_reference_with_tuned_blocks(self):
+    def test_row_candidates_respect_divisibility_and_vmem(self):
+        """Row blocks divide S, head blocks divide B*H, and each fits the
+        VMEM estimate; a sequence shorter than every block has none."""
+        from paddle_tpu.ops.pallas.flash_attention import (
+            _row_candidates, _rows_vmem, _VMEM_BUDGET)
+        import jax.numpy as jnp
+        c = _row_candidates(192, 1024, 64, jnp.bfloat16)
+        assert c and any(hb > 1 for _, hb in c)
+        assert all(1024 % b == 0 and 192 % hb == 0
+                   and _rows_vmem(1024, b, 64, hb, 2) <= _VMEM_BUDGET
+                   for b, hb in c)
+        assert all(hb == 1 for _, hb in _row_candidates(
+            3, 1024, 64, jnp.bfloat16))
+        assert _row_candidates(2, 64, 64, jnp.bfloat16) == []
+
+    def test_autotune_times_row_blocks(self, monkeypatch):
+        """With the flag on, the tuner times the (b, hb) candidates of the
+        row-block kernels and caches the pick."""
+        from paddle_tpu.ops.pallas import flash_attention as fa
+        from paddle_tpu.nn.functional.attention import _sdpa_ref
+        import jax.numpy as jnp
+        monkeypatch.setattr(fa, "_ROW_BLOCKS", [(128, 2), (256, 1)])
+        at.clear()
+        paddle.set_flags({"FLAGS_use_autotune": True})
+        try:
+            q = jnp.asarray(rng.rand(1, 256, 2, 64).astype(np.float32))
+            out = fa.flash_attention_bshd(q, q, q, causal=True)
+            np.testing.assert_allclose(
+                np.asarray(out), np.asarray(_sdpa_ref(q, q, q, causal=True)),
+                atol=2e-5)
+            cached = at.lookup(at.cache_key("flash_rows", 2, 256, 64,
+                                            q.dtype))
+            assert tuple(cached) in [(128, 2), (256, 1)]
+        finally:
+            paddle.set_flags({"FLAGS_use_autotune": False})
+            at.clear()
+
+    @pytest.mark.parametrize("Hk,causal", [(1, True), (2, False)],
+                             ids=["gqa-causal", "mha-full"])
+    def test_flash_matches_reference_with_tuned_blocks(self, Hk, causal):
+        """The grid kernels' tuner: causal GQA and non-causal calls (causal
+        self-attention takes the row-block kernels, tuned under their own
+        key)."""
         at.clear()
         paddle.set_flags({"FLAGS_use_autotune": True})
         try:
@@ -101,10 +143,10 @@ class TestFlashBlocks:
             import jax.numpy as jnp
             B, S, H, D = 1, 256, 2, 128
             q = jnp.asarray(rng.rand(B, S, H, D).astype(np.float32))
-            k = jnp.asarray(rng.rand(B, S, H, D).astype(np.float32))
-            v = jnp.asarray(rng.rand(B, S, H, D).astype(np.float32))
-            out = flash_attention_bshd(q, k, v, causal=True)
-            ref = _sdpa_ref(q, k, v, causal=True)
+            k = jnp.asarray(rng.rand(B, S, Hk, D).astype(np.float32))
+            v = jnp.asarray(rng.rand(B, S, Hk, D).astype(np.float32))
+            out = flash_attention_bshd(q, k, v, causal=causal)
+            ref = _sdpa_ref(q, k, v, causal=causal)
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                        rtol=2e-2, atol=2e-3)
             # a tuned entry landed in the cache
@@ -122,7 +164,9 @@ class TestFlashBlocks:
 
 
 class TestWarmAutotune:
-    def test_warm_autotune_populates_cache(self):
+    @pytest.mark.parametrize("Hk,causal", [(1, True), (2, False)],
+                             ids=["gqa-causal", "mha-full"])
+    def test_warm_autotune_populates_cache(self, Hk, causal):
         # the dispatch wrappers call warm_autotune with concrete arrays on
         # the TPU path (_use_pallas gates it off on CPU, so drive directly);
         # traced kernel calls then hit this cache by static-shape key
@@ -132,17 +176,21 @@ class TestWarmAutotune:
             import jax.numpy as jnp
             from paddle_tpu.ops.pallas.flash_attention import warm_autotune
             q = jnp.asarray(rng.rand(1, 256, 2, 128).astype(np.float32))
-            warm_autotune(q, q, q, causal=True)
+            k = jnp.asarray(rng.rand(1, 256, Hk, 128).astype(np.float32))
+            warm_autotune(q, k, k, causal=causal)
             assert any(k.startswith("flash_fwd|2|256|256|128")
                        for k in at._cache), list(at._cache)
             # a traced call now uses the cached pick without tuning
             import jax
             from paddle_tpu.ops.pallas import flash_attention as fa
             q3 = jnp.moveaxis(q, 2, 1).reshape(2, 256, 128)
+            k3 = jnp.moveaxis(k, 2, 1).reshape(Hk, 256, 128)
             cached = tuple(at.lookup(at.cache_key(
-                "flash_fwd", 2, 256, 256, 128, q3.dtype, True)))
+                "flash_fwd", 2, 256, 256, 128, q3.dtype, causal)))
             got = jax.eval_shape(
-                lambda a: jnp.asarray(fa._pick_blocks(a, a, a, True)), q3)
+                lambda a, b: jnp.asarray(fa._pick_blocks(a, b, b, causal)),
+                q3, k3)
+            assert got.shape == (2,)
             assert cached in fa._block_candidates(256, 256, 128, q3.dtype)
         finally:
             paddle.set_flags({"FLAGS_use_autotune": False})

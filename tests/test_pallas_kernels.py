@@ -5,6 +5,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
 from paddle_tpu.nn.functional.attention import _sdpa_ref
 
@@ -126,37 +127,6 @@ def test_supported_predicate():
     assert not supported((1, 256, 8, 64), (1, 256, 3, 64))  # 8 % 3 != 0
 
 
-def test_layout_direct_bshd_path_matches_reference():
-    """FLAGS_flash_layout_direct engages the [B,S,H,D] lane-sliced kernels;
-    numerics must match the default [B*H,S,D] path (fwd + grads)."""
-    import paddle_tpu as pt
-    rng = np.random.RandomState(7)
-    B, S, H, D = 2, 128, 4, 64
-    q = jnp.asarray(rng.randn(B, S, H, D).astype(np.float32) * 0.3)
-    k = jnp.asarray(rng.randn(B, S, H, D).astype(np.float32) * 0.3)
-    v = jnp.asarray(rng.randn(B, S, H, D).astype(np.float32) * 0.3)
-
-    def loss(qq, kk, vv):
-        return jnp.sum(flash_attention_bshd(qq, kk, vv, causal=True)
-                       .astype(jnp.float32) ** 2)
-
-    o_ref = flash_attention_bshd(q, k, v, causal=True)
-    g_ref = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    pt.set_flags({"FLAGS_flash_layout_direct": True})
-    try:
-        from paddle_tpu.ops.pallas.flash_attention import _bshd_config
-        assert _bshd_config(B, S, S, H, D, q.dtype) is not None
-        o_new = flash_attention_bshd(q, k, v, causal=True)
-        g_new = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    finally:
-        pt.set_flags({"FLAGS_flash_layout_direct": False})
-    np.testing.assert_allclose(np.asarray(o_new), np.asarray(o_ref),
-                               rtol=1e-4, atol=1e-4)
-    for a, b in zip(g_new, g_ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-3, atol=1e-3)
-
-
 def test_flash_llama3_geometry_fwd_bwd():
     """The r5 bench's north-star head shape: head_dim=128 + GQA 4:1 (the MXU
     contraction-filling configuration) — forward and gradients vs reference,
@@ -180,6 +150,138 @@ def test_flash_llama3_geometry_fwd_bwd():
     g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-3)
+
+
+def _flash_tiled(q, k, v, causal, kind, cfg):
+    """flash_attention_bshd's [B*H, S, D] kernels at a given tiling: the
+    row-block kernels at (b, hb) or the grid kernels at (bq, bk)."""
+    B, S, H, D = q.shape
+
+    def to3(x):
+        return jnp.moveaxis(x, 2, 1).reshape(-1, S, D)
+
+    if kind == "rows":
+        o3 = fa._rows3(to3(q), to3(k), to3(v), D ** -0.5, *cfg)
+    else:
+        o3 = fa._flash3(to3(q), to3(k), to3(v), D ** -0.5, causal, H,
+                        k.shape[2], *cfg)
+    return jnp.moveaxis(o3.reshape(B, H, S, D), 1, 2)
+
+
+@pytest.mark.parametrize("B,H,Hk,kind,cfg,causal", [
+    (2, 2, 2, "rows", (128, 2), True),   # a block of heads across two rows
+    (1, 4, 4, "rows", (128, 4), True),
+    (1, 4, 4, "rows", (256, 2), True),
+    (1, 2, 2, "rows", (512, 1), True),   # one block: the diagonal alone
+    (1, 4, 4, "grid", (128, 256), True),
+    (1, 4, 2, "grid", (128, 128), True),  # GQA
+    (1, 4, 2, "grid", (128, 128), False),
+    (1, 4, 4, None, None, True),         # as flash_attention_bshd picks
+    (1, 4, 4, None, None, False),
+    (1, 4, 2, None, None, True),
+], ids=["rows-128-hb2", "rows-128-hb4", "rows-256-hb2", "rows-512",
+        "grid-mha-128x256", "grid-gqa-128", "grid-gqa-128-full",
+        "picked-mha", "picked-mha-full", "picked-gqa"])
+def test_flash_tilings_match_reference(B, H, Hk, kind, cfg, causal):
+    """At S = 512 in 128-row blocks the causal grid holds skipped, interior
+    and diagonal tiles at once: forward and all three gradients."""
+    S, D = 512, 64
+    r = np.random.RandomState(11)
+    q, k, v = (jnp.asarray(r.randn(B, S, h, D).astype(np.float32) * 0.5)
+               for h in (H, Hk, Hk))
+
+    def flash(q, k, v):
+        if kind is None:
+            return flash_attention_bshd(q, k, v, causal=causal)
+        return _flash_tiled(q, k, v, causal, kind, cfg)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(_sdpa_ref(q, k, v, causal=causal)),
+                               atol=2e-5)
+    g1 = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
+    g2 = jax.grad(lambda *a: jnp.sum(_sdpa_ref(*a, causal=causal) ** 2),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+
+
+def test_causal_tiling_rule_at_gpt2_shape():
+    """GPT-2's training shape takes the row-block kernels in blocks smaller
+    than S, so the mask skips; GQA and non-causal calls keep the grid
+    kernels' blocks."""
+    B, S, H, D = 16, 1024, 12, 64
+    q3 = jnp.zeros((B * H, S, D), jnp.bfloat16)
+    b, hb = fa._pick_rows(q3)
+    assert b < S and S % b == 0 and (B * H) % hb == 0
+    assert fa._rows_vmem(S, b, D, hb, 2) <= fa._VMEM_BUDGET
+    assert fa._self_attention(q3, q3)
+    assert fa._pick_blocks(q3, q3, q3, False) == (512, 1024)
+    kv3 = jnp.zeros((B * H // 4, S, D), jnp.bfloat16)
+    assert not fa._self_attention(q3, kv3)
+    assert fa._pick_blocks(q3, kv3, kv3, True) == (512, 1024)
+    # the one-chip Llama shape: 32 q heads over 8 kv heads of 128
+    q3, kv3 = (jnp.zeros((h, S, 128), jnp.bfloat16) for h in (32, 8))
+    assert fa._pick_blocks(q3, kv3, kv3, True) == (512, 512)
+
+
+def test_tile_share_gauge():
+    """flash_attention_tile_share reads the tiling of the newest trace: the
+    grid kernels' (512, 1024) blocks compute the whole square masked at
+    S = 1024; GPT-2's causal shape skips the upper triangle."""
+    from paddle_tpu import observability as obs
+    B, S, H, D = 16, 1024, 12, 64
+    scale = 0.123       # a static argument no other test traces with
+
+    def shares():
+        series = obs.snapshot(prefix="flash_attention_tile_share")[
+            "flash_attention_tile_share"]["series"]
+        return {(s["labels"]["kernel"], s["labels"]["kind"]): s["value"]
+                for s in series}
+
+    q3 = jax.ShapeDtypeStruct((B * H, S, D), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16)
+    obs.reset()
+    obs.enable()
+    try:
+        jax.eval_shape(lambda *a: fa._flash_fwd(*a, scale, True, H, H,
+                                                512, 1024), q3, q3, q3)
+        got = shares()
+        assert got[("flash_fwd", "computed")] == 100.0
+        assert got[("flash_fwd", "masked")] == 100.0
+        jax.eval_shape(jax.grad(
+            lambda *a: jnp.sum(flash_attention_bshd(*a, causal=True,
+                                                    scale=scale)
+                               .astype(jnp.float32)), argnums=(0, 1, 2)),
+            q, q, q)
+        got = shares()
+        b, _ = fa._pick_rows(jnp.zeros((B * H, S, D), jnp.bfloat16))
+        for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+            read = got[(kernel, "computed")], got[(kernel, "masked")]
+            assert read == fa._tile_shares(S, S, b, b, True)
+            assert read[0] < 100.0 and read[1] < 100.0
+    finally:
+        obs.disable()
+        obs.reset()
+    assert fa._tile_shares(S, S, 512, 512, True) == (75.0, 50.0)
+    assert fa._tile_shares(S, S, 128, 128, True) == (56.25, 12.5)
+    assert fa._tile_shares(S, S, 256, 256, True) == (62.5, 25.0)
+    assert fa._tile_shares(S, S, 128, 128, False) == (100.0, 0.0)
+
+
+def test_eager_calls_trace_the_row_kernels_once(monkeypatch):
+    """Op-by-op (a training step's capture passes) a second causal call
+    with the same shapes reuses the jitted row-block kernels: nothing is
+    traced or lowered again."""
+    traced = []
+    monkeypatch.setattr(fa, "_record_tiles",
+                        lambda kernels, *a: traced.append(kernels))
+    q = jnp.asarray(rng.rand(1, 256, 2, 64).astype(np.float32))
+    for _ in range(2):
+        out, vjp = jax.vjp(lambda *a: flash_attention_bshd(
+            *a, causal=True, scale=0.321), q, q, q)
+        vjp(out)
+    assert traced == [("flash_fwd",), ("flash_bwd_dq", "flash_bwd_dkv")]
 
 
 def test_llama3_shaped_train_step_scans():
