@@ -92,6 +92,21 @@ def refuse_recurrent(plan, what, why):
             f"layers: {why}")
 
 
+_NO_TAIL_HANDOFF = ("the hand-off carries pages and no tail, and the "
+                    "pages alone do not continue a request")
+
+
+def refuse_tails(plan, what, why):
+    """A model whose attention layers keep a tail a slot (what the next
+    token needs of the tokens before it) has pages that do not continue a
+    request alone: a page boundary holds no tail. What cannot be right yet
+    refuses by name at construction, as for recurrent state."""
+    if any(plan.kinds[name].cache == "pages" for name in plan.tailed):
+        raise NotImplementedError(
+            f"{what} is not available for a model whose attention layers "
+            f"keep a tail a slot: {why}")
+
+
 def refuse_blocks(plan, what, why):
     """A model that generates by blocks yields no token at a prompt's last
     position and several a dispatch: what assumes otherwise and is not
@@ -118,7 +133,13 @@ class LLMEngine(_SpecOrchestration):
     block-causal mask; it generates by blocks: a decode dispatch is one
     block of ``block_length`` positions a sequence, unmasked over several
     forward passes, and a request's first token arrives with its first
-    block). The speculative-decode orchestration comes from
+    block), ``ZAYAForCausalLM`` (paged KV of compressed convolutional
+    attention whose layers also keep a TAIL a slot - the last token's
+    latent q and k, their first convolution's output and its shifted value
+    - and a router state carried from layer to layer beside the residual;
+    its head is the embedding's transpose; ``prefix_cache``,
+    ``spec_decode``, the host tier and the page hand-off refuse it by
+    name). The speculative-decode orchestration comes from
     :class:`~.spec._SpecOrchestration`."""
 
     _engine_seq = 0   # observability label: one series set per engine
@@ -130,7 +151,7 @@ class LLMEngine(_SpecOrchestration):
                  prefix_cache=False, spec_decode=None, max_waiting=None,
                  shed_min_free_ratio=0.0, default_deadline=None,
                  step_retry=None, debug_refcount_audit=False,
-                 host_cache_bytes=None):
+                 host_cache_bytes=None, chunk_attention="rows"):
         """page_pool: usable KV pages (the HBM budget). Defaults to the
         worst case (max_batch * ceil(max_len/page)); set it SMALLER to
         oversubscribe — on-demand growth means slots only claim what they
@@ -164,6 +185,15 @@ class LLMEngine(_SpecOrchestration):
         picks the power-of-two block where the dispatch latency costs
         <= ~25% of device time (re-estimated as timing
         samples accumulate, capped at decode_block_max).
+
+        chunk_attention: how a prefill chunk's C positions meet the pages.
+        "rows": each position is a row of the one-query kernel and walks
+        its own context, so a chunk at context T reads about C x T tokens
+        of K and V. "sequence": the chunk is ONE sequence of C positions
+        through the multi-query kernel, which walks the context once for
+        all of them (row i still sees start + i + 1 tokens); its VMEM holds
+        heads x C score rows, which suits few query heads (ZAYA1's 8 at
+        chunks of 256). Not for a model that generates by blocks.
 
         kv_cache_dtype: "auto" stores pages in the weight dtype; "int8"
         quantizes K/V pages per-(token, kv-head) with f32 scales (reference:
@@ -239,6 +269,18 @@ class LLMEngine(_SpecOrchestration):
                 refuse_recurrent(plan, what, why)
         for what, asked, why in (
                 ("prefix_cache", prefix_cache,
+                 "a page boundary holds no tail, and a cached prefix skips "
+                 "the prefill that leaves one"),
+                ("spec_decode", spec_decode is not None,
+                 "a rejected draft has already moved the tail on, and "
+                 "there is no rollback of it"),
+                ("host_cache_bytes", host_cache_bytes is not None,
+                 "the spill tier keeps pages by prefix chain and carries "
+                 "no tail")):
+            if asked:
+                refuse_tails(plan, what, why)
+        for what, asked, why in (
+                ("prefix_cache", prefix_cache,
                  "admission re-prefills a cached prompt's last token for "
                  "the sample it yields, and a block model's prefill yields "
                  "none"),
@@ -275,7 +317,8 @@ class LLMEngine(_SpecOrchestration):
             model, mesh=mesh, mp_axis=mp_axis, pp_axis=pp_axis,
             max_batch=max_batch, page_size=page_size,
             prefill_chunk=prefill_chunk, n_pages=self.n_pages,
-            use_kernel=use_kernel, kv_cache_dtype=kv_cache_dtype)
+            use_kernel=use_kernel, kv_cache_dtype=kv_cache_dtype,
+            chunk_attention=chunk_attention)
         self.pool = PagePool(self.n_pages, prefix_cache=self.prefix_cache,
                              metrics=self._m)
         # host-RAM spill tier (HBM -> host RAM -> recompute hierarchy)
@@ -1011,8 +1054,8 @@ class LLMEngine(_SpecOrchestration):
         return self.runner.kv_bytes_per_page()
 
     def state_bytes_per_slot(self):
-        """HBM bytes of recurrent state a slot holds across all layers,
-        whatever its length (0 for a model that keeps none); with
+        """HBM bytes of recurrent state and tails a slot holds across all
+        layers, whatever its length (0 for a model that keeps neither); with
         :meth:`kv_bytes_per_page` the whole of what a request costs."""
         return self.runner.state_bytes_per_slot()
 
@@ -1116,6 +1159,7 @@ class LLMEngine(_SpecOrchestration):
         [L, n, page, ...] numpy arrays}``, or None when even the first key
         misses everywhere — the puller then recomputes."""
         refuse_recurrent(self.runner.plan, "export_pages", _NO_STATE_HANDOFF)
+        refuse_tails(self.runner.plan, "export_pages", _NO_TAIL_HANDOFF)
         refuse_blocks(self.runner.plan, "export_pages", _NO_BLOCK_HANDOFF)
         self._drain()
         host = self.pool.host
@@ -1158,6 +1202,7 @@ class LLMEngine(_SpecOrchestration):
         ordinary prefix hit.  Any failure stops the splice mid-chain — the
         un-spliced tail simply recomputes.  Returns pages spliced."""
         refuse_recurrent(self.runner.plan, "import_pages", _NO_STATE_HANDOFF)
+        refuse_tails(self.runner.plan, "import_pages", _NO_TAIL_HANDOFF)
         refuse_blocks(self.runner.plan, "import_pages", _NO_BLOCK_HANDOFF)
         if not payload:
             return 0

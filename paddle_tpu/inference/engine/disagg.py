@@ -69,8 +69,8 @@ from ... import observability as _obs
 from ...observability import flight as _flight
 from ...core.retry import RetryError, RetryPolicy, retry_call
 from ...testing.faults import FAULTS as _faults
-from .core import (_NO_BLOCK_HANDOFF, _NO_STATE_HANDOFF, LLMEngine,
-                   refuse_blocks, refuse_recurrent)
+from .core import (_NO_BLOCK_HANDOFF, _NO_STATE_HANDOFF, _NO_TAIL_HANDOFF,
+                   LLMEngine, refuse_blocks, refuse_recurrent, refuse_tails)
 from .metrics import _PoolMetrics
 from .request import Request, RequestStatus
 
@@ -235,6 +235,7 @@ class DisaggEngine:
             plans.append(model.serving_plan())
         for plan in plans:
             refuse_recurrent(plan, "DisaggEngine", _NO_STATE_HANDOFF)
+            refuse_tails(plan, "DisaggEngine", _NO_TAIL_HANDOFF)
             refuse_blocks(plan, "DisaggEngine", _NO_BLOCK_HANDOFF)
         self.max_batch = max_batch
         self.max_len = max_len

@@ -51,12 +51,15 @@ TPU-native design (carried over from the monolithic serving engine):
   and one around the period. What is here is the engine's: the pools BY
   KIND OF CACHE, all carried through every loop and donated -
   pages ``[L_pages, n_pages, page, KVH, D]`` for the layers that attend (a
-  layer's own index among them shifts the tables), and for the layers
-  with a recurrence a state pool ``[L_state, max_batch + 1, heads, dk,
-  dv]`` float32 and the tails of their convolutions ``[L_state, max_batch
-  + 1, taps - 1, channels]``, a row a slot and one more that idle rows
-  write to - the write of a step's rows into them, which attention or
-  recurrence reads them, and the sums of the layers' routing counts.
+  layer's own index among them shifts the tables), for the layers with a
+  recurrence a state pool ``[L_state, max_batch + 1, heads, dk, dv]``
+  float32, and for each kind whose next token needs something of the
+  tokens before it (a convolution's last inputs, a shifted projection) a
+  pool of tails ``[L_kind, max_batch + 1, rows, channels]``, a row a slot
+  and one more that idle rows write to - the write of a step's rows into
+  them, which attention or recurrence reads them, and the sums of the
+  layers' routing counts. A plan may carry a stream beside the residual
+  from layer to layer (``plan.carry``) and tie its head to the embedding.
 """
 from __future__ import annotations
 
@@ -204,15 +207,22 @@ class _DecodeTokens:
 class ModelRunner:
     """Weights + paged KV + jitted forwards over one mesh (slice)."""
 
+    chunk_attention = "rows"
+
     def __init__(self, model, mesh=None, mp_axis="mp", pp_axis="pp",
                  max_batch=4, page_size=16, prefill_chunk=32, n_pages=None,
-                 use_kernel=None, kv_cache_dtype="auto"):
+                 use_kernel=None, kv_cache_dtype="auto",
+                 chunk_attention="rows"):
         cfg = model.config
         self.cfg = cfg
         self.mesh = mesh
         self.max_batch = int(max_batch)
         self.page = int(page_size)
         self.chunk = int(prefill_chunk)
+        if chunk_attention not in ("rows", "sequence"):
+            raise ValueError(f"chunk_attention={chunk_attention!r}: "
+                             f"'rows' or 'sequence'")
+        self.chunk_attention = chunk_attention
         self.n_pages = int(n_pages)
         self.trash_page = self.n_pages - 1
         if use_kernel is None:
@@ -249,19 +259,33 @@ class ModelRunner:
             self.cache = (pool(page_dtype, D), pool(page_dtype, D))
             if self.kv_quant:
                 self.cache += (pool(jnp.float32), pool(jnp.float32))
+        # a row a slot, and one that idle rows write to; born after the
+        # weights are placed, so construction never holds them beside a
+        # second copy of anything
+        slots = self.max_batch + 1
         if plan.recurrent:
-            # a row a slot, and one that idle rows write to; born after the
-            # weights are placed, so construction never holds them beside
-            # a second copy of anything
-            rows = (plan.layers_of("state"), self.max_batch + 1)
             with _obs.trace_span("engine.build.state_pool"):
-                self.cache += (
-                    jnp.zeros(rows + (plan.state_heads, plan.state_dk,
-                                      plan.state_dv), jnp.float32,
-                              device=cache_spec),
-                    jnp.zeros(rows + (plan.conv_tail, plan.conv_channels),
-                              dtype, device=cache_spec))
+                self.cache += (jnp.zeros(
+                    (plan.layers_of("state"), slots, plan.state_heads,
+                     plan.state_dk, plan.state_dv), jnp.float32,
+                    device=cache_spec),)
+        if plan.tailed:
+            with _obs.trace_span("engine.build.tail_pool"):
+                self.cache += tuple(
+                    jnp.zeros((plan.kind_layers(name), slots)
+                              + plan.kinds[name].tail, dtype,
+                              device=cache_spec)
+                    for name in plan.tailed)
         if plan.block:
+            if plan.per_slot:
+                raise NotImplementedError(
+                    "a model that generates by blocks keeps nothing a slot "
+                    "beside its pages")
+            if chunk_attention != "rows":
+                raise NotImplementedError(
+                    "a block model's chunk rows see to the end of their "
+                    "block, not one position further each: chunk_attention "
+                    "'rows' only")
             if self.page % plan.block or self.chunk % plan.block:
                 raise ValueError(
                     f"a model that generates by blocks of {plan.block} needs "
@@ -309,9 +333,30 @@ class ModelRunner:
     @property
     def _n_page_pools(self):
         """The page pools lead ``self.cache``: K and V, and the two scale
-        pools of int8 pages; a recurrent model's state pool and tails
-        follow, then the routing counts of a model that has them."""
+        pools of int8 pages; a recurrent model's state pool follows, then a
+        pool of tails for each kind that keeps one (``plan.tailed``), then
+        the block counts and the routing counts of a model that has them."""
         return 4 if self.kv_quant else 2
+
+    def _tail_at(self, name):
+        """Where kind ``name``'s pool of tails lies in ``self.cache``."""
+        return (self._n_page_pools + int(self.plan.recurrent)
+                + self.plan.tailed.index(name))
+
+    @staticmethod
+    def _slot_tail(pool, l, r, kind, fresh=None):
+        """Layer ``l``'s tails of this dispatch's slots, out of ``pool``,
+        and how to write them back: a DECODE row's slot is ``r.slots`` (an
+        idle row names the idle slot), gathered and scattered (a few MB);
+        a PREFILL chunk is ONE slot's, ``r.slot``, and where it starts the
+        prompt (``fresh``: ``r.start == 0``) its tail is zeros whatever the
+        slot held - so admission, and re-admission after a preemption,
+        need no pass of their own."""
+        if kind == "decode":
+            return (pool[l, r.slots],
+                    lambda tail: pool.at[l, r.slots].set(tail))
+        return (jnp.where(fresh, 0, pool[l, r.slot]),
+                lambda tail: pool.at[l, r.slot].set(tail))
 
     def _count(self, cache, counts, kind):
         """Add one layer's routing counts (``None``: the layer has none) to
@@ -334,10 +379,10 @@ class ModelRunner:
             return lambda carry, wl: layer(carry, {**wl, **whole})
         return {name: with_whole(lk, (
             self._pages_layer if lk.cache == "pages"
-            else self._state_layer)(lk, r, kind))
+            else self._state_layer)(name, lk, r, kind))
             for name, lk in self.plan.kinds.items()}
 
-    def _pages_layer(self, lk, r, kind):
+    def _pages_layer(self, name, lk, r, kind):
         """A layer that attends. With ``r.mq = (B, Q)`` the flat rows
         are B sequences x Q consecutive query positions and attention goes
         through the multi-query kernel (tables [B, S]; ctx [B] is row 0's
@@ -357,7 +402,16 @@ class ModelRunner:
 
         ``r.horizon == "block"`` (a block program's rows): the Q rows of a
         sequence are one block and each sees ``ctx + Q - 1`` tokens, the
-        block's own, just written, included."""
+        block's own, just written, included.
+
+        A kind that keeps a TAIL (``lk.tail``) gets its slot's in ``first``
+        and gives back the next (:meth:`_slot_tail`); a decode row is one
+        position after it, a prefill chunk ``r.n_valid`` of them."""
+        if lk.tail and kind == "verify":
+            raise NotImplementedError(
+                "speculative verification over a layer's tail: rejected "
+                "drafts would need the tail rolled back")
+        tat = self._tail_at(name) if lk.tail else None
         nh, D = self.plan.nh, self.plan.D
         use_kernel = self.use_kernel
         quant = self.kv_quant
@@ -367,12 +421,24 @@ class ModelRunner:
 
         def layer(carry, wl):
             from ...ops.pallas.paged_attention import (
-                paged_attention, paged_attention_blocks,
+                paged_attention, paged_attention_blocks, paged_attention_chunk,
                 paged_attention_multiquery, paged_attention_multiquery_ref,
                 paged_attention_ref, quantize_kv)
             x, cache = carry
             l = wl["l"]
-            q, k, v = lk.first(wl, x, pos)
+            if tat is None:
+                q, k, v = lk.first(wl, x, pos)
+            elif kind == "decode":
+                one = jax.tree_util.tree_map(lambda a: a[:, None], x)
+                tail, put = self._slot_tail(cache[tat], l, r, kind)
+                (q, k, v), tail = lk.first(wl, one, pos[:, None], tail, None)
+                q, k, v = (a[:, 0] for a in (q, k, v))
+                cache = cache[:tat] + (put(tail),) + cache[tat + 1:]
+            else:
+                tail, put = self._slot_tail(cache[tat], l, r, kind,
+                                            r.start == 0)
+                (q, k, v), tail = lk.first(wl, x, pos, tail, r.n_valid)
+                cache = cache[:tat] + (put(tail),) + cache[tat + 1:]
             if mq is None:
                 attn = paged_attention if use_kernel else paged_attention_ref
             else:
@@ -387,6 +453,8 @@ class ModelRunner:
                         base = paged_attention_blocks
                     else:
                         hz = {"horizon": "block"}
+                elif kind == "prefill" and use_kernel:
+                    base = paged_attention_chunk
 
                 def attn(qx, kp, vp, tb, cl, **kw):
                     out = base(qx.reshape(Bq, Q, nh, D), kp, vp, tb, cl,
@@ -413,53 +481,55 @@ class ModelRunner:
 
         return layer
 
-    def _state_layer(self, lk, r, kind):
-        """A layer with a recurrence: its state ``[heads, dk, dv]`` and the
-        tail of its convolutions live in the two pools after the pages, at
-        ``(l, slot)``; ``l`` is the layer's index among its like.
+    def _state_layer(self, name, lk, r, kind):
+        """A layer with a recurrence: its state ``[heads, dk, dv]`` lives in
+        the pool after the pages and the tail of its convolutions in its
+        kind's pool of tails, both at ``(l, slot)``; ``l`` is the layer's
+        index among its like.
 
         A DECODE row is its slot's next token (``r.slots [B]``; an idle row
-        names the idle slot): the tails are gathered and scattered back (a
-        few MB), the states are not - ``kda_step`` updates the rows it is
-        told in place, in the pool flattened to ``[L * slots, ...]``. A
-        PREFILL chunk is ``r.n_valid`` consecutive positions of ONE slot:
-        its state and tail are sliced out, run through the chunk and
-        written back, and where the chunk starts the prompt (``r.start ==
-        0``) both begin at zero whatever the slot held - so admission,
-        and re-admission after a preemption, need no pass of their own.
-        The chunk's padding rows leave the state as it was."""
+        names the idle slot): the tails go as :meth:`_slot_tail` says, the
+        states are not gathered - ``kda_step`` updates the rows it is told
+        in place, in the pool flattened to ``[L * slots, ...]``. A PREFILL
+        chunk is ``r.n_valid`` consecutive positions of ONE slot: its state
+        is sliced out, run through the chunk and written back, and where
+        the chunk starts the prompt (``r.start == 0``) it begins at zero,
+        as the tail does. The chunk's padding rows leave the state as it
+        was."""
         from ...ops.pallas.kda import kda_recurrence, kda_step, kda_step_ref
         if kind == "verify":
             raise NotImplementedError(
                 "speculative verification over recurrent state: rejected "
                 "drafts would need the state rolled back")
-        at = self._n_page_pools
+        at, tat = self._n_page_pools, self._tail_at(name)
         step = kda_step if self.use_kernel else kda_step_ref
 
         def layer(carry, wl):
             x, cache = carry
             l = wl["l"]
-            state, conv = cache[at], cache[at + 1]
+            state = cache[at]
             if kind == "decode":
-                (q, k, v, g, beta), tails = lk.first(
-                    wl, x[:, None], conv[l, r.slots], None)
-                conv = conv.at[l, r.slots].set(tails)
+                one = x[:, None]
+                tail, put = self._slot_tail(cache[tat], l, r, kind)
+                (q, k, v, g, beta), tail = lk.first(wl, one, tail, None)
+                conv = put(tail)
                 flat = state.reshape((-1,) + state.shape[2:])
                 o, flat = step(flat, l * state.shape[1] + r.slots, q[:, 0],
                                k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
                 state = flat.reshape(state.shape)
             else:
                 fresh = r.start == 0
-                (q, k, v, g, beta), tail = lk.first(
-                    wl, x, jnp.where(fresh, 0, conv[l, r.slot]), r.n_valid)
+                tail, put = self._slot_tail(cache[tat], l, r, kind, fresh)
+                (q, k, v, g, beta), tail = lk.first(wl, x, tail, r.n_valid)
                 live = r.live
                 o, S = kda_recurrence(
                     jnp.where(fresh, 0.0, state[l, r.slot]), q, k, v,
                     jnp.where(live[:, None, None], g, 0.0),
                     jnp.where(live[:, None], beta, 0.0))
                 state = state.at[l, r.slot].set(S)
-                conv = conv.at[l, r.slot].set(tail)
-            cache = cache[:at] + (state, conv) + cache[at + 2:]
+                conv = put(tail)
+            cache = cache[:at] + (state,) + cache[at + 1:]
+            cache = cache[:tat] + (conv,) + cache[tat + 1:]
             x, counts = lk.second(wl, x, o, r.live)
             return (x, self._count(cache, counts, kind)), None
 
@@ -472,8 +542,11 @@ class ModelRunner:
         the periods around them (none where the leaves have no period
         axis). Only weights and indices are scanned; the pools are carried
         and come back updated in place (see :meth:`_pages_layer` for the
-        copies that avoids)."""
+        copies that avoids). A plan's ``carry`` rides beside the residual
+        from its zero start and is dropped after the last layer."""
         plan = self.plan
+        if plan.carry is not None:
+            x = (x, plan.carry(x))
 
         def period(carry, Wp, p):
             for name, n in plan.period:
@@ -485,14 +558,26 @@ class ModelRunner:
             return carry
 
         if plan.periods is None:
-            return period((x, cache), W, None)
-        leaves = {k: W[k] for name, _ in plan.period
-                  for k in plan.kinds[name].keys
-                  if k not in plan.kinds[name].whole}
-        carry, _ = jax.lax.scan(
-            lambda carry, wp: (period(carry, *wp), None), (x, cache),
-            (leaves, jnp.arange(plan.periods, dtype=jnp.int32)))
-        return carry
+            x, cache = period((x, cache), W, None)
+        else:
+            leaves = {k: W[k] for name, _ in plan.period
+                      for k in plan.kinds[name].keys
+                      if k not in plan.kinds[name].whole}
+            (x, cache), _ = jax.lax.scan(
+                lambda carry, wp: (period(carry, *wp), None), (x, cache),
+                (leaves, jnp.arange(plan.periods, dtype=jnp.int32)))
+        return (x[0] if plan.carry is not None else x), cache
+
+    def _logits(self, W, h):
+        """float32 logits of the final norm's rows ``h [..., H]`` (in the
+        weights' dtype): times the head, or the embedding's transpose where
+        the plan ties them - read where it lies, in its own dtype, and
+        accumulated in float32, so that no copy of it is made (an upcast
+        of the whole embedding would be one, 2 GB at 262,144 x 2,048)."""
+        if self.plan.tied_head:
+            return jnp.einsum("...h,vh->...v", h, W["embed"],
+                              preferred_element_type=jnp.float32)
+        return h.astype(jnp.float32) @ W["head"].astype(jnp.float32)
 
     # ------------------------------------------------------------- programs
     def _build_decode(self, K):
@@ -545,15 +630,13 @@ class ModelRunner:
                 rows = types.SimpleNamespace(
                     page_idx=page_idx, within=within, tables=tables, ctx=ctx,
                     pos=pos, mq=None, live=active)
-                if self.plan.recurrent:     # idle rows: the idle slot
+                if self.plan.per_slot:      # idle rows: the idle slot
                     B = tokens.shape[0]
                     rows.slots = jnp.where(
                         active > 0, jnp.arange(B, dtype=jnp.int32), B)
                 x, cache = self._run_layers(
                     W, cache, x, self._layer_fns(W, rows, "decode"))
-                h = rms_norm(x, W["norm"], eps)
-                logits = h.astype(jnp.float32) @ W["head"].astype(
-                    jnp.float32)
+                logits = self._logits(W, rms_norm(x, W["norm"], eps))
                 nxt = _sample_rows(logits, greedy, temp, topp, topk,
                                    seeds + i * fold)
                 tokens = jnp.where(active > 0, nxt, tokens)
@@ -647,9 +730,7 @@ class ModelRunner:
                 toks, masked, steps, cache = carry
                 x, cache = forward(
                     cache, jnp.where(masked | cut, plan.mask_token, toks))
-                h = rms_norm(x, W["norm"], eps)
-                logits = h.astype(jnp.float32) @ W["head"].astype(
-                    jnp.float32)
+                logits = self._logits(W, rms_norm(x, W["norm"], eps))
                 x0, conf = _sample_rows_conf(
                     logits, *sample, rep(seeds) + (s * Q + row_j) * rep(fold))
                 keep = masked & plan.unmask(conf.reshape(B, Q), masked, s)
@@ -699,10 +780,15 @@ class ModelRunner:
             # start scalar; table [S]; n_valid scalar <= C. Chunk rows ride
             # the paged-attention BATCH dim: row i gets ctx = start+i+1, so
             # in-chunk causality and attention to the already-cached prefix
-            # both fall out of the per-row context length. A model that
-            # generates by blocks of Q: a row sees to the end of its own
-            # block (the chunk's K and V are all written before any row
-            # attends), never past the chunk, which holds whole blocks.
+            # both fall out of the per-row context length, and each row
+            # walks its own context. A model that generates by blocks of Q:
+            # a row sees to the end of its own block (the chunk's K and V
+            # are all written before any row attends), never past the
+            # chunk, which holds whole blocks. With ``chunk_attention`` =
+            # "sequence" the chunk is ONE sequence of C positions instead:
+            # the multi-query kernel gives row i the same start+i+1 tokens
+            # and walks the pages once for all rows (a padded row's output
+            # is never read).
             x = W["embed"][tokens]                       # [C, H]
             offs = jnp.arange(C, dtype=jnp.int32)
             pos = start.astype(jnp.int32) + offs
@@ -710,24 +796,28 @@ class ModelRunner:
             page_idx = table[pos // page]
             page_idx = jnp.where(valid, page_idx, trash)
             within = pos % page
-            if Q:       # causal from block to block, open inside a block
-                seen = jnp.minimum((pos // Q + 1) * Q,
-                                   start.astype(jnp.int32) + n_valid)
+            if self.chunk_attention == "sequence":
+                tables, mq = table[None, :], (1, C)
+                ctx = (start.astype(jnp.int32) + 1)[None]
             else:
-                seen = pos + 1
-            ctx = jnp.where(valid, seen, 1).astype(jnp.int32)
-            tables = jnp.broadcast_to(table[None, :], (C, table.shape[0]))
+                if Q:   # causal from block to block, open inside a block
+                    seen = jnp.minimum((pos // Q + 1) * Q,
+                                       start.astype(jnp.int32) + n_valid)
+                else:
+                    seen = pos + 1
+                ctx = jnp.where(valid, seen, 1).astype(jnp.int32)
+                tables = jnp.broadcast_to(table[None, :], (C, table.shape[0]))
+                mq = None
             rows = types.SimpleNamespace(
                 page_idx=page_idx, within=within, tables=tables, ctx=ctx,
-                pos=pos, mq=None, live=valid, slot=slot, start=start,
+                pos=pos, mq=mq, live=valid, slot=slot, start=start,
                 n_valid=n_valid)
             x, cache2 = self._run_layers(
                 W, cache, x, self._layer_fns(W, rows, "prefill"))
             if Q:       # a block model's chunk emits no token: no head
                 return jnp.int32(0), cache2
             h = rms_norm(x, W["norm"], eps)
-            last = h[jnp.maximum(n_valid - 1, 0)]
-            logits = last.astype(jnp.float32) @ W["head"].astype(jnp.float32)
+            logits = self._logits(W, h[jnp.maximum(n_valid - 1, 0)])
             nxt = _sample_rows(logits[None], greedy[None], temp[None],
                                topp[None], topk[None], seed[None])[0]
             return nxt, cache2
@@ -774,8 +864,7 @@ class ModelRunner:
                 pos=pos, mq=(B, Kv), live=valid)
             x, cache2 = self._run_layers(
                 W, cache, x, self._layer_fns(W, rows, "verify"))
-            h = rms_norm(x, W["norm"], eps)
-            logits = h.astype(jnp.float32) @ W["head"].astype(jnp.float32)
+            logits = self._logits(W, rms_norm(x, W["norm"], eps))
             # seed schedule mirrors the decode block's `seeds + i*fold`:
             # emitted token #j of this step draws the key step #j of a
             # non-speculative block would have drawn, so fixed-seed
@@ -835,12 +924,12 @@ class ModelRunner:
         """Dispatch one prefill chunk; returns the sampled next token as a
         DEVICE value (only the caller decides whether to sync on it — a
         mid-prompt chunk's sample is never read). ``slot``: whose state the
-        chunk continues, for a model that keeps recurrent state (one that
-        keeps none is not told)."""
+        chunk continues, for a model that keeps a state or a tail a slot
+        (one that keeps neither is not told)."""
         attrs = ({"kind": "prefill", "rows": int(n_valid), "start": int(start),
                   "argmax_only": _argmax_only(greedy)}
                  if _obs.enabled() else {})
-        state = (np.int32(slot),) if self.plan.recurrent else ()
+        state = (np.int32(slot),) if self.plan.per_slot else ()
         return self._launch(
             ("prefill",), self._prefill, attrs, tokens, np.int32(start), table,
             np.int32(n_valid), np.int32(greedy), np.float32(temp),
@@ -985,14 +1074,13 @@ class ModelRunner:
                    self.cache[:self._n_page_pools]) // self.n_pages
 
     def state_bytes_per_slot(self):
-        """HBM bytes one slot's recurrent state costs across all layers
-        (state and convolution tails; 0 for a model that keeps none): what
-        a slot holds whatever its length, beside its pages."""
-        if not self.plan.recurrent:
-            return 0
+        """HBM bytes one slot's recurrent state and tails cost across all
+        layers (0 for a model that keeps neither): what a slot holds
+        whatever its length, beside its pages."""
         at = self._n_page_pools
+        n = int(self.plan.recurrent) + len(self.plan.tailed)
         return sum(int(a.nbytes) for a in
-                   self.cache[at:at + 2]) // (self.max_batch + 1)
+                   self.cache[at:at + n]) // (self.max_batch + 1)
 
     def pages_to_host(self, page_idx):
         """Gather ``page_idx`` pages and land them in host RAM as a tuple of

@@ -10,11 +10,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["ROUTING_COUNTS", "routed_experts", "routing_counts"]
+__all__ = ["ROUTING_COUNTS", "ROUTING_COUNTS_SKIP", "routed_experts",
+           "routing_counts"]
 
 # what one expert layer's routing gives for one call, in this order
 ROUTING_COUNTS = ("calls", "rows", "assignments", "experts_touched",
                   "max_load")
+# ... and of a router that may choose NO expert (the choice past its last
+# one): the live (row, choice) pairs that chose it
+ROUTING_COUNTS_SKIP = ROUTING_COUNTS + ("skipped",)
 
 
 def routed_experts(p, h, chosen, weight, live, e, offset, gmm, l, dtype):
@@ -36,7 +40,11 @@ def routed_experts(p, h, chosen, weight, live, e, offset, gmm, l, dtype):
     are sorted by expert, the rows gathered in that order, and the three
     grouped products (``gmm``) see ``group_sizes`` of whatever they are -
     no capacity. Pairs whose expert lives on another chip sort past the
-    last group and weigh nothing."""
+    last group and weigh nothing, and so does a choice of NO expert (a
+    router whose last output means "skip", as ZAYA's): its index lies past
+    every chip's experts, so it is held nowhere, is no assignment, and its
+    row costs the grouped products nothing - the compute a token gets
+    varies with what its router chose."""
     n, k = chosen.shape
     local = chosen - offset
     held = (local >= 0) & (local < e) & (live > 0)[:, None]
@@ -59,9 +67,14 @@ def routed_experts(p, h, chosen, weight, live, e, offset, gmm, l, dtype):
     return routed, held, sizes
 
 
-def routing_counts(live, held, sizes):
+def routing_counts(live, held, sizes, skipped=None):
     """One call's ``ROUTING_COUNTS`` (int32) from what
-    :func:`routed_experts` returned."""
-    return jnp.stack([jnp.int32(1), jnp.sum(live > 0, dtype=jnp.int32),
-                      jnp.sum(held, dtype=jnp.int32),
-                      jnp.sum(sizes > 0, dtype=jnp.int32), jnp.max(sizes)])
+    :func:`routed_experts` returned; with ``skipped [N, k]`` (the choices
+    of no expert) ``ROUTING_COUNTS_SKIP``, the live ones counted last."""
+    counts = [jnp.int32(1), jnp.sum(live > 0, dtype=jnp.int32),
+              jnp.sum(held, dtype=jnp.int32),
+              jnp.sum(sizes > 0, dtype=jnp.int32), jnp.max(sizes)]
+    if skipped is not None:
+        counts.append(jnp.sum(skipped & (live > 0)[:, None],
+                              dtype=jnp.int32))
+    return jnp.stack(counts)

@@ -21,12 +21,31 @@ between the halves:
              and runs the delta rule; ``second(wl, x, o, live) -> (x,
              counts)``.
 
+A kind of either cache may keep a TAIL for every slot: ``tail = (rows,
+channels)``, what its next token needs of the tokens before it (the last
+inputs of a convolution over time, a shifted projection). The runner keeps
+one pool of tails for each kind that declares one, ``[layers of the kind,
+slots + 1, rows, channels]`` in the weights' dtype, hands the slot's tail
+in and writes the one that comes back; a prefill chunk that starts a prompt
+gets zeros. A ``"state"`` kind's tail is its convolution's; a ``"pages"``
+kind that keeps one has ``first(wl, x, pos, tail, n_valid) -> ((q, k, v),
+tail)`` on rows ``x [..., T, H]`` at positions ``pos`` that follow ``tail
+[..., rows, channels]``, q and k and v with the ``T`` axis.
+
 A kind may name some of its leaves ``whole``: those reach the halves
 unsliced, ``[periods, n, ...]`` as they are stacked, beside the layer's
 index among its like (``wl["l"]``), and the half picks its own part. That
 is for what a Pallas kernel reads: XLA cannot fuse the scan's slice of a
 layer into a custom call's operand, so a scanned-over leaf would be copied
 out once a layer (0.4 GB a matrix of a layer's experts).
+
+A plan with a ``carry`` runs its layers on more than the residual: ``carry(x)
+-> c`` is the zero start, for rows ``x [N, H]``, of a stream that goes from
+layer to layer beside it. The halves then get ``(x, c)`` where they got
+``x`` (``first`` may read it; ``second`` returns the pair), and the head
+reads ``x`` alone after the last layer. ``tied_head``: the logits are the
+final norm's rows times the embedding's transpose, and there is no
+``head`` leaf.
 
 ``live [B]`` marks the rows that carry a request's token (a sparse expert
 layer routes no other); ``counts`` is ``None`` or the int32 vector of
@@ -81,6 +100,7 @@ class LayerKind:
     first: Callable
     second: Callable
     whole: tuple = ()           # the keys among ``keys`` handed over unsliced
+    tail: tuple = ()            # (rows, channels) kept a slot; (): none
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,14 +114,14 @@ class ServingPlan:
     nh: int = 0
     kvh: int = 0
     D: int = 0
-    # recurrent state of one layer and slot: [heads, dk, dv] float32 and a
-    # tail of ``conv_tail`` rows of ``conv_channels`` (0: no "state" kind)
+    # recurrent state of one layer and slot: [heads, dk, dv] float32
+    # (0: no "state" kind)
     state_heads: int = 0
     state_dk: int = 0
     state_dv: int = 0
-    conv_tail: int = 0
-    conv_channels: int = 0
     counts: int = 0             # length of a layer's routing counts (0: none)
+    carry: Optional[Callable] = None    # rows x -> the stream's zero start
+    tied_head: bool = False     # logits = rows @ embed^T; no "head" leaf
     # generation by blocks (0: one token a sequence a forward)
     block: int = 0
     mask_token: int = 0
@@ -118,3 +138,20 @@ class ServingPlan:
     @property
     def recurrent(self):
         return self.layers_of("state") > 0
+
+    @property
+    def tailed(self):
+        """The kinds that keep a tail a slot, in the order of ``kinds``:
+        each has a pool of its own."""
+        return tuple(name for name, lk in self.kinds.items() if lk.tail)
+
+    @property
+    def per_slot(self):
+        """Whether anything is kept a slot beside the pages (a state or a
+        tail): the programs then name each row's slot."""
+        return self.recurrent or bool(self.tailed)
+
+    def kind_layers(self, name):
+        """How many layers of kind ``name``, over all periods."""
+        n = sum(c for k, c in self.period if k == name)
+        return n * (self.periods or 1)

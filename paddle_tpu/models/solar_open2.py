@@ -446,7 +446,8 @@ def serving_plan(c, weights, kernels=False):
             cache="state", keys=_keys(c, "kda"),
             first=lambda wl, x, tail, n_valid: kda_pre(
                 leaves_of("kda", wl), x, tail, n_valid, c),
-            second=second("kda", kda_post), whole=whole("kda")),
+            second=second("kda", kda_post), whole=whole("kda"),
+            tail=(c.short_conv_kernel_size - 1, c.conv_channels)),
     }
     return ServingPlan(
         kinds=kinds, period=(("gqa", 1), ("kda", c.gqa_interval)),
@@ -455,8 +456,7 @@ def serving_plan(c, weights, kernels=False):
         nh=c.num_attention_heads, kvh=c.num_key_value_heads,
         D=c.head_dim, state_heads=c.linear_num_heads,
         state_dk=c.linear_head_dim, state_dv=c.linear_head_dim,
-        conv_tail=c.short_conv_kernel_size - 1,
-        conv_channels=c.conv_channels, counts=len(ROUTING_COUNTS))
+        counts=len(ROUTING_COUNTS))
 
 
 def _keys(c, kind):

@@ -122,7 +122,8 @@ SERVING_DECODE_LAUNCHES = REGISTRY.counter(
     ("engine", "ahead"))                       # ahead: "0" | "1"
 # a sparse expert layer's routing, summed on the device over every layer's
 # call and read with the tokens (engine/runner.py); kind: decode | prefill.
-# In the order of models.solar_open2.ROUTING_COUNTS.
+# In the order of models.dropless.ROUTING_COUNTS_SKIP (a router that cannot
+# choose no expert sends the first five).
 SERVING_MOE = (
     REGISTRY.counter("serving_moe_calls_total",
                      "expert-layer calls (one a layer a dispatch)",
@@ -138,7 +139,10 @@ SERVING_MOE = (
                      "the calls", ("engine", "kind")),
     REGISTRY.counter("serving_moe_max_load_total",
                      "the fullest expert's assignments, summed over the "
-                     "calls", ("engine", "kind")))
+                     "calls", ("engine", "kind")),
+    REGISTRY.counter("serving_moe_skipped_total",
+                     "live (row, layer) routings that chose no expert (a "
+                     "router's skip)", ("engine", "kind")))
 # a model that generates by blocks (engine/runner.py:_build_block): the
 # forwards are counted on the device and read with the tokens
 SERVING_BLOCKS = REGISTRY.counter(

@@ -365,6 +365,24 @@ paged_attention_blocks = jax.jit(_paged_attention_blocks,
                                  static_argnames=("scale",))
 
 
+def _paged_attention_chunk(q, k_pages, v_pages, block_tables, context_lens,
+                           *, k_scales=None, v_scales=None,
+                           scale_tables=None, scale=None):
+    """:func:`paged_attention_multiquery` over a prefill chunk: ONE
+    sequence (``B`` = 1) of Q consecutive positions, row j seeing
+    ``context_lens[0] + j`` tokens, so the chunk's pages are walked once for
+    all of its rows. Named ``paged_attention`` as the blocks' call is, and
+    for the same reason."""
+    return paged_attention_multiquery.__wrapped__(
+        q, k_pages, v_pages, block_tables, context_lens, k_scales=k_scales,
+        v_scales=v_scales, scale_tables=scale_tables, scale=scale)
+
+
+_paged_attention_chunk.__name__ = "paged_attention"
+paged_attention_chunk = jax.jit(_paged_attention_chunk,
+                                static_argnames=("scale",))
+
+
 def paged_attention_multiquery_ref(q, k_pages, v_pages, block_tables,
                                    context_lens, *, k_scales=None,
                                    v_scales=None, scale_tables=None,

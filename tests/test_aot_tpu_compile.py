@@ -532,3 +532,74 @@ class TestSDARPrograms:
         if kind == "decode":
             # the loop over denoising steps and the layers' scans are whiles
             assert text.count(" while(") >= 2
+
+
+class TestZAYAPrograms:
+    """The long-input cell's two whole programs on the v5e's compiler at the
+    cell's own shapes (bench/configs/zaya1-8b.json: published widths, 10
+    layers, all 16 experts, the whole vocabulary, 64 slots x 8,192 tokens,
+    chunks of 256): the paged attention over tables 8,192 tokens long,
+    ``moe_gmm`` over the stack of the layers' experts, the tails pool and
+    the router state beside the residual, the head read off the embedding."""
+    B, MAX_LEN, CHUNK, L = 64, 8192, 256, 10
+
+    def runner_and_arguments(self, sharding):
+        from paddle_tpu.inference.engine.runner import _COUNT_ROWS, ModelRunner
+        from paddle_tpu.models import zaya
+        cfg = zaya.ZAYAConfig(num_hidden_layers=self.L)
+        n_pages = self.B * self.MAX_LEN // PAGE + 1
+        r = ModelRunner.__new__(ModelRunner)
+        r.cfg, r.mesh = cfg, None
+        r.max_batch, r.page, r.chunk = self.B, PAGE, self.CHUNK
+        r.n_pages, r.trash_page = n_pages, n_pages - 1
+        r.use_kernel, r.kv_quant = True, False
+        r.chunk_attention = "sequence"          # the chunk's pages read once
+        r.plan = plan = zaya.serving_plan(cfg, None, kernels=True)
+
+        def sds(shape, dt=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+        W = {"embed": sds((cfg.vocab_size, cfg.hidden_size)),
+             "norm": sds((cfg.hidden_size,))}
+        for name, (shape, _) in zaya.layer_leaves(cfg).items():
+            W[name] = sds((self.L,) + tuple(shape))
+        pages = sds((self.L, n_pages, PAGE, plan.kvh, plan.D))
+        cache = (pages, pages,
+                 sds((self.L, self.B + 1) + plan.kinds["cca"].tail),
+                 sds((len(_COUNT_ROWS), plan.counts), jnp.int32))
+        i32, f32 = jnp.int32, jnp.float32
+        table = self.MAX_LEN // PAGE
+        decode = [sds((self.B,), i32), sds((self.B,), i32),
+                  sds((self.B, table), i32), sds((self.B,), i32)] + [
+            sds((self.B,), dt) for dt in (i32, f32, f32, i32, i32, i32,
+                                          i32, i32)]        # .., take, prev
+        prefill = [sds((self.CHUNK,), i32), sds((), i32), sds((table,), i32),
+                   sds((), i32)] + [
+            sds((), dt) for dt in (i32, f32, f32, i32, i32)] + [sds((), i32)]
+        return r, W, cache, {"decode": decode, "prefill": prefill}
+
+    @pytest.mark.parametrize("kind", ["decode", "prefill"])
+    def test_whole_program_fits_and_copies_nothing_big(self, v5e, kind):
+        r, W, cache, rest = self.runner_and_arguments(
+            SingleDeviceSharding(v5e[0]))
+        prog = r._build_decode(1) if kind == "decode" else r._build_prefill()
+        compiled = prog.lower(W, cache, *rest[kind]).compile()
+        mem = compiled.memory_analysis()
+        print(f"zaya {kind}: arguments {mem.argument_size_in_bytes / 2**30:.3f}"
+              f" GiB, temporaries {mem.temp_size_in_bytes / 2**30:.4f} GiB")
+        # weights 5.23 GB and two page pools of 2.68 GB, 10.6 GB in all
+        assert mem.argument_size_in_bytes < 10.2 * 2**30
+        embed_bytes = int(np.prod(W["embed"].shape)) * 2
+        # nothing of the embedding's size (a head of its own, or the
+        # embedding upcast: 2 GB in float32) and no pool is made beside the
+        # arguments; what is there is the decode step's logits and the
+        # sampler's sort over them, 64 x 262,272 a few times over
+        assert mem.temp_size_in_bytes < embed_bytes // 2
+        text = compiled.as_text()
+        for shape in (r"bf16\[(?:10,)?\d+,16,2,128\]",
+                      r"bf16\[(?:10,16|160|16),(?:2048,2048)\]",
+                      r"(?:bf16|f32)\[(?:262272,2048|2048,262272)\]"):
+            assert not re.findall(
+                rf"= {shape}\S* (?:copy|dynamic-slice|gather|transpose)\(",
+                text), shape
+        for name in ("moe_gmm", "paged_attention"):
+            assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text), name

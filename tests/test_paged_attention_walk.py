@@ -111,6 +111,35 @@ def test_walk_matches_reference(multiquery, int8, case):
     np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
 
 
+@pytest.mark.parametrize("start", [0, BLOCK - 5, 2 * BLOCK + 3],
+                         ids=["prompt_start", "across_a_block", "deep"])
+def test_a_chunk_as_one_sequence_is_its_rows(start):
+    """``paged_attention_chunk`` (a prefill chunk: ONE sequence of C
+    positions, row j seeing start + j + 1 tokens, its pages walked once)
+    computes what the one-query kernel computes for each position as a row
+    of its own; no page past the chunk's last position is read."""
+    C, S = 40, 4 * PPB
+    rng = np.random.RandomState(3)
+    P = S + 3
+    table = rng.permutation(P)[:S].astype(np.int32)
+    q = jnp.asarray(rng.randn(1, C, H, D), jnp.bfloat16)
+    k, v = (jnp.asarray(rng.randn(P, PAGE, KVH, D), jnp.bfloat16)
+            for _ in range(2))
+    off = np.ones(P, bool)
+    off[table[:-(-(start + C) // PAGE)]] = False
+    bad_k, bad_v = (jnp.where(jnp.asarray(off)[:, None, None, None], jnp.nan,
+                              a).astype(a.dtype) for a in (k, v))
+    got = pa.paged_attention_chunk(q, bad_k, bad_v, jnp.asarray(table)[None],
+                                   jnp.asarray([start + 1], jnp.int32))[0]
+    rows = pa.paged_attention(
+        q[0], k, v, jnp.broadcast_to(jnp.asarray(table), (C, S)),
+        jnp.asarray(start + 1 + np.arange(C), jnp.int32))
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all(), "a page past the chunk was read"
+    np.testing.assert_allclose(got, np.asarray(rows, np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
 def test_block_is_about_128_tokens_at_the_serving_shape():
     """The block is a constant of the kernel (PERF.md section 6, PR 29),
     derived from the page and head sizes and from nothing a caller says."""
