@@ -91,6 +91,13 @@ FLASH_TILE_SHARE = REGISTRY.gauge(
     "% of the (Sq/bq) x (Sk/bk) tile grid of the newest traced flash kernel "
     "that runs (kind=computed) and that takes the causal mask "
     "(kind=masked)", ("kernel", "kind"))
+# serving paged attention (ops/pallas/paged_attention.py), set when a walk's
+# trip is fixed at trace time: the pages a trip of the newest traced walk at
+# that many KV heads and score rows (a row's query heads x its queries)
+PAGED_TRIP_PAGES = REGISTRY.gauge(
+    "paged_attention_trip_pages",
+    "pages one trip of the newest traced paged-attention walk copies, by "
+    "its KV heads and its score rows", ("kv_heads", "score_rows"))
 
 # serving engine (inference/serving.py); one label per engine instance
 SERVING_TTFT = REGISTRY.histogram(

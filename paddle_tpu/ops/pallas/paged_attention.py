@@ -9,10 +9,12 @@ block table. One kernel body (:func:`_walk`) serves the four entry points
 scales). Its grid runs over the rows alone; the pools stay in HBM and the
 block table and the context lengths are scalar-prefetched. For its row a
 program walks ``ceil(ctx / block)`` blocks of several consecutive table
-entries (about 128 tokens: :func:`_pages_per_block`) and no more: it copies
-a block's pages by the table into one of two VMEM buffers while it computes
-the block before, and runs the online softmax (m, l, acc in VMEM scratch,
-float32) once a block over all KV heads. So a call costs what its rows'
+entries and no more (as many pages as a trip's K bytes and score tile
+allow: :func:`_pages_per_block`): it copies a block's pages by the table
+into one of two VMEM buffers, a page's tokens and KV heads as the dense
+``[page * KVH, D]`` rows they are in HBM, while it computes the block
+before, and runs the online softmax (m, l, acc in VMEM scratch, float32)
+once a block over all KV heads. So a call costs what its rows'
 contexts cost, not ``rows x max_len``. GQA q-head groups meet their kv head
 as a mask on the score columns (no repeat materialization)."""
 from __future__ import annotations
@@ -26,7 +28,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-_BLOCK_ELEMS = 128 * 128     # a KV head's key tile a trip: see _pages_per_block
+# a trip of the walk (see _pages_per_block): the first block is a KV head's
+# [128, 128] key tile; it doubles while the trip's K bytes and its score tile
+# stay within these, and the trip within its table
+_BLOCK_ELEMS = 128 * 128
+_TRIP_K_BYTES = 256 * 1024
+_TRIP_SCORES = 128 * 1024
 
 
 # which query of its sequence's q_len a row counts as, for its horizon: its
@@ -39,12 +46,26 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _pages_per_block(page_size, head_dim):
-    """Pages one trip of the walk covers: a KV head's key tile is then
-    ``_BLOCK_ELEMS`` elements, ``[128, 128]`` at a head size of 128 (8
-    pages of 16). Fixed by measurement on the v5e (PERF.md section 6,
-    PR 29); not an option of any caller."""
-    return max(1, _BLOCK_ELEMS // (page_size * head_dim))
+def _pages_per_block(page_size, kv_heads, head_dim, itemsize, score_rows,
+                     table_pages):
+    """Pages one trip of the walk covers, from the operands' shapes alone.
+
+    It starts at a KV head's key tile of ``_BLOCK_ELEMS`` elements, 8 pages
+    of 16 at a head size of 128, and doubles while a trip's K bytes stay
+    within ``_TRIP_K_BYTES``, its ``[score_rows, block * kv_heads]`` score
+    tile within ``_TRIP_SCORES`` elements and the trip within the table's
+    ``table_pages``. So 8 KV heads keep their 8 pages (256 KiB of K; 16
+    pages won on long rows at Mistral's shape but lost 14 % on short ones,
+    PERF.md section 6), 2 KV heads take 32 and 4 take 16 (ZAYA's decode
+    call 926 -> 630 us, SDAR's block call 473 -> 390 on the v5e: the trip
+    tables of PERF.md section 6), and a prefill chunk's many score rows
+    keep the first block. Not an option of any caller."""
+    first = max(1, _BLOCK_ELEMS // (page_size * head_dim))
+    cols = page_size * kv_heads             # score columns a page
+    most = min(_TRIP_K_BYTES // (cols * head_dim * itemsize),
+               _TRIP_SCORES // (score_rows * cols), table_pages)
+    # the first block, doubled as often as it stays within ``most`` pages
+    return first << max(0, (most // first).bit_length() - 1)
 
 
 def _walk(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, *rest, scale, page_size, ppb,
@@ -62,12 +83,15 @@ def _walk(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, *rest, scale, page_size, ppb,
 
     The online softmax runs once a block, over ALL KV heads at once: the
     buffer is read as it lies, ``[block * KVH, D]`` with a token's heads
-    side by side, so a score column is a (token, KV head) pair and a row
-    keeps the columns of its own head (``own`` below). The MXU loads the
-    same 2 * KVH key and value tiles a block as a product a head would, the
-    other heads' columns ride along as zeros, and no head's rows have to be
-    picked out of the pages (a sublane-strided read a head: twice the time
-    on the v5e, PERF.md section 6, PR 29).
+    side by side (a page is copied as the dense ``[page * KVH, D]`` rows it
+    is in HBM; as ``[page, KVH, D]`` the buffer would pad KVH to a tile's
+    16 rows and every read re-lay it: 1.3-1.8 x the time at 2 KV heads,
+    PERF.md section 6), so a score column is a (token, KV head) pair
+    and a row keeps the columns of its own head (``own`` below). The MXU
+    loads the same 2 * KVH key and value tiles a block as a product a head
+    would, the other heads' columns ride along as zeros, and no head's rows
+    have to be picked out of the pages (a sublane-strided read a head:
+    twice the time on the v5e, PERF.md section 6).
 
     int8 pages go to the MXU as they are (an int8 is exact in bf16); their
     per-token scales multiply the scores (K) and the probabilities (V),
@@ -178,6 +202,12 @@ def _walk(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, *rest, scale, page_size, ppb,
     o_ref[0] = (acc_s[:] / denom).astype(o_ref.dtype)
 
 
+def _record_trip(ppb, kv_heads, score_rows):
+    """Sets paged_attention_trip_pages for the walk being traced."""
+    from ...observability import PAGED_TRIP_PAGES
+    PAGED_TRIP_PAGES.set(ppb, kv_heads=kv_heads, score_rows=score_rows)
+
+
 def _paged_call(qf, k_pages, v_pages, block_tables, context_lens,
                 k_scales, v_scales, scale_tables, *, scale, q_len,
                 horizon="row"):
@@ -195,12 +225,16 @@ def _paged_call(qf, k_pages, v_pages, block_tables, context_lens,
     _, page_size, KVH, _ = k_pages.shape
     S = block_tables.shape[1]
     quant = k_scales is not None
-    ppb = _pages_per_block(page_size, D)
+    ppb = _pages_per_block(page_size, KVH, D, k_pages.dtype.itemsize, HQ, S)
+    _record_trip(ppb, KVH, HQ)
     n_blk = -(-S // ppb)
     cols = ppb * page_size * KVH
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     row_spec = pl.BlockSpec((1, HQ, D), lambda b, *_: (b, 0, 0))
-    operands = [qf, k_pages, v_pages]
+    # a page as the [page * KVH, D] rows it is in HBM (a bitcast): the VMEM
+    # buffers are then dense, where [page, KVH, D] pads KVH to a whole tile
+    operands = [qf] + [p.reshape(p.shape[0], page_size * KVH, D)
+                       for p in (k_pages, v_pages)]
     in_specs = [row_spec, hbm, hbm]
     if quant:
         st = block_tables if scale_tables is None else scale_tables
@@ -216,8 +250,8 @@ def _paged_call(qf, k_pages, v_pages, block_tables, context_lens,
         num_scalar_prefetch=2, grid=(B,), in_specs=in_specs,
         out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, ppb, page_size, KVH, D), k_pages.dtype),
-            pltpu.VMEM((2, ppb, page_size, KVH, D), v_pages.dtype),
+            pltpu.VMEM((2, ppb, page_size * KVH, D), k_pages.dtype),
+            pltpu.VMEM((2, ppb, page_size * KVH, D), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SMEM((1,), jnp.int32),    # the slot of the row's block 0
             pltpu.VMEM((HQ, 1), jnp.float32),

@@ -170,6 +170,32 @@ class TestPagedAttention:
                                         max_len))
         assert "tpu_custom_call" in text
 
+    @pytest.mark.parametrize("kernel,q_shape,kvh,max_len,int8,pages", [
+        (pa.paged_attention, (64, 8, D), 2, 8192, False, 32),
+        (pa.paged_attention_blocks, (64, 4, 32, D), 4, 3072, False, 16),
+        (pa.paged_attention, (64, 8, D), 2, 8192, True, 64),
+    ], ids=["decode_kvh2_table8192", "blocks_kvh4_table3072",
+            "decode_kvh2_int8_table8192"])
+    def test_grown_trips(self, v5e, kernel, q_shape, kvh, max_len, int8,
+                         pages):
+        """Fewer KV heads take more pages a trip (PERF.md section 6):
+        ZAYA's decode rows (8 q heads over 2 KV heads) walk 32 pages a
+        trip of 8,192-token tables, SDAR's block call (4 positions x 32
+        heads over 4) 16 of 3,072-token ones; both must compile. int8 pages
+        at 2 KV heads too: a page is copied as dense ``[page * KVH, D]``
+        rows, where a ``[page, 2, D]`` slice of the pool was refused."""
+        rows, score_rows = q_shape[0], int(np.prod(q_shape[1:-1]))
+        dt = jnp.int8 if int8 else jnp.bfloat16
+        assert pa._pages_per_block(PAGE, kvh, D, dt.dtype.itemsize,
+                                   score_rows, max_len // PAGE) == pages
+        n_pages = rows * max_len // PAGE + 1
+        kv = ((n_pages, PAGE, kvh, D), dt)
+        text = compile_for(
+            SingleDeviceSharding(v5e[0]), self.call(kernel), bf16(*q_shape),
+            kv, kv, ((rows, max_len // PAGE), jnp.int32), ((rows,), jnp.int32),
+            *([((n_pages, PAGE, kvh), jnp.float32)] * 2 if int8 else []))
+        assert "tpu_custom_call" in text
+
 
 class TestQuantMatmul:
     @pytest.mark.parametrize("int4", [False, True], ids=["int8", "int4"])

@@ -269,6 +269,41 @@ def test_tile_share_gauge():
     assert fa._tile_shares(S, S, 128, 128, False) == (100.0, 0.0)
 
 
+def test_trip_pages_gauge():
+    """paged_attention_trip_pages reads the trip of the newest traced walk
+    by its KV heads and score rows: 32 pages at ZAYA's decode shape, 8 at
+    Mistral's."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    def trips():
+        series = obs.snapshot(prefix="paged_attention_trip_pages")[
+            "paged_attention_trip_pages"]["series"]
+        return {(s["labels"]["kv_heads"], s["labels"]["score_rows"]):
+                s["value"] for s in series}
+
+    def trace(B, h, kvh, max_len=8192, pages=64):
+        q = jax.ShapeDtypeStruct((B, h, 128), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((pages, 16, kvh, 128), jnp.bfloat16)
+        tables = jax.ShapeDtypeStruct((B, max_len // 16), jnp.int32)
+        cl = jax.ShapeDtypeStruct((B,), jnp.int32)
+        # a scale no other test traces with: the jit cache must miss
+        jax.eval_shape(lambda *a: pa.paged_attention(*a, scale=0.0917),
+                       q, kv, kv, tables, cl)
+
+    obs.reset()
+    obs.enable()
+    try:
+        trace(64, 8, 2)                         # ZAYA's decode call
+        trace(16, 32, 8, max_len=2048)          # Mistral's
+        got = trips()
+        assert got[("2", "8")] == 32
+        assert got[("8", "32")] == 8
+    finally:
+        obs.disable()
+        obs.reset()
+
+
 def test_eager_calls_trace_the_row_kernels_once(monkeypatch):
     """Op-by-op (a training step's capture passes) a second causal call
     with the same shapes reuses the jitted row-block kernels: nothing is
